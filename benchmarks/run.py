@@ -14,8 +14,9 @@ import time
 
 
 def _kernel_microbench():
-    """Per-kernel interpret-mode timing vs pure-jnp oracle (CPU container:
-    these validate dispatch + give a baseline; TPU timing is out of scope)."""
+    """Per-kernel host-clock timing vs pure-jnp oracle on the default
+    platform (off-TPU the Pallas kernels run interpreted; these validate
+    dispatch, not device speed)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -41,7 +42,7 @@ def _kernel_microbench():
     pk = jnp.asarray(rng.choice(1 << 21, 65536).astype(np.int32))
     qm = jnp.asarray([1], jnp.uint32)
     us = timeit(lambda: ops.probe(pk, tk, tv, qm))
-    rows.append(("kernel", "hash_probe_lens[64k]", round(us, 1), "interpret"))
+    rows.append(("kernel", "hash_probe_lens[64k]", round(us, 1), "xla"))
     us = timeit(lambda: ref.hash_probe_lens_ref(pk[:4096], tk, tv, qm))
     rows.append(("kernel", "hash_probe_ref[4k]", round(us, 1), "oracle"))
 

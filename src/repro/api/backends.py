@@ -8,12 +8,15 @@ build state (§4.3) and segmented aggregation into shared accumulators
   probe index in ``core.state``, ``np.bincount`` reductions). Always
   available; the correctness oracle path (``relational/refexec.py``
   semantics).
-* ``PallasBackend`` — the jax_pallas TPU kernels (``kernels/hash_probe.py``,
-  ``kernels/fused_chain.py``, ``kernels/seg_aggregate.py``), run in
-  interpret mode off-TPU. States that the kernels cannot serve (multi-match
-  keys, out-of-range keycodes, over-long probe clusters) fall back to the
-  reference path per-call, mirroring the routing note in the kernel
-  docstrings; per-reason fallback counters record why.
+* ``PallasBackend`` — the device data plane: the probes and the fused
+  stage chain are jitted XLA programs (``kernels/hash_probe.py``,
+  ``kernels/fused_chain.py``) that run unchanged on every platform; the
+  opt-in batch insert (``hash_build_insert``) and segmented aggregate
+  (``kernels/seg_aggregate.py``) are Pallas kernels, compiled on the TPU
+  and interpreted elsewhere. States that the device programs cannot serve
+  (multi-match keys, out-of-range keycodes, over-long probe clusters) fall
+  back to the reference path per-call; per-reason fallback counters
+  record why.
 
 The Pallas backend keeps a device-resident mirror of every served state's
 SoA (DESIGN.md §13): open-addressing keycode table, *entry-indexed* packed
@@ -106,6 +109,32 @@ def _scatter_set(donate: bool):
     return jax.jit(f)
 
 
+def _pow2(n: int) -> int:
+    """Row length a device program sees for ``n`` rows: the next power of
+    two (at least 8), so each program compiles for O(log n) row shapes."""
+    cap = 8
+    while cap < n:
+        cap *= 2
+    return cap
+
+
+def _pad(a: np.ndarray, length: int, fill=0) -> np.ndarray:
+    if len(a) < length:
+        a = np.concatenate([a, np.full(length - len(a), fill, dtype=a.dtype)])
+    return a
+
+
+def _device_keys(keycodes: np.ndarray):
+    """Probe keys as a power-of-two-length int32 device array; the EMPTY
+    padding matches nothing and is sliced off the results."""
+    import jax.numpy as jnp
+
+    from ..kernels.hash_probe import EMPTY
+
+    kc = np.asarray(keycodes, dtype=np.int32)
+    return jnp.asarray(_pad(kc, _pow2(len(kc)), EMPTY))
+
+
 class _ProbeTable:
     """Device-resident mirror of one state's SoA (DESIGN.md §13).
 
@@ -162,12 +191,12 @@ class _ProbeTable:
 
 
 class PallasBackend:
-    """jax_pallas data plane (interpret mode off-TPU).
+    """Device data plane.
 
-    Unique-key states probe through the fused-lens Pallas kernels over
+    Unique-key states probe through the fused-lens device programs over
     entry-indexed device mirrors. Single-query probes route through
     ``probe_visible`` — the query's slot bit (any of the 64) becomes the
-    kernel lens mask, so visibility resolves in-kernel and the runtime
+    lens mask, so visibility resolves on device and the runtime
     skips its NumPy ``visible_mask`` pass. Multi-member probes take
     ``probe_visible_multi``, which returns the matched entries' full packed
     uint64 words. ``probe_chain`` fuses a morsel's entire stage chain —
@@ -181,8 +210,10 @@ class PallasBackend:
     Probe-table maintenance is batch-oriented: new keys insert via
     vectorized per-slot winner election (``_batch_insert``), or through the
     Pallas ``hash_build_insert`` kernel when ``use_insert_kernel`` is set
-    (opt-in: the in-kernel insert loop is sequential, which only pays off
-    compiled on-device).
+    (opt-in: the in-kernel insert loop is sequential).
+
+    ``interpret`` applies to the two Pallas kernels only; None takes it
+    from the platform (compiled on the TPU, interpreted elsewhere).
 
     Segmented sums route through the one-hot MXU kernel below
     ``max_kernel_groups`` groups when ``use_agg_kernel`` is set; it
@@ -198,13 +229,14 @@ class PallasBackend:
 
     def __init__(
         self,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         max_kernel_groups: int = 4096,
         use_agg_kernel: bool = False,
         use_insert_kernel: bool = False,
     ):
         import jax
 
+        from ..kernels import default_interpret
         from ..kernels.fused_chain import chain_launch, total_order_u32
         from ..kernels.hash_probe import (
             hash_build_insert,
@@ -221,7 +253,7 @@ class PallasBackend:
         self._seg_aggregate = seg_aggregate
         self._chain_launch = chain_launch
         self._total_order_u32 = total_order_u32
-        self.interpret = interpret
+        self.interpret = default_interpret() if interpret is None else interpret
         self.max_kernel_groups = max_kernel_groups
         self.use_agg_kernel = use_agg_kernel
         self.use_insert_kernel = use_insert_kernel
@@ -244,6 +276,7 @@ class PallasBackend:
         self.kernel_multi_probes = 0
         self.fallback_probes = 0
         self.chain_launches = 0
+        self.chain_devices = set()  # devices the chain launches ran on
         self.mirror_full_regathers = 0
         self.mirror_patched_rows = 0
         self.fallback_reasons = {r: 0 for r in FALLBACK_REASONS}
@@ -267,6 +300,14 @@ class PallasBackend:
         }
         for r in FALLBACK_REASONS:
             out[f"fallback_{r}"] = self.fallback_reasons[r]
+        return out
+
+    def mirror_devices(self) -> set:
+        """Devices holding the live states' probe-table mirrors."""
+        out = set()
+        for ent in list(self._tables.values()):
+            if ent.jkeys is not None:
+                out.update(ent.jkeys.devices())
         return out
 
     def note_fallback(self, reason: str, counters=None) -> None:
@@ -295,14 +336,8 @@ class PallasBackend:
         if self._qmask is None:  # lens off: pure key match
             self._qmask = jnp.asarray([0xFFFFFFFF], dtype=jnp.uint32)
         found_slots = np.asarray(
-            self._hash_probe_lens(
-                jnp.asarray(keycodes, dtype=jnp.int32),
-                tkeys,
-                tones,
-                self._qmask,
-                interpret=self.interpret,
-            )
-        )
+            self._hash_probe_lens(_device_keys(keycodes), tkeys, tones, self._qmask)
+        )[: len(keycodes)]
         self.kernel_probes += 1
         probe_idx = np.flatnonzero(found_slots >= 0).astype(np.int64)
         entry_idx = slot_entry[found_slots[probe_idx]]
@@ -337,15 +372,14 @@ class PallasBackend:
         mlo, mhi = split_words(np.array([mask], dtype=np.uint64))
         found = np.asarray(
             self._hash_probe_lens64(
-                jnp.asarray(keycodes, dtype=jnp.int32),
+                _device_keys(keycodes),
                 ent.jkeys,
                 ent.jentry,
                 ent.jvlo,
                 ent.jvhi,
                 jnp.asarray(np.array([mlo[0], mhi[0]], dtype=np.uint32)),
-                interpret=self.interpret,
             )
-        )
+        )[: len(keycodes)]
         self.kernel_probes += 1
         self.kernel_lens_probes += 1
         probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
@@ -366,19 +400,12 @@ class PallasBackend:
         table = self._table_for(state)
         if table is None or keycodes.min() < 0 or keycodes.max() > self._KEY_LIMIT:
             return None
-        import jax.numpy as jnp
-
         ent = self._tables[state]
         self._sync_mirrors(ent, state)
         found, wlo, whi = self._hash_probe_lens_multi64(
-            jnp.asarray(keycodes, dtype=jnp.int32),
-            ent.jkeys,
-            ent.jentry,
-            ent.jvlo,
-            ent.jvhi,
-            interpret=self.interpret,
+            _device_keys(keycodes), ent.jkeys, ent.jentry, ent.jvlo, ent.jvhi
         )
-        found = np.asarray(found)
+        found = np.asarray(found)[: len(keycodes)]
         self.kernel_probes += 1
         self.kernel_multi_probes += 1
         probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
@@ -466,16 +493,10 @@ class PallasBackend:
 
         from ..kernels.hash_probe import EMPTY
 
-        npad = 8
-        while npad < n:
-            npad *= 2
+        npad = _pow2(n)
 
         def pad_row(a, fill=0):
-            if len(a) < npad:
-                a = np.concatenate(
-                    [a, np.full(npad - len(a), fill, dtype=a.dtype)]
-                )
-            return jnp.asarray(a)
+            return jnp.asarray(_pad(a, npad, fill))
 
         blo, bhi = split_words(bits)
         arrays = [pad_row(blo), pad_row(bhi)]
@@ -553,9 +574,8 @@ class PallasBackend:
                 dev["sink"] = sp
             arrays += list(sp)
         spec = (tuple(spec_stages), sink is not None)
-        out = self._chain_launch(
-            spec, tuple(arrays), interpret=self.interpret, mesh=self.mesh
-        )
+        out = self._chain_launch(spec, tuple(arrays), mesh=self.mesh)
+        self.chain_devices.update(out[0].devices())
         n_stages = len(stages)
         res = {
             "bits": join_words(np.asarray(out[0])[:n], np.asarray(out[1])[:n]),

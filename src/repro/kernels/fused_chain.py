@@ -1,20 +1,20 @@
-"""Fused morsel stage-chain Pallas kernel (DESIGN.md §13).
+"""Fused morsel stage chain as one device program (DESIGN.md §13).
 
 One launch runs a morsel's entire packed stage chain — hash probe →
 lens-word translation → grant-predicate visibility → interval-matrix stage
 filter, for every stage in sequence — plus the build-sink word translation,
 over device-resident state mirrors. This replaces the per-stage host
-round-trips of the member-major pipeline (§11): the host hands the kernel
+round-trips of the member-major pipeline (§11): the host hands the device
 the morsel's packed ownership words and per-row probe keys once, and gets
 back the final words, per-stage matched entry indices, per-stage
 alive/matched counts, per-slot survivor counts, and the sink's
 visibility/extent words. Everything that must stay bit-exact in float64
 (aggregate accumulation, payload values) is reconstructed host-side from
-the returned entry indices; the kernel only ever computes set membership,
+the returned entry indices; the device only ever computes set membership,
 so results are bit-identical to the NumPy member-major path.
 
 Two representation choices make the full 64-slot lens space and float64
-predicates kernel-servable without 64-bit device types (TPUs have neither
+predicates device-servable without 64-bit device types (TPUs have neither
 int64 nor float64 lanes; the repo never enables jax x64):
 
 * every packed uint64 word — ownership bits, lens words, translation
@@ -24,7 +24,7 @@ int64 nor float64 lanes; the repo never enables jax x64):
 * float64 predicate operands (grant bounds, stage-filter bounds, payload
   columns they compare against) are encoded host-side through a *monotone
   total-order* map onto a (hi, lo) uint32 pair (``total_order_u32``), so
-  unsigned lexicographic compares in-kernel reproduce IEEE ``>=``/``<=``
+  unsigned lexicographic compares on device reproduce IEEE ``>=``/``<=``
   bit-exactly — including -0.0 == 0.0 (canonicalized before encoding) and
   NaN failing every constrained interval (NaN encodes outside the
   ±inf-bounded range on its sign's side).
@@ -32,11 +32,10 @@ int64 nor float64 lanes; the repo never enables jax x64):
 The launch is shaped by a static, hashable *chain spec* (stage count, key
 sourcing, grant/filter arity); the host assembles a flat canonical input
 list (``input_kinds`` documents the traversal) and ``chain_launch``
-dispatches through a cached jitted ``pallas_call``. Under interpret mode
-the whole morsel runs as a single grid step (the grid would otherwise
-unroll into Python-loop tracing at bench sizes); on a real TPU the same
-kernel tiles by ``block_n`` with the stats/popcount outputs accumulated
-across grid steps.
+dispatches through a cached jitted XLA program named ``graft_chain``. It is
+not a Pallas kernel: its probes and mirror lookups are data-dependent 1-D
+gathers into HBM-resident tables, which Mosaic does not lower and which
+outgrow VMEM at SF 1 (see ``hash_probe``).
 """
 
 from __future__ import annotations
@@ -47,9 +46,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .hash_probe import EMPTY, MAX_PROBE, MULT
+from .hash_probe import EMPTY, probe_slots
 
 __all__ = [
     "total_order_u32",
@@ -104,10 +102,10 @@ def total_order_bound(x: float):
 def input_kinds(spec):
     """Canonical flat input traversal for a chain spec.
 
-    Returns a list of ``"row"`` (morsel-length, block-tiled) /
-    ``"full"`` (whole-array-per-block: tables, mirrors, parameter
-    matrices) markers, in the exact order the host must assemble inputs
-    and the kernel consumes them:
+    Returns a list of ``"row"`` (morsel-length, row-sharded under a mesh)
+    / ``"full"`` (replicated: tables, mirrors, parameter matrices)
+    markers, in the exact order the host must assemble inputs and the
+    chain program consumes them:
 
     ``bits_lo, bits_hi``, then per stage: key array; ``tkeys, tentry``;
     ``evis_lo, evis_hi``; ``ttab_lo, ttab_hi``; grants block
@@ -160,265 +158,174 @@ def _translate(bl, bh, tlo, thi):
     return olo, ohi
 
 
-def _build_kernel(spec):
+def _chain_body(spec, arrays):
+    """The stage chain over the flat input list of :func:`input_kinds`;
+    returns the output tuple :func:`chain_launch` documents."""
     stages, sink = spec
-    n_stages = len(stages)
+    it = iter(arrays)
+    bl = next(it)
+    bh = next(it)
+    stage_in = []
+    for key_mode, n_grants, grant_attrs, filt in stages:
+        d = {"key": next(it)}
+        d["tkeys"] = next(it)
+        d["tentry"] = next(it)
+        d["evlo"] = next(it)
+        d["evhi"] = next(it)
+        d["ttlo"] = next(it)
+        d["tthi"] = next(it)
+        if n_grants:
+            d["eemlo"] = next(it)
+            d["eemhi"] = next(it)
+            d["gbit"] = next(it)
+            d["gallow"] = next(it)
+            d["gcon"] = next(it)
+            d["glo"] = next(it)
+            d["ghi"] = next(it)
+            d["gattrs"] = [(next(it), next(it)) for _ in range(grant_attrs)]
+        if filt is not None:
+            _, srcs = filt
+            d["fvals"] = [(next(it), next(it)) for _ in srcs]
+            d["flo"] = next(it)
+            d["fhi"] = next(it)
+            d["fcon"] = next(it)
+            d["fbit"] = next(it)
+        stage_in.append(d)
+    if sink:
+        stlo = next(it)
+        sthi = next(it)
+        selo = next(it)
+        sehi = next(it)
 
-    def kernel(*refs):
-        it = iter(refs)
-        bl = next(it)[...]
-        bh = next(it)[...]
-        stage_refs = []
-        for key_mode, n_grants, grant_attrs, filt in stages:
-            d = {"key": next(it)[...]}
-            d["tkeys"] = next(it)[...]
-            d["tentry"] = next(it)[...]
-            d["evlo"] = next(it)[...]
-            d["evhi"] = next(it)[...]
-            d["ttlo"] = next(it)[...]
-            d["tthi"] = next(it)[...]
-            if n_grants:
-                d["eemlo"] = next(it)[...]
-                d["eemhi"] = next(it)[...]
-                d["gbit"] = next(it)[...]
-                d["gallow"] = next(it)[...]
-                d["gcon"] = next(it)[...]
-                d["glo"] = next(it)[...]
-                d["ghi"] = next(it)[...]
-                d["gattrs"] = [(next(it)[...], next(it)[...]) for _ in range(grant_attrs)]
-            if filt is not None:
-                _, srcs = filt
-                d["fvals"] = [(next(it)[...], next(it)[...]) for _ in srcs]
-                d["flo"] = next(it)[...]
-                d["fhi"] = next(it)[...]
-                d["fcon"] = next(it)[...]
-                d["fbit"] = next(it)[...]
-            stage_refs.append(d)
-        if sink:
-            stlo = next(it)[...]
-            sthi = next(it)[...]
-            selo = next(it)[...]
-            sehi = next(it)[...]
-        obl_ref = next(it)
-        obh_ref = next(it)
-        oent_refs = [next(it) for _ in range(n_stages)]
-        ostats_ref = next(it)
-        oslot_ref = next(it)
-        if sink:
-            osv_lo_ref = next(it)
-            osv_hi_ref = next(it)
-            ose_lo_ref = next(it)
-            ose_hi_ref = next(it)
-
-        entries = []
-        stats = []
-        for s, (key_mode, n_grants, grant_attrs, filt) in enumerate(stages):
-            d = stage_refs[s]
-            alive = (bl | bh) != 0
-            if key_mode == -1:
-                keys = d["key"]
-            else:
-                e = entries[key_mode]
-                ok = e >= 0
-                keys = jnp.where(ok, d["key"][jnp.where(ok, e, 0)], jnp.int32(EMPTY))
-            keys = jnp.where(alive, keys, jnp.int32(EMPTY))
-            tkeys = d["tkeys"]
-            cap_mask = jnp.int32(tkeys.shape[0] - 1)
-            pos = (keys.astype(jnp.uint32) * jnp.uint32(MULT)).astype(jnp.int32) & cap_mask
-            found0 = jnp.full(keys.shape, -1, jnp.int32)
-            done0 = keys == jnp.int32(EMPTY)
-
-            def cond(carry):
-                i, _pos, _found, done = carry
-                return (i < MAX_PROBE) & jnp.any(~done)
-
-            def body(carry, keys=keys, tkeys=tkeys, cap_mask=cap_mask):
-                i, pos, found, done = carry
-                slot_keys = tkeys[pos]
-                hit = (slot_keys == keys) & ~done
-                empty = (slot_keys == jnp.int32(EMPTY)) & ~done
-                found = jnp.where(hit, pos, found)
-                done = done | hit | empty
-                pos = (pos + 1) & cap_mask
-                return i + 1, pos, found, done
-
-            _, _, found, _ = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), pos, found0, done0)
-            )
-            matched = found >= 0
-            entry = jnp.where(matched, d["tentry"][jnp.where(matched, found, 0)], -1)
-            entries.append(entry)
-            safe_e = jnp.where(matched, entry, 0)
-            # lens gather (entry-indexed: rebuild-invariant) + translation
-            vlo = jnp.where(matched, d["evlo"][safe_e], jnp.uint32(0))
-            vhi = jnp.where(matched, d["evhi"][safe_e], jnp.uint32(0))
-            plo, phi = _translate(vlo, vhi, d["ttlo"], d["tthi"])
-            if n_grants:
-                # compiled extent-scoped grants: emask ∩ allowed, then the
-                # conjunction's interval bounds on total-order-encoded cols
-                elo = jnp.where(matched, d["eemlo"][safe_e], jnp.uint32(0))
-                ehi = jnp.where(matched, d["eemhi"][safe_e], jnp.uint32(0))
-                gvals = [
-                    (gh[safe_e], gl[safe_e]) for gh, gl in d["gattrs"]
+    entries = []
+    stats = []
+    for s, (key_mode, n_grants, grant_attrs, filt) in enumerate(stages):
+        d = stage_in[s]
+        alive = (bl | bh) != 0
+        if key_mode == -1:
+            keys = d["key"]
+        else:
+            e = entries[key_mode]
+            ok = e >= 0
+            keys = jnp.where(ok, d["key"][jnp.where(ok, e, 0)], jnp.int32(EMPTY))
+        keys = jnp.where(alive, keys, jnp.int32(EMPTY))
+        found = probe_slots(keys, d["tkeys"])
+        matched = found >= 0
+        entry = jnp.where(matched, d["tentry"][jnp.where(matched, found, 0)], -1)
+        entries.append(entry)
+        safe_e = jnp.where(matched, entry, 0)
+        # lens gather (entry-indexed: rebuild-invariant) + translation
+        vlo = jnp.where(matched, d["evlo"][safe_e], jnp.uint32(0))
+        vhi = jnp.where(matched, d["evhi"][safe_e], jnp.uint32(0))
+        plo, phi = _translate(vlo, vhi, d["ttlo"], d["tthi"])
+        if n_grants:
+            # compiled extent-scoped grants: emask ∩ allowed, then the
+            # conjunction's interval bounds on total-order-encoded cols
+            elo = jnp.where(matched, d["eemlo"][safe_e], jnp.uint32(0))
+            ehi = jnp.where(matched, d["eemhi"][safe_e], jnp.uint32(0))
+            gvals = [
+                (gh[safe_e], gl[safe_e]) for gh, gl in d["gattrs"]
+            ]
+            for g in range(n_grants):
+                gok = ((elo & d["gallow"][g, 0]) | (ehi & d["gallow"][g, 1])) != 0
+                for a in range(grant_attrs):
+                    xh, xl = gvals[a]
+                    inb = _ge(xh, xl, d["glo"][g, a, 0], d["glo"][g, a, 1]) & _le(
+                        xh, xl, d["ghi"][g, a, 0], d["ghi"][g, a, 1]
+                    )
+                    gok = gok & (inb | (d["gcon"][g, a] == 0))
+                plo = plo | jnp.where(gok, d["gbit"][g, 0], jnp.uint32(0))
+                phi = phi | jnp.where(gok, d["gbit"][g, 1], jnp.uint32(0))
+        nbl = bl & jnp.where(matched, plo, jnp.uint32(0))
+        nbh = bh & jnp.where(matched, phi, jnp.uint32(0))
+        m_post = matched & ((nbl | nbh) != 0)
+        bl, bh = nbl, nbh
+        if filt is not None:
+            n_members, srcs = filt
+            vals = []
+            for a, src in enumerate(srcs):
+                vh, vl = d["fvals"][a]
+                if src == -1:
+                    vals.append((vh, vl))
+                else:
+                    e2 = entries[src]
+                    s2 = jnp.where(e2 >= 0, e2, 0)
+                    vals.append((vh[s2], vl[s2]))
+            fblo = jnp.zeros_like(bl)
+            fbhi = jnp.zeros_like(bh)
+            fmlo = jnp.zeros_like(bl)
+            fmhi = jnp.zeros_like(bh)
+            for m in range(n_members):
+                okm = None
+                for a in range(len(srcs)):
+                    xh, xl = vals[a]
+                    inb = _ge(
+                        xh, xl, d["flo"][m, a, 0], d["flo"][m, a, 1]
+                    ) & _le(xh, xl, d["fhi"][m, a, 0], d["fhi"][m, a, 1])
+                    oka = inb | (d["fcon"][m, a] == 0)
+                    okm = oka if okm is None else okm & oka
+                fblo = fblo | jnp.where(okm, d["fbit"][m, 0], jnp.uint32(0))
+                fbhi = fbhi | jnp.where(okm, d["fbit"][m, 1], jnp.uint32(0))
+                fmlo = fmlo | d["fbit"][m, 0]
+                fmhi = fmhi | d["fbit"][m, 1]
+            bl = bl & (~fmlo | fblo)
+            bh = bh & (~fmhi | fbhi)
+        stats.append(
+            jnp.stack(
+                [
+                    jnp.sum(alive.astype(jnp.int32)),
+                    jnp.sum(matched.astype(jnp.int32)),
+                    jnp.sum(m_post.astype(jnp.int32)),
                 ]
-                for g in range(n_grants):
-                    gok = ((elo & d["gallow"][g, 0]) | (ehi & d["gallow"][g, 1])) != 0
-                    for a in range(grant_attrs):
-                        xh, xl = gvals[a]
-                        inb = _ge(xh, xl, d["glo"][g, a, 0], d["glo"][g, a, 1]) & _le(
-                            xh, xl, d["ghi"][g, a, 0], d["ghi"][g, a, 1]
-                        )
-                        gok = gok & (inb | (d["gcon"][g, a] == 0))
-                    plo = plo | jnp.where(gok, d["gbit"][g, 0], jnp.uint32(0))
-                    phi = phi | jnp.where(gok, d["gbit"][g, 1], jnp.uint32(0))
-            nbl = bl & jnp.where(matched, plo, jnp.uint32(0))
-            nbh = bh & jnp.where(matched, phi, jnp.uint32(0))
-            m_post = matched & ((nbl | nbh) != 0)
-            bl, bh = nbl, nbh
-            if filt is not None:
-                n_members, srcs = filt
-                vals = []
-                for a, src in enumerate(srcs):
-                    vh, vl = d["fvals"][a]
-                    if src == -1:
-                        vals.append((vh, vl))
-                    else:
-                        e2 = entries[src]
-                        s2 = jnp.where(e2 >= 0, e2, 0)
-                        vals.append((vh[s2], vl[s2]))
-                fblo = jnp.zeros_like(bl)
-                fbhi = jnp.zeros_like(bh)
-                fmlo = jnp.zeros_like(bl)
-                fmhi = jnp.zeros_like(bh)
-                for m in range(n_members):
-                    okm = None
-                    for a in range(len(srcs)):
-                        xh, xl = vals[a]
-                        inb = _ge(
-                            xh, xl, d["flo"][m, a, 0], d["flo"][m, a, 1]
-                        ) & _le(xh, xl, d["fhi"][m, a, 0], d["fhi"][m, a, 1])
-                        oka = inb | (d["fcon"][m, a] == 0)
-                        okm = oka if okm is None else okm & oka
-                    fblo = fblo | jnp.where(okm, d["fbit"][m, 0], jnp.uint32(0))
-                    fbhi = fbhi | jnp.where(okm, d["fbit"][m, 1], jnp.uint32(0))
-                    fmlo = fmlo | d["fbit"][m, 0]
-                    fmhi = fmhi | d["fbit"][m, 1]
-                bl = bl & (~fmlo | fblo)
-                bh = bh & (~fmhi | fbhi)
-            stats.append(
-                jnp.stack(
-                    [
-                        jnp.sum(alive.astype(jnp.int32)),
-                        jnp.sum(matched.astype(jnp.int32)),
-                        jnp.sum(m_post.astype(jnp.int32)),
-                    ]
-                )
             )
-
-        obl_ref[...] = bl
-        obh_ref[...] = bh
-        for s in range(n_stages):
-            oent_refs[s][...] = entries[s]
-        slot_counts = jnp.stack(
-            [
-                jnp.sum(((bl >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int32))
-                for j in range(32)
-            ]
-            + [
-                jnp.sum(((bh >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int32))
-                for j in range(32)
-            ]
         )
-        block_stats = jnp.stack(stats)
 
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            ostats_ref[...] = jnp.zeros(ostats_ref.shape, jnp.int32)
-            oslot_ref[...] = jnp.zeros(oslot_ref.shape, jnp.int32)
-
-        ostats_ref[...] = ostats_ref[...] + block_stats
-        oslot_ref[...] = oslot_ref[...] + slot_counts
-        if sink:
-            svlo, svhi = _translate(bl, bh, stlo, sthi)
-            oelo, oehi = _translate(bl, bh, selo, sehi)
-            osv_lo_ref[...] = svlo
-            osv_hi_ref[...] = svhi
-            ose_lo_ref[...] = oelo
-            ose_hi_ref[...] = oehi
-
-    return kernel
-
-
-@functools.lru_cache(maxsize=None)
-def _chain_fn(spec, block_n, interpret):
-    stages, sink = spec
-    n_stages = len(stages)
-    kinds = input_kinds(spec)
-    kernel = _build_kernel(spec)
-
-    @jax.jit
-    def run(*arrays):
-        n = arrays[0].shape[0]
-        block = n if block_n is None else block_n
-        grid = (n // block,)
-
-        def spec_of(kind, arr):
-            if kind == "row":
-                return pl.BlockSpec((block,), lambda i: (i,))
-            return pl.BlockSpec(arr.shape, lambda i, nd=arr.ndim: (0,) * nd)
-
-        in_specs = [spec_of(k, a) for k, a in zip(kinds, arrays)]
-        row_spec = pl.BlockSpec((block,), lambda i: (i,))
-        out_specs = [row_spec, row_spec]
-        out_shape = [
-            jax.ShapeDtypeStruct((n,), jnp.uint32),
-            jax.ShapeDtypeStruct((n,), jnp.uint32),
+    slot_counts = jnp.stack(
+        [
+            jnp.sum(((bl >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int32))
+            for j in range(32)
         ]
-        for _ in range(n_stages):
-            out_specs.append(row_spec)
-            out_shape.append(jax.ShapeDtypeStruct((n,), jnp.int32))
-        out_specs.append(pl.BlockSpec((n_stages, 3), lambda i: (0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((n_stages, 3), jnp.int32))
-        out_specs.append(pl.BlockSpec((64,), lambda i: (0,)))
-        out_shape.append(jax.ShapeDtypeStruct((64,), jnp.int32))
-        if sink:
-            for _ in range(4):
-                out_specs.append(row_spec)
-                out_shape.append(jax.ShapeDtypeStruct((n,), jnp.uint32))
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*arrays)
-
-    return run
+        + [
+            jnp.sum(((bh >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int32))
+            for j in range(32)
+        ]
+    )
+    out = [bl, bh, *entries, jnp.stack(stats), slot_counts]
+    if sink:
+        out += [*_translate(bl, bh, stlo, sthi), *_translate(bl, bh, selo, sehi)]
+    return tuple(out)
 
 
 @functools.lru_cache(maxsize=None)
-def _chain_fn_sharded(spec, block_n, interpret, mesh, axis_name):
+def _chain_fn(spec):
+    def graft_chain(*arrays):
+        with jax.named_scope("graft_chain"):
+            return _chain_body(spec, arrays)
+
+    return jax.jit(graft_chain)
+
+
+@functools.lru_cache(maxsize=None)
+def _chain_fn_sharded(spec, mesh, axis_name):
     """Shard-local chain launch (DESIGN.md §14): row inputs partitioned
-    over the mesh's data axis, state mirrors replicated, one pallas launch
+    over the mesh's data axis, state mirrors replicated, one chain program
     per shard inside shard_map. Stats/slot-count outputs are additive over
     row shards and psum'd so every shard (and the host) sees the global
     totals; row outputs stay sharded. Row buffers are donated off-CPU —
     the packed words and keys are dead after the launch, so the device
     reuses their memory for the outputs."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     stages, sink = spec
     n_stages = len(stages)
     kinds = input_kinds(spec)
-    inner = _chain_fn(spec, block_n, interpret)
     row = PartitionSpec(axis_name)
     rep = PartitionSpec()
     stats_i = 2 + n_stages
 
     def local(*arrays):
-        out = list(inner(*arrays))
+        with jax.named_scope("graft_chain"):
+            out = list(_chain_body(spec, arrays))
         out[stats_i] = jax.lax.psum(out[stats_i], axis_name)
         out[stats_i + 1] = jax.lax.psum(out[stats_i + 1], axis_name)
         return tuple(out)
@@ -428,12 +335,12 @@ def _chain_fn_sharded(spec, block_n, interpret, mesh, axis_name):
     if sink:
         out_specs += [row] * 4
     assert len(out_specs) == n_out
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(row if k == "row" else rep for k in kinds),
         out_specs=tuple(out_specs),
-        check_rep=False,
+        check_vma=False,
     )
     donate = ()
     if jax.default_backend() != "cpu":
@@ -443,8 +350,7 @@ def _chain_fn_sharded(spec, block_n, interpret, mesh, axis_name):
     return jax.jit(fn, donate_argnums=donate)
 
 
-def chain_launch(spec, arrays, *, block_n=None, interpret=True, mesh=None,
-                 axis_name="data"):
+def chain_launch(spec, arrays, *, mesh=None, axis_name="data"):
     """Dispatch one fused stage-chain launch.
 
     ``arrays`` must follow :func:`input_kinds`'s traversal, with every
@@ -462,5 +368,5 @@ def chain_launch(spec, arrays, *, block_n=None, interpret=True, mesh=None,
     counts are global. A 1-device mesh is bit-identical to the unsharded
     launch."""
     if mesh is None:
-        return _chain_fn(spec, block_n, interpret)(*arrays)
-    return _chain_fn_sharded(spec, block_n, interpret, mesh, axis_name)(*arrays)
+        return _chain_fn(spec)(*arrays)
+    return _chain_fn_sharded(spec, mesh, axis_name)(*arrays)

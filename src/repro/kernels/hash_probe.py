@@ -1,43 +1,46 @@
-"""Pallas TPU kernel: hash-join probe with a fused per-query state lens.
+"""Hash-join probe with a fused per-query state lens.
 
 The paper's hot spot (§4.3): probe a shared open-addressing hash-build state
 and emit, per probe key, the matching entry index — but only when the entry
 is visible to the probing query (visibility bitmask AND query mask), i.e.
 the per-query state lens is fused into the probe.
 
-TPU adaptation (DESIGN.md §2/§7): probe keys are tiled into VMEM blocks of
-``BLOCK_N``; the SoA table (keys / entry-visibility words) is VMEM-resident
-per kernel instance (slab-sized tables; the engine's sort-probe handles
-overflow sizes). The linear-probe loop is a bounded ``fori_loop`` of fully
-vectorized gathers+compares on the VPU — no pointer chasing.
+Device form (DESIGN.md §2/§7): the probes are jitted XLA programs over
+HBM-resident tables — a bounded linear-probe ``while_loop`` of fully
+vectorized gathers+compares (``probe_slots``), no pointer chasing. They are
+not Pallas kernels: Mosaic lowers only 2-D gathers, and a kernel's operands
+must fit VMEM, while one SF-1 state's table is 4M slots (16 MiB per int32
+array). The same programs run on every platform, so the CPU tests exercise
+what the chip runs. Each runs under the ``graft_probe`` name scope.
 
 Unique-key tables only (FK-keyed dimension states); the engine routes
 multi-match states through the reference path.
 
-``hash_probe_lens_multi`` is the multi-member variant (DESIGN.md §11): one
-launch returns, per probe key, the matched slot AND the matched entry's
-packed visibility word — the per-row ownership mask of every probing
-member at once. The host translates the word from state-slot space into
-pipeline ownership bits (``core.visibility.translate_bits``), so per-morsel
-kernel cost is independent of how many queries share the probe.
+``hash_probe_lens_multi64`` is the multi-member variant (DESIGN.md §11/§13):
+one launch returns, per probe key, the matched slot AND the matched entry's
+packed visibility word — the per-row ownership mask of every probing member
+at once. The host translates the word from state-slot space into pipeline
+ownership bits (``core.visibility.translate_bits``), so per-morsel device
+cost is independent of how many queries share the probe.
 
-``hash_build_insert`` is the batch-insert companion: one kernel call builds
-the whole open-addressing table from a key batch (linear-probe placement,
-bounded by ``MAX_PROBE``; duplicate keys or over-long clusters clear the
-``ok`` flag so the caller can fall back). The placement loop is sequential
-in-kernel — the win over host insertion is batching the dispatch, so the
-backend keeps it opt-in off-TPU.
+``hash_build_insert`` is the batch-insert companion, a Pallas kernel: one
+call builds the whole open-addressing table from a key batch (linear-probe
+placement, bounded by ``MAX_PROBE``; duplicate keys or over-long clusters
+clear the ``ok`` flag so the caller can fall back). The placement loop is
+sequential in-kernel, so the backend keeps it opt-in.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_N = 1024
+from . import default_interpret
+
 MAX_PROBE = 16
 EMPTY = -0x7FFFFFFF
 MULT = 2654435761
@@ -47,161 +50,53 @@ def _hash(keys: jnp.ndarray, mask) -> jnp.ndarray:
     return (keys.astype(jnp.uint32) * jnp.uint32(MULT)).astype(jnp.int32) & mask
 
 
-def _probe_kernel(probe_ref, tkeys_ref, tvis_ref, qmask_ref, out_ref):
-    tkeys = tkeys_ref[...]
-    tvis = tvis_ref[...]
-    qmask = qmask_ref[0]
-    cap_mask = jnp.int32(tkeys.shape[0] - 1)
-    keys = probe_ref[...]
-    pos = _hash(keys, cap_mask)
-    found = jnp.full(keys.shape, -1, jnp.int32)
-    done = jnp.zeros(keys.shape, jnp.bool_)
+def probe_slots(keys: jnp.ndarray, tkeys: jnp.ndarray) -> jnp.ndarray:
+    """Linear-probe scan: per key, the table slot holding it (-1 = absent).
 
-    def step(_, carry):
-        pos, found, done = carry
+    ``EMPTY`` keys (padding, dead rows) match nothing. The scan stops at an
+    empty slot, after ``MAX_PROBE`` slots, or once every key has resolved."""
+    cap_mask = jnp.int32(tkeys.shape[0] - 1)
+    pos = _hash(keys, cap_mask)
+    found0 = jnp.full(keys.shape, -1, jnp.int32)
+    done0 = keys == jnp.int32(EMPTY)
+
+    def cond(carry):
+        i, _pos, _found, done = carry
+        return (i < MAX_PROBE) & jnp.any(~done)
+
+    def body(carry):
+        i, pos, found, done = carry
         slot_keys = tkeys[pos]
         hit = (slot_keys == keys) & ~done
         empty = (slot_keys == jnp.int32(EMPTY)) & ~done
-        # state lens: entry visible to this query?
-        vis = (tvis[pos] & qmask) != 0
-        found = jnp.where(hit & vis, pos, found)
+        found = jnp.where(hit, pos, found)
         done = done | hit | empty
         pos = (pos + 1) & cap_mask
-        return pos, found, done
+        return i + 1, pos, found, done
 
-    _, found, _ = jax.lax.fori_loop(0, MAX_PROBE, step, (pos, found, done))
-    out_ref[...] = found
+    _, _, found, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), pos, found0, done0)
+    )
+    return found
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def hash_probe_lens(
     probe_keys: jnp.ndarray,  # [N] int32
     table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
-    table_vis: jnp.ndarray,  # [T] uint32 per-entry visibility words
+    table_vis: jnp.ndarray,  # [T] uint32 per-slot visibility words
     query_mask: jnp.ndarray,  # [1] uint32
-    *,
-    interpret: bool = True,
 ) -> jnp.ndarray:
-    n = probe_keys.shape[0]
-    pad = (-n) % BLOCK_N
-    pk = jnp.pad(probe_keys, (0, pad), constant_values=jnp.int32(EMPTY))
-    grid = (pk.shape[0] // BLOCK_N,)
-    out = pl.pallas_call(
-        _probe_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec(table_keys.shape, lambda i: (0,)),
-            pl.BlockSpec(table_vis.shape, lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(pk.shape, jnp.int32),
-        interpret=interpret,
-    )(pk, table_keys, table_vis, query_mask)
-    return out[:n]
+    """Single-query probe over slot-indexed visibility words: the matched
+    table slot per probe key (-1 = no visible match)."""
+    with jax.named_scope("graft_probe"):
+        slot = probe_slots(probe_keys, table_keys)
+        hit = slot >= 0
+        vis = (table_vis[jnp.where(hit, slot, 0)] & query_mask[0]) != 0
+        return jnp.where(hit & vis, slot, -1)
 
 
-def _probe_multi_kernel(probe_ref, tkeys_ref, tvis_ref, out_slot_ref, out_vis_ref):
-    tkeys = tkeys_ref[...]
-    tvis = tvis_ref[...]
-    cap_mask = jnp.int32(tkeys.shape[0] - 1)
-    keys = probe_ref[...]
-    pos = _hash(keys, cap_mask)
-    found = jnp.full(keys.shape, -1, jnp.int32)
-    vis = jnp.zeros(keys.shape, jnp.uint32)
-    done = jnp.zeros(keys.shape, jnp.bool_)
-
-    def step(_, carry):
-        pos, found, vis, done = carry
-        slot_keys = tkeys[pos]
-        hit = (slot_keys == keys) & ~done
-        empty = (slot_keys == jnp.int32(EMPTY)) & ~done
-        # multi-member lens: emit the whole packed visibility word — every
-        # probing member's ownership bit resolves from one gather
-        found = jnp.where(hit, pos, found)
-        vis = jnp.where(hit, tvis[pos], vis)
-        done = done | hit | empty
-        pos = (pos + 1) & cap_mask
-        return pos, found, vis, done
-
-    _, found, vis, _ = jax.lax.fori_loop(0, MAX_PROBE, step, (pos, found, vis, done))
-    out_slot_ref[...] = found
-    out_vis_ref[...] = vis
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hash_probe_lens_multi(
-    probe_keys: jnp.ndarray,  # [N] int32
-    table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
-    table_vis: jnp.ndarray,  # [T] uint32 per-entry visibility words
-    *,
-    interpret: bool = True,
-):
-    """Multi-member probe (§11): per probe key, the matched table slot
-    (-1 = no match, pre-visibility — the pair stream matches the reference
-    probe exactly) and the matched entry's packed visibility word. One
-    launch serves every probing member; the host maps the word to
-    pipeline ownership bits."""
-    n = probe_keys.shape[0]
-    pad = (-n) % BLOCK_N
-    pk = jnp.pad(probe_keys, (0, pad), constant_values=jnp.int32(EMPTY))
-    grid = (pk.shape[0] // BLOCK_N,)
-    found, vis = pl.pallas_call(
-        _probe_multi_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec(table_keys.shape, lambda i: (0,)),
-            pl.BlockSpec(table_vis.shape, lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(pk.shape, jnp.int32),
-            jax.ShapeDtypeStruct(pk.shape, jnp.uint32),
-        ],
-        interpret=interpret,
-    )(pk, table_keys, table_vis)
-    return found[:n], vis[:n]
-
-
-def _probe_lens64_kernel(
-    probe_ref, tkeys_ref, tentry_ref, evlo_ref, evhi_ref, qmask_ref, out_ref
-):
-    tkeys = tkeys_ref[...]
-    tentry = tentry_ref[...]
-    evlo = evlo_ref[...]
-    evhi = evhi_ref[...]
-    qlo = qmask_ref[0]
-    qhi = qmask_ref[1]
-    cap_mask = jnp.int32(tkeys.shape[0] - 1)
-    keys = probe_ref[...]
-    pos = _hash(keys, cap_mask)
-    found = jnp.full(keys.shape, -1, jnp.int32)
-    done = jnp.zeros(keys.shape, jnp.bool_)
-
-    def step(_, carry):
-        pos, found, done = carry
-        slot_keys = tkeys[pos]
-        hit = (slot_keys == keys) & ~done
-        empty = (slot_keys == jnp.int32(EMPTY)) & ~done
-        # 64-slot lens: the visibility word lives entry-indexed (split into
-        # uint32 halves), so a table rebuild never touches the mirror
-        entry = jnp.where(hit, tentry[pos], 0)
-        vis = ((evlo[entry] & qlo) | (evhi[entry] & qhi)) != 0
-        found = jnp.where(hit & vis, pos, found)
-        done = done | hit | empty
-        pos = (pos + 1) & cap_mask
-        return pos, found, done
-
-    _, found, _ = jax.lax.fori_loop(0, MAX_PROBE, step, (pos, found, done))
-    out_ref[...] = found
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def hash_probe_lens64(
     probe_keys: jnp.ndarray,  # [N] int32
     table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
@@ -209,109 +104,41 @@ def hash_probe_lens64(
     evis_lo: jnp.ndarray,  # [E] uint32 entry-indexed visibility low words
     evis_hi: jnp.ndarray,  # [E] uint32 entry-indexed visibility high words
     query_mask: jnp.ndarray,  # [2] uint32 (lo, hi) lens mask
-    *,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Single-query fused-lens probe over the full 64-slot space
     (DESIGN.md §13): visibility words are entry-indexed uint32 pairs, so
-    any slot 0..63 resolves in-kernel and rebuilds leave the mirror
+    any slot 0..63 resolves on device and rebuilds leave the mirror
     untouched. Returns the matched table slot per probe key (-1 = no
     visible match)."""
-    n = probe_keys.shape[0]
-    pad = (-n) % BLOCK_N
-    pk = jnp.pad(probe_keys, (0, pad), constant_values=jnp.int32(EMPTY))
-    grid = (pk.shape[0] // BLOCK_N,)
-    out = pl.pallas_call(
-        _probe_lens64_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec(table_keys.shape, lambda i: (0,)),
-            pl.BlockSpec(table_entry.shape, lambda i: (0,)),
-            pl.BlockSpec(evis_lo.shape, lambda i: (0,)),
-            pl.BlockSpec(evis_hi.shape, lambda i: (0,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(pk.shape, jnp.int32),
-        interpret=interpret,
-    )(pk, table_keys, table_entry, evis_lo, evis_hi, query_mask)
-    return out[:n]
+    with jax.named_scope("graft_probe"):
+        slot = probe_slots(probe_keys, table_keys)
+        hit = slot >= 0
+        entry = jnp.where(hit, table_entry[jnp.where(hit, slot, 0)], 0)
+        vis = (
+            (evis_lo[entry] & query_mask[0]) | (evis_hi[entry] & query_mask[1])
+        ) != 0
+        return jnp.where(hit & vis, slot, -1)
 
 
-def _probe_multi64_kernel(
-    probe_ref, tkeys_ref, tentry_ref, evlo_ref, evhi_ref,
-    out_slot_ref, out_lo_ref, out_hi_ref,
-):
-    tkeys = tkeys_ref[...]
-    tentry = tentry_ref[...]
-    evlo = evlo_ref[...]
-    evhi = evhi_ref[...]
-    cap_mask = jnp.int32(tkeys.shape[0] - 1)
-    keys = probe_ref[...]
-    pos = _hash(keys, cap_mask)
-    found = jnp.full(keys.shape, -1, jnp.int32)
-    done = jnp.zeros(keys.shape, jnp.bool_)
-
-    def step(_, carry):
-        pos, found, done = carry
-        slot_keys = tkeys[pos]
-        hit = (slot_keys == keys) & ~done
-        empty = (slot_keys == jnp.int32(EMPTY)) & ~done
-        found = jnp.where(hit, pos, found)
-        done = done | hit | empty
-        pos = (pos + 1) & cap_mask
-        return pos, found, done
-
-    _, found, _ = jax.lax.fori_loop(0, MAX_PROBE, step, (pos, found, done))
-    matched = found >= 0
-    entry = jnp.where(matched, tentry[jnp.where(matched, found, 0)], 0)
-    out_slot_ref[...] = found
-    out_lo_ref[...] = jnp.where(matched, evlo[entry], jnp.uint32(0))
-    out_hi_ref[...] = jnp.where(matched, evhi[entry], jnp.uint32(0))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def hash_probe_lens_multi64(
     probe_keys: jnp.ndarray,  # [N] int32
     table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
     table_entry: jnp.ndarray,  # [T] int32 slot -> entry index
     evis_lo: jnp.ndarray,  # [E] uint32 entry-indexed visibility low words
     evis_hi: jnp.ndarray,  # [E] uint32 entry-indexed visibility high words
-    *,
-    interpret: bool = True,
 ):
     """Multi-member probe returning the full uint64 lens word as (lo, hi)
-    uint32 halves (DESIGN.md §13): like ``hash_probe_lens_multi`` but
-    serving all 64 slots from entry-indexed (rebuild-invariant) mirrors.
-    The pair stream is pre-visibility and identical to ``probe``."""
-    n = probe_keys.shape[0]
-    pad = (-n) % BLOCK_N
-    pk = jnp.pad(probe_keys, (0, pad), constant_values=jnp.int32(EMPTY))
-    grid = (pk.shape[0] // BLOCK_N,)
-    found, wlo, whi = pl.pallas_call(
-        _probe_multi64_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec(table_keys.shape, lambda i: (0,)),
-            pl.BlockSpec(table_entry.shape, lambda i: (0,)),
-            pl.BlockSpec(evis_lo.shape, lambda i: (0,)),
-            pl.BlockSpec(evis_hi.shape, lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(pk.shape, jnp.int32),
-            jax.ShapeDtypeStruct(pk.shape, jnp.uint32),
-            jax.ShapeDtypeStruct(pk.shape, jnp.uint32),
-        ],
-        interpret=interpret,
-    )(pk, table_keys, table_entry, evis_lo, evis_hi)
-    return found[:n], wlo[:n], whi[:n]
+    uint32 halves (DESIGN.md §13), served for all 64 slots from
+    entry-indexed (rebuild-invariant) mirrors. The pair stream is
+    pre-visibility and identical to ``probe``."""
+    with jax.named_scope("graft_probe"):
+        found = probe_slots(probe_keys, table_keys)
+        matched = found >= 0
+        entry = jnp.where(matched, table_entry[jnp.where(matched, found, 0)], 0)
+        wlo = jnp.where(matched, evis_lo[entry], jnp.uint32(0))
+        whi = jnp.where(matched, evis_hi[entry], jnp.uint32(0))
+        return found, wlo, whi
 
 
 def _insert_kernel(keys_ref, tkeys_ref, tentry_ref, ok_ref):
@@ -353,7 +180,7 @@ def hash_build_insert(
     keys: jnp.ndarray,  # [N] int32, no EMPTY values
     capacity: int,  # power of two, >= 2 * N
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Batch-insert ``keys`` into a fresh open-addressing table.
 
@@ -369,5 +196,5 @@ def hash_build_insert(
             jax.ShapeDtypeStruct((capacity,), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(keys)

@@ -1,25 +1,20 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the data plane's device programs and kernels.
 
-On this CPU container the kernels execute via ``interpret=True`` (the kernel
-body runs in Python for correctness validation); on TPU set
-``interpret=False`` (or rely on the platform default) for the compiled
-VMEM-tiled versions.
+The probe is a jitted XLA program on every platform. The Pallas kernels
+take ``interpret`` from the platform (``default_interpret``): compiled on
+the TPU, interpreted elsewhere.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import default_interpret
 from .flash_attention import flash_attention
 from .hash_probe import EMPTY, hash_build_insert, hash_probe_lens
 from .linrec import linrec
 from .seg_aggregate import seg_aggregate
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def build_hash_table(keys: np.ndarray, vis: np.ndarray, load: float = 0.5):
@@ -47,7 +42,6 @@ def build_insert(keys, capacity=None, interpret=None):
     """In-kernel batch build of the open-addressing table (the device-side
     counterpart of ``build_hash_table``). Returns (table_keys, table_entry,
     ok) — ``ok[0] == 0`` flags duplicate keys / over-long probe chains."""
-    interpret = default_interpret() if interpret is None else interpret
     n = len(keys)
     if capacity is None:
         # default to <=25% load: keeps clusters well inside the kernel's
@@ -60,19 +54,16 @@ def build_insert(keys, capacity=None, interpret=None):
     )
 
 
-def probe(probe_keys, table_keys, table_vis, query_mask, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
+def probe(probe_keys, table_keys, table_vis, query_mask):
     return hash_probe_lens(
         jnp.asarray(probe_keys, jnp.int32),
         table_keys,
         table_vis,
         jnp.asarray(query_mask, jnp.uint32).reshape(1),
-        interpret=interpret,
     )
 
 
 def segmented_sum(codes, values, n_groups, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
     return seg_aggregate(
         jnp.asarray(codes, jnp.int32), jnp.asarray(values), n_groups, interpret=interpret
     )

@@ -10,10 +10,13 @@ sequential TPU grid (accumulate-in-place pattern).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import default_interpret
 
 BLOCK_N = 512
 
@@ -39,7 +42,7 @@ def seg_aggregate(
     values: jnp.ndarray,  # [N, V] float
     n_groups: int,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     n, v = values.shape
     pad = (-n) % BLOCK_N
@@ -55,6 +58,6 @@ def seg_aggregate(
         ],
         out_specs=pl.BlockSpec((n_groups, v), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_groups, v), jnp.float32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(codes_p, vals_p)
     return out

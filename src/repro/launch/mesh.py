@@ -9,18 +9,25 @@ everything else (tests, benches) sees the single real device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model and data plane place arrays through GSPMD
+    # sharding constraints, not jax.make_mesh's default explicit-axis typing
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """Single-device mesh with the production axis names — lets the same
     sharding rules run in tests on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_data_mesh(n_data: int):
@@ -30,7 +37,7 @@ def make_data_mesh(n_data: int):
     n_data = int(n_data)
     if n_data < 1:
         raise ValueError(f"data-axis size must be >= 1, got {n_data}")
-    return jax.make_mesh((n_data, 1), ("data", "model"))
+    return _mesh((n_data, 1), ("data", "model"))
 
 
 def resolve_mesh(spec):

@@ -29,7 +29,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -142,12 +141,12 @@ def make_partitioned_join(
         overflow = jax.lax.psum(ob + op_, axis_name)
         return out, hit, pk2, overflow
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_k, spec_v, spec_k, spec_v),
         out_specs=(spec_v, spec_k, spec_k, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -169,12 +168,12 @@ def make_partitioned_exchange(
         k2, v2, ok, ov = repartition_by_key(keys, vals, axis_name, n, capacity, dest=dest)
         return k2, v2, ok, jax.lax.psum(ov, axis_name)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_k, spec_v, spec_k),
         out_specs=(spec_k, spec_v, spec_k, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -282,8 +281,8 @@ def make_partitioned_aggregate(
         partial = jnp.einsum("rg,rw->gw", onehot, vals)
         return jax.lax.psum(partial, axis_name)
 
-    fn = shard_map(
-        local, mesh=mesh, in_specs=(spec_g, spec_v), out_specs=P(None, None), check_rep=False
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec_g, spec_v), out_specs=P(None, None), check_vma=False
     )
     return jax.jit(fn)
 

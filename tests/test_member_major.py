@@ -6,7 +6,7 @@ and the virtual clock (a cost divergence would reorder scheduling) are
 compared across fuzzer-seeded workloads in all 5 execution modes. The
 >64-member overflow slow lane is exercised end-to-end (members beyond the
 packed word must fall back soundly, never silently drop rows), and the
-multi-member kernel lens (``hash_probe_lens_multi``) is checked against
+multi-member kernel lens (``hash_probe_lens_multi64``) is checked against
 the state's own probe + visibility words.
 
 Uses ``tests/_hypothesis_compat.py`` so tier-1 passes without hypothesis.
