@@ -39,6 +39,7 @@ from typing import Optional, Protocol, Tuple, runtime_checkable
 import numpy as np
 
 from ..core.state import SharedHashBuildState, _bincount_segment_sum
+from ..core.tracing import span, spanned
 from ..core.visibility import join_words, split_words
 
 #: chain-level / probe-level decline reasons (DESIGN.md §13). ``grants``,
@@ -122,17 +123,6 @@ def _pad(a: np.ndarray, length: int, fill=0) -> np.ndarray:
     if len(a) < length:
         a = np.concatenate([a, np.full(length - len(a), fill, dtype=a.dtype)])
     return a
-
-
-def _device_keys(keycodes: np.ndarray):
-    """Probe keys as a power-of-two-length int32 device array; the EMPTY
-    padding matches nothing and is sliced off the results."""
-    import jax.numpy as jnp
-
-    from ..kernels.hash_probe import EMPTY
-
-    kc = np.asarray(keycodes, dtype=np.int32)
-    return jnp.asarray(_pad(kc, _pow2(len(kc)), EMPTY))
 
 
 class _ProbeTable:
@@ -280,6 +270,13 @@ class PallasBackend:
         self.mirror_full_regathers = 0
         self.mirror_patched_rows = 0
         self.fallback_reasons = {r: 0 for r in FALLBACK_REASONS}
+        # transfer and padding accounting: bytes of every array handed to
+        # or read back from the device, and the real and padded rows of
+        # every probe and chain launch
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.device_rows = 0
+        self.device_padded_rows = 0
 
     def stats(self) -> dict:
         """Kernel-dispatch counters (surfaced via ``Session.stats``).
@@ -297,6 +294,10 @@ class PallasBackend:
             "chain_launches": self.chain_launches,
             "mirror_full_regathers": self.mirror_full_regathers,
             "mirror_patched_rows": self.mirror_patched_rows,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
+            "device_rows": self.device_rows,
+            "device_padded_rows": self.device_padded_rows,
         }
         for r in FALLBACK_REASONS:
             out[f"fallback_{r}"] = self.fallback_reasons[r]
@@ -317,7 +318,46 @@ class PallasBackend:
         if counters is not None:
             counters[f"fallback_probes_{reason}"] += 1
 
+    # -- host<->device transfers ---------------------------------------------
+    def _h2d(self, a):
+        """Every host->device copy the backend makes: ``jnp.asarray``,
+        counted in ``h2d_bytes`` at the device array's size."""
+        import jax.numpy as jnp
+
+        with span("graftdb.h2d"):
+            out = jnp.asarray(a)
+        self.h2d_bytes += out.nbytes
+        return out
+
+    def _d2h(self, *outs):
+        """Read a launch's outputs back, counted in ``d2h_bytes``. Every
+        copy is queued behind the launch first, as ``jax.device_get``
+        does, so the host's wait for the device and its wait for the
+        copies are timed apart without a round trip between them."""
+        import jax
+
+        for o in outs:
+            o.copy_to_host_async()
+        with span("graftdb.device_wait"):
+            jax.block_until_ready(outs)
+        with span("graftdb.d2h"):
+            host = [np.asarray(o) for o in outs]
+        self.d2h_bytes += sum(h.nbytes for h in host)
+        return host
+
+    def _device_keys(self, keycodes: np.ndarray):
+        """Probe keys as a power-of-two-length int32 device array; the EMPTY
+        padding matches nothing and is sliced off the results."""
+        from ..kernels.hash_probe import EMPTY
+
+        kc = np.asarray(keycodes, dtype=np.int32)
+        npad = _pow2(len(kc))
+        self.device_rows += len(kc)
+        self.device_padded_rows += npad
+        return self._h2d(_pad(kc, npad, EMPTY))
+
     # -- probe ---------------------------------------------------------------
+    @spanned("graftdb.backend.probe")
     def probe(self, state, keycodes, counters=None):
         if state.keycode.n == 0 or len(keycodes) == 0:
             return np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64)
@@ -330,19 +370,19 @@ class PallasBackend:
             self.fallback_probes += 1
             self.note_fallback("keyrange", counters)
             return self._ref.probe(state, keycodes)
-        import jax.numpy as jnp
-
         tkeys, tones, slot_entry = table
         if self._qmask is None:  # lens off: pure key match
-            self._qmask = jnp.asarray([0xFFFFFFFF], dtype=jnp.uint32)
-        found_slots = np.asarray(
-            self._hash_probe_lens(_device_keys(keycodes), tkeys, tones, self._qmask)
-        )[: len(keycodes)]
+            self._qmask = self._h2d(np.array([0xFFFFFFFF], dtype=np.uint32))
+        (found_slots,) = self._d2h(
+            self._hash_probe_lens(self._device_keys(keycodes), tkeys, tones, self._qmask)
+        )
+        found_slots = found_slots[: len(keycodes)]
         self.kernel_probes += 1
         probe_idx = np.flatnonzero(found_slots >= 0).astype(np.int64)
         entry_idx = slot_entry[found_slots[probe_idx]]
         return probe_idx, entry_idx
 
+    @spanned("graftdb.backend.probe_visible")
     def probe_visible(self, state, keycodes, qid):
         """Single-query probe with the state lens fused in-kernel.
 
@@ -364,28 +404,28 @@ class PallasBackend:
         table = self._table_for(state)
         if table is None or keycodes.min() < 0 or keycodes.max() > self._KEY_LIMIT:
             return None
-        import jax.numpy as jnp
-
         ent = self._tables[state]
         self._sync_mirrors(ent, state)
         mask = np.uint64(1) << np.uint64(slot)
         mlo, mhi = split_words(np.array([mask], dtype=np.uint64))
-        found = np.asarray(
+        (found,) = self._d2h(
             self._hash_probe_lens64(
-                _device_keys(keycodes),
+                self._device_keys(keycodes),
                 ent.jkeys,
                 ent.jentry,
                 ent.jvlo,
                 ent.jvhi,
-                jnp.asarray(np.array([mlo[0], mhi[0]], dtype=np.uint32)),
+                self._h2d(np.array([mlo[0], mhi[0]], dtype=np.uint32)),
             )
-        )[: len(keycodes)]
+        )
+        found = found[: len(keycodes)]
         self.kernel_probes += 1
         self.kernel_lens_probes += 1
         probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
         entry_idx = ent.slot_entry[found[probe_idx]]
         return probe_idx, entry_idx
 
+    @spanned("graftdb.backend.probe_visible_multi")
     def probe_visible_multi(self, state, keycodes):
         """Multi-member probe with the packed lens words gathered in-kernel
         (§11): returns ``(probe_idx, entry_idx, vis_words)`` where
@@ -402,20 +442,21 @@ class PallasBackend:
             return None
         ent = self._tables[state]
         self._sync_mirrors(ent, state)
-        found, wlo, whi = self._hash_probe_lens_multi64(
-            _device_keys(keycodes), ent.jkeys, ent.jentry, ent.jvlo, ent.jvhi
+        found, wlo, whi = self._d2h(
+            *self._hash_probe_lens_multi64(
+                self._device_keys(keycodes), ent.jkeys, ent.jentry, ent.jvlo, ent.jvhi
+            )
         )
-        found = np.asarray(found)[: len(keycodes)]
+        found = found[: len(keycodes)]
         self.kernel_probes += 1
         self.kernel_multi_probes += 1
         probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
         entry_idx = ent.slot_entry[found[probe_idx]]
-        vis_words = join_words(
-            np.asarray(wlo)[probe_idx], np.asarray(whi)[probe_idx]
-        )
+        vis_words = join_words(wlo[probe_idx], whi[probe_idx])
         return probe_idx, entry_idx, vis_words
 
     # -- fused stage chain (DESIGN.md §13) -----------------------------------
+    @spanned("graftdb.backend.probe_chain")
     def probe_chain(self, cplan, cols, bits, host_keys, counters=None):
         """One fused launch for a morsel's entire stage chain.
 
@@ -489,14 +530,14 @@ class PallasBackend:
                     self.note_fallback("keyrange", counters)
                     return None
 
-        import jax.numpy as jnp
-
         from ..kernels.hash_probe import EMPTY
 
         npad = _pow2(n)
+        self.device_rows += n
+        self.device_padded_rows += npad
 
         def pad_row(a, fill=0):
-            return jnp.asarray(_pad(a, npad, fill))
+            return self._h2d(_pad(a, npad, fill))
 
         blo, bhi = split_words(bits)
         arrays = [pad_row(blo), pad_row(bhi)]
@@ -520,10 +561,7 @@ class PallasBackend:
             tt = dev.get(("tt", si))
             if tt is None:
                 tlo, thi = split_words(st["tables"].ravel())
-                tt = (
-                    jnp.asarray(tlo.reshape(8, 256)),
-                    jnp.asarray(thi.reshape(8, 256)),
-                )
+                tt = (self._h2d(tlo.reshape(8, 256)), self._h2d(thi.reshape(8, 256)))
                 dev[("tt", si)] = tt
             arrays += [tt[0], tt[1]]
             n_grants = len(st["grants"])
@@ -568,33 +606,23 @@ class PallasBackend:
                 vt, et = sink
                 vlo, vhi = split_words(vt.ravel())
                 elo, ehi = split_words(et.ravel())
-                sp = tuple(
-                    jnp.asarray(x.reshape(8, 256)) for x in (vlo, vhi, elo, ehi)
-                )
+                sp = tuple(self._h2d(x.reshape(8, 256)) for x in (vlo, vhi, elo, ehi))
                 dev["sink"] = sp
             arrays += list(sp)
         spec = (tuple(spec_stages), sink is not None)
-        out = self._chain_launch(spec, tuple(arrays), mesh=self.mesh)
-        self.chain_devices.update(out[0].devices())
+        dev_out = self._chain_launch(spec, tuple(arrays), mesh=self.mesh)
+        self.chain_devices.update(dev_out[0].devices())
+        out = self._d2h(*dev_out)
         n_stages = len(stages)
         res = {
-            "bits": join_words(np.asarray(out[0])[:n], np.asarray(out[1])[:n]),
-            "entries": [
-                np.asarray(out[2 + s])[:n].astype(np.int64)
-                for s in range(n_stages)
-            ],
-            "stats": np.asarray(out[2 + n_stages]).astype(np.int64),
-            "slots": np.asarray(out[3 + n_stages]).astype(np.int64),
+            "bits": join_words(out[0][:n], out[1][:n]),
+            "entries": [out[2 + s][:n].astype(np.int64) for s in range(n_stages)],
+            "stats": out[2 + n_stages].astype(np.int64),
+            "slots": out[3 + n_stages].astype(np.int64),
         }
         if sink is not None:
-            res["vismask"] = join_words(
-                np.asarray(out[4 + n_stages])[:n],
-                np.asarray(out[5 + n_stages])[:n],
-            )
-            res["emask"] = join_words(
-                np.asarray(out[6 + n_stages])[:n],
-                np.asarray(out[7 + n_stages])[:n],
-            )
+            res["vismask"] = join_words(out[4 + n_stages][:n], out[5 + n_stages][:n])
+            res["emask"] = join_words(out[6 + n_stages][:n], out[7 + n_stages][:n])
         self.kernel_probes += 1
         self.chain_launches += 1
         stats = res["stats"]
@@ -612,8 +640,6 @@ class PallasBackend:
         union attr list, per-grant split bit/allowed words, and the
         per-(grant, attr) constrained flags + total-order interval bounds
         (unconstrained cells carry flag 0 and the full [-inf, inf] band)."""
-        import jax.numpy as jnp
-
         from ..kernels.fused_chain import total_order_bound
 
         attrs = []
@@ -640,20 +666,11 @@ class PallasBackend:
                 gcon[g, j] = 1
                 glo[g, j] = total_order_bound(blo)
                 ghi[g, j] = total_order_bound(bhi)
-        return (
-            tuple(attrs),
-            jnp.asarray(gbit),
-            jnp.asarray(gallow),
-            jnp.asarray(gcon),
-            jnp.asarray(glo),
-            jnp.asarray(ghi),
-        )
+        return (tuple(attrs),) + tuple(self._h2d(a) for a in (gbit, gallow, gcon, glo, ghi))
 
     def _filter_params(self, f):
         """Device matrices of one stage's fused interval filter: bounds as
         total-order uint32 pairs, constrained flags, split member bits."""
-        import jax.numpy as jnp
-
         n_m = f["n_members"]
         n_a = len(f["attrs"])
         lh, ll = self._total_order_u32(np.asarray(f["lo"], np.float64).ravel())
@@ -663,28 +680,19 @@ class PallasBackend:
         fcon = np.asarray(f["con"], np.int32).reshape(n_m, n_a)
         blo, bhi = split_words(np.asarray(f["bitvals"], np.uint64))
         fbit = np.stack([blo, bhi], axis=-1)
-        return (
-            jnp.asarray(flo),
-            jnp.asarray(fhi),
-            jnp.asarray(fcon),
-            jnp.asarray(fbit),
-        )
+        return tuple(self._h2d(a) for a in (flo, fhi, fcon, fbit))
 
     # -- entry-indexed device mirrors ----------------------------------------
     def _upload(self, vals, cap):
-        import jax.numpy as jnp
-
         if len(vals) < cap:
             vals = np.pad(vals, (0, cap - len(vals)))
-        return jnp.asarray(vals)
+        return self._h2d(vals)
 
     def _patch(self, buf, idx, vals):
         """Scatter ``vals`` into the device mirror at entry ids ``idx``.
         Index/value lengths pad to the next power of two (repeating the
         first element — duplicate same-value writes are benign) so the
         jitted scatter compiles O(log n) shapes, not one per batch size."""
-        import jax.numpy as jnp
-
         m = len(idx)
         cap = 1
         while cap < m:
@@ -693,8 +701,9 @@ class PallasBackend:
         if cap != m:
             idx = np.concatenate([idx, np.full(cap - m, idx[0], dtype=np.int32)])
             vals = np.concatenate([vals, np.full(cap - m, vals[0], dtype=vals.dtype)])
-        return _scatter_set(self._donate)(buf, jnp.asarray(idx), jnp.asarray(vals))
+        return _scatter_set(self._donate)(buf, self._h2d(idx), self._h2d(vals))
 
+    @spanned("graftdb.backend.sync_mirrors")
     def _sync_mirrors(self, ent, state, need_em=False, ord_attrs=(), key_attrs=()):
         """Bring the entry-indexed device mirrors up to the state's SoA.
 
@@ -830,6 +839,7 @@ class PallasBackend:
                 return None
         return ent.jkeys, ent.jones, ent.slot_entry
 
+    @spanned("graftdb.backend.insert_keys")
     def _insert_keys(self, ent: "_ProbeTable", keys, n: int) -> None:
         """Insert keys[ent.n:n] into the table, rebuilding at a larger
         capacity when the 50% load factor would be exceeded. Insertion is
@@ -864,8 +874,8 @@ class PallasBackend:
         import jax.numpy as jnp
 
         ent.n = n
-        ent.jkeys = jnp.asarray(ent.tkeys)
-        ent.jentry = jnp.asarray(ent.slot_entry.astype(np.int32))
+        ent.jkeys = self._h2d(ent.tkeys)
+        ent.jentry = self._h2d(ent.slot_entry.astype(np.int32))
         if ent.jones is None or ent.jones.shape[0] != len(ent.tkeys):
             ent.jones = jnp.ones(len(ent.tkeys), dtype=jnp.uint32)
 
@@ -923,15 +933,16 @@ class PallasBackend:
 
     def _kernel_rebuild(self, ent: "_ProbeTable", keys, cap: int) -> bool:
         """Full-table rebuild through the Pallas batch-insert kernel."""
-        import jax.numpy as jnp
-
-        tkeys, tentry, ok = self._hash_build_insert(
-            jnp.asarray(keys, dtype=jnp.int32), capacity=cap, interpret=self.interpret
+        tkeys, tentry, ok = self._d2h(
+            *self._hash_build_insert(
+                self._h2d(np.asarray(keys, dtype=np.int32)), capacity=cap,
+                interpret=self.interpret,
+            )
         )
-        if int(np.asarray(ok)[0]) == 0:
+        if int(ok[0]) == 0:
             return False
-        ent.tkeys = np.asarray(tkeys)
-        ent.slot_entry = np.asarray(tentry, dtype=np.int64)
+        ent.tkeys = tkeys
+        ent.slot_entry = tentry.astype(np.int64)
         return True
 
     # -- segmented aggregation ------------------------------------------------
@@ -940,20 +951,20 @@ class PallasBackend:
             return np.zeros(n_groups, dtype=np.float64)
         if not self.use_agg_kernel or n_groups > self.max_kernel_groups:
             return self._ref.segment_sum(gids, values, n_groups)
-        import jax.numpy as jnp
-
         vals = (
             np.ones((len(gids), 1))
             if values is None
             else np.asarray(values, dtype=np.float64).reshape(-1, 1)
         )
-        out = self._seg_aggregate(
-            jnp.asarray(gids, dtype=jnp.int32),
-            jnp.asarray(vals, dtype=jnp.float32),
-            n_groups,
-            interpret=self.interpret,
+        (out,) = self._d2h(
+            self._seg_aggregate(
+                self._h2d(np.asarray(gids, dtype=np.int32)),
+                self._h2d(np.asarray(vals, dtype=np.float32)),
+                n_groups,
+                interpret=self.interpret,
+            )
         )
-        return np.asarray(out, dtype=np.float64)[:, 0]
+        return out.astype(np.float64)[:, 0]
 
 
 def resolve_backend(spec) -> ExecutionBackend:

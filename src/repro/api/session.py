@@ -232,7 +232,9 @@ class Session:
         return self._engine
 
     def worker_stats(self) -> Dict[str, object]:
-        """Per-worker utilization of the partition-parallel pool (§9)."""
+        """Per-worker utilization of the partition-parallel pool (§9):
+        modelled seconds under the work clock, host seconds measured
+        around each unit under the wall clock (``Runner.worker_stats``)."""
         return self._runner.worker_stats()
 
     def mesh_stats(self) -> Dict[str, object]:

@@ -33,6 +33,7 @@ from .predicates import TRUE, Conjunction
 from .reuse import ReusePlane
 from .runtime import AggGate, AggSink, Gate, Member, Pipeline, ProbeOp, ScanNode
 from .state import SharedAggregateState, SharedHashBuildState, StateLifecycle
+from .tracing import span
 
 
 @dataclass(frozen=True)
@@ -532,16 +533,17 @@ class GraftEngine:
     def _maybe_complete(self, handle: QueryHandle) -> bool:
         if handle.done or handle.agg_gate is None or not handle.agg_gate.open():
             return False
-        result = handle.agg_state.result()
-        if handle.orderby is not None:
-            result = _apply_orderby(result, handle.orderby)
-        handle.result = result
-        handle.t_complete = self.clock.now if self.clock is not None else 0.0
-        handle.done = True
-        self.active_handles.remove(handle)
-        self.completed.append(handle)
-        self.counters["completed"] += 1
-        self._release(handle)
+        with span("graftdb.complete", qid=handle.qid):
+            result = handle.agg_state.result()
+            if handle.orderby is not None:
+                result = _apply_orderby(result, handle.orderby)
+            handle.result = result
+            handle.t_complete = self.clock.now if self.clock is not None else 0.0
+            handle.done = True
+            self.active_handles.remove(handle)
+            self.completed.append(handle)
+            self.counters["completed"] += 1
+            self._release(handle)
         return True
 
     def _release(self, handle: QueryHandle) -> None:

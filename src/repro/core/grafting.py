@@ -43,6 +43,7 @@ from .runtime import (
     encode_keys,
 )
 from .state import SharedHashBuildState
+from .tracing import span
 
 # ---------------------------------------------------------------------------
 # Plan walking
@@ -301,6 +302,11 @@ def resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
     """Resolve one stateful boundary of query ``handle`` bottom-up:
     select-or-create the shared state, partition the state-side extent, and
     install producer obligations and the state-readiness gate."""
+    with span("graftdb.graft", qid=handle.qid):
+        return _resolve_boundary(engine, handle, join)
+
+
+def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
     qid = handle.qid
     mode = engine.mode
     sig, b_q = boundary_key(join)

@@ -72,6 +72,7 @@ from .state import (
     SharedHashBuildState,
     _bincount_segment_sum,
 )
+from .tracing import span, spanned
 from .visibility import (
     SlotAllocator,
     bit_of,
@@ -660,6 +661,7 @@ class Pipeline:
             bits |= np.where(mask, m.bitval, U64_0)
         return bits
 
+    @spanned("graftdb.plan")
     def _member_major_plan(self, act: List[Member]) -> dict:
         """Per-wave member-major execution plan (§11), cached on the active
         member set: per-stage lens translation tables + grant fallbacks,
@@ -911,13 +913,13 @@ class Pipeline:
         cost = 0.0
         plan = self._member_major_plan(act)
 
-        bits = self._source_bits(act, cols, n, engine)
+        with span("graftdb.filter"):
+            bits = self._source_bits(act, cols, n, engine)
+            keep = np.flatnonzero(bits)
+            cols = {k: v[keep] for k, v in cols.items()}
+            bits = bits[keep]
+            did = row_ids[keep].astype(np.int64)
         cost += cm["filter"] * n * len(act)
-
-        keep = np.flatnonzero(bits)
-        cols = {k: v[keep] for k, v in cols.items()}
-        bits = bits[keep]
-        did = row_ids[keep].astype(np.int64)
 
         # mesh execution (§14): record the morsel's first-stage repartition
         # in the per-device histogram — stage-0 keys are identical whether
@@ -934,136 +936,140 @@ class Pipeline:
             getattr(backend, "probe_chain", None) if backend is not None else None
         )
         if cplan is not None and probe_chain is not None and len(did) > 0:
-            if cplan["ok"]:
-                # one fused launch for the whole stage chain (§13); host
-                # keys validated backend-side over the full morsel — any
-                # dynamic decline falls through to the staged loop below
-                host_keys = {
-                    si: encode_keys(cols, st["key"][1])
-                    for si, st in enumerate(cplan["stages"])
-                    if st["key"][0] == "host"
-                }
-                res = probe_chain(
-                    cplan, cols, bits, host_keys, counters=engine.counters
-                )
-                if res is not None:
-                    engine.counters["kernel_chain_launches"] += 1
-                    cost, cols, bits, did, chain_sink = self._replay_chain(
-                        engine, plan, cplan, res, cols, did, cost
+            with span("graftdb.join"):
+                if cplan["ok"]:
+                    # one fused launch for the whole stage chain (§13); host
+                    # keys validated backend-side over the full morsel — any
+                    # dynamic decline falls through to the staged loop below
+                    host_keys = {
+                        si: encode_keys(cols, st["key"][1])
+                        for si, st in enumerate(cplan["stages"])
+                        if st["key"][0] == "host"
+                    }
+                    res = probe_chain(
+                        cplan, cols, bits, host_keys, counters=engine.counters
                     )
-                    served = True
-            else:
-                backend.note_fallback(cplan["reason"], engine.counters)
+                    if res is not None:
+                        engine.counters["kernel_chain_launches"] += 1
+                        cost, cols, bits, did, chain_sink = self._replay_chain(
+                            engine, plan, cplan, res, cols, did, cost
+                        )
+                        served = True
+                else:
+                    backend.note_fallback(cplan["reason"], engine.counters)
         for stage, op in enumerate(self.ops):
             if served or len(did) == 0:
                 break
-            keycodes = encode_keys(cols, op.probe_attrs)
-            vis_tables, grant_members, kernelable = plan["stages"][stage]
-            lens_fused = False
-            words = None
-            if backend is not None:
-                if len(act) == 1 and not grant_members:
-                    probe_visible = getattr(backend, "probe_visible", None)
-                    if probe_visible is not None:
-                        fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
-                        if fused_pair is not None:
-                            probe_idx, entry_idx = fused_pair
-                            lens_fused = True
-                            engine.counters["kernel_lens_probes"] += 1
-                elif kernelable and len(act) > 1:
-                    # multi-member lens: one launch returns every probing
-                    # member's ownership word (the matched entry's packed
-                    # visibility word), translated below
-                    probe_multi = getattr(backend, "probe_visible_multi", None)
-                    if probe_multi is not None:
-                        trip = probe_multi(op.state, keycodes)
-                        if trip is not None:
-                            probe_idx, entry_idx, words = trip
-                            engine.counters["kernel_multi_lens_probes"] += 1
-                if not lens_fused and words is None:
-                    probe_idx, entry_idx = _backend_probe(
-                        backend, op.state, keycodes, engine.counters
+            with span("graftdb.join"):
+                keycodes = encode_keys(cols, op.probe_attrs)
+                vis_tables, grant_members, kernelable = plan["stages"][stage]
+                lens_fused = False
+                words = None
+                if backend is not None:
+                    if len(act) == 1 and not grant_members:
+                        probe_visible = getattr(backend, "probe_visible", None)
+                        if probe_visible is not None:
+                            fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
+                            if fused_pair is not None:
+                                probe_idx, entry_idx = fused_pair
+                                lens_fused = True
+                                engine.counters["kernel_lens_probes"] += 1
+                    elif kernelable and len(act) > 1:
+                        # multi-member lens: one launch returns every probing
+                        # member's ownership word (the matched entry's packed
+                        # visibility word), translated below
+                        probe_multi = getattr(backend, "probe_visible_multi", None)
+                        if probe_multi is not None:
+                            trip = probe_multi(op.state, keycodes)
+                            if trip is not None:
+                                probe_idx, entry_idx, words = trip
+                                engine.counters["kernel_multi_lens_probes"] += 1
+                    if not lens_fused and words is None:
+                        probe_idx, entry_idx = _backend_probe(
+                            backend, op.state, keycodes, engine.counters
+                        )
+                else:
+                    probe_idx, entry_idx = op.state.probe(keycodes)
+                if engine.mesh_plan is not None:
+                    # §14: probe rows cross the bucketed all_to_all to their
+                    # key shard's device before the shard-local probe
+                    xr = engine.mesh_plan.exchange_rows(len(keycodes))
+                    cost += cm["exchange"] * xr
+                    engine.counters["mesh_exchange_rows"] += xr
+                cost += cm["probe"] * len(keycodes) + cm["match"] * len(probe_idx)
+                engine.counters["probe_rows"] += len(keycodes)
+                bits_in = bits[probe_idx]
+                if lens_fused:
+                    new_bits = bits_in & act[0].bitval
+                else:
+                    if words is None:
+                        words = op.state.vis.data[entry_idx]
+                    vis_pl = translate_bits(words, vis_tables)
+                    for m in grant_members:
+                        vm = op.state.visible_mask(m.lens_qid, entry_idx)
+                        vis_pl = vis_pl | np.where(vm, m.bitval, U64_0)
+                    new_bits = bits_in & vis_pl
+                    engine.counters["fused_vis_rows"] += len(probe_idx) * (
+                        len(act) - len(grant_members)
                     )
-            else:
-                probe_idx, entry_idx = op.state.probe(keycodes)
-            if engine.mesh_plan is not None:
-                # §14: probe rows cross the bucketed all_to_all to their
-                # key shard's device before the shard-local probe
-                xr = engine.mesh_plan.exchange_rows(len(keycodes))
-                cost += cm["exchange"] * xr
-                engine.counters["mesh_exchange_rows"] += xr
-            cost += cm["probe"] * len(keycodes) + cm["match"] * len(probe_idx)
-            engine.counters["probe_rows"] += len(keycodes)
-            bits_in = bits[probe_idx]
-            if lens_fused:
-                new_bits = bits_in & act[0].bitval
-            else:
-                if words is None:
-                    words = op.state.vis.data[entry_idx]
-                vis_pl = translate_bits(words, vis_tables)
-                for m in grant_members:
-                    vm = op.state.visible_mask(m.lens_qid, entry_idx)
-                    vis_pl = vis_pl | np.where(vm, m.bitval, U64_0)
-                new_bits = bits_in & vis_pl
-                engine.counters["fused_vis_rows"] += len(probe_idx) * (
-                    len(act) - len(grant_members)
-                )
-            cols = {k: v[probe_idx] for k, v in cols.items()}
-            for a, out in zip(op.payload, op.out_names):
-                cols[out] = op.state.cols[a].data[entry_idx]
-            if self.compose_did:
-                did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
-            else:
-                did = did[probe_idx]
-            bits = new_bits
+                cols = {k: v[probe_idx] for k, v in cols.items()}
+                for a, out in zip(op.payload, op.out_names):
+                    cols[out] = op.state.cols[a].data[entry_idx]
+                if self.compose_did:
+                    did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
+                else:
+                    did = did[probe_idx]
+                bits = new_bits
             # post-join stage filters: one fused bound-check over all
             # interval-canonical members (§11); the rest evaluate per-member
-            ff, n_fused, fmask, slow = plan["filters"][stage]
-            if n_fused:
-                fbits = ff(len(bits), cols)
-                bits = bits & (~fmask | fbits)
-                engine.counters["fused_stage_filter_rows"] += len(bits) * n_fused
-            for m in slow:
-                for p in m.stage_filters.get(stage, ()):  # e.g. Q5 ColEq
-                    bm = bit_of(bits, m.slot) & evaluate(p, cols)
-                    bits = (bits & ~m.bitval) | np.where(bm, m.bitval, U64_0)
-            keep = np.flatnonzero(bits)
-            if len(keep) != len(bits):
-                cols = {k: v[keep] for k, v in cols.items()}
-                did = did[keep]
-                bits = bits[keep]
+            with span("graftdb.filter"):
+                ff, n_fused, fmask, slow = plan["filters"][stage]
+                if n_fused:
+                    fbits = ff(len(bits), cols)
+                    bits = bits & (~fmask | fbits)
+                    engine.counters["fused_stage_filter_rows"] += len(bits) * n_fused
+                for m in slow:
+                    for p in m.stage_filters.get(stage, ()):  # e.g. Q5 ColEq
+                        bm = bit_of(bits, m.slot) & evaluate(p, cols)
+                        bits = (bits & ~m.bitval) | np.where(bm, m.bitval, U64_0)
+                keep = np.flatnonzero(bits)
+                if len(keep) != len(bits):
+                    cols = {k: v[keep] for k, v in cols.items()}
+                    did = did[keep]
+                    bits = bits[keep]
 
         # sinks
         if self.build_target is not None and len(did) > 0:
-            bt = self.build_target
-            if chain_sink is not None:
-                # chain launches translate the sink words in-kernel and
-                # return per-slot survivor counts alongside (§13)
-                vismask, emask, counts = chain_sink
-            else:
-                vis_tables, em_tables = plan["sink"]
-                # all beneficiaries of all members tag in ONE translate +
-                # one bitwise_or.at scatter inside insert_or_mark (§11)
-                vismask = translate_bits(bits, vis_tables)
-                emask = translate_bits(bits, em_tables)
-                counts = slot_popcounts(bits)
-            engine.counters["fused_sink_rows"] += len(bits)
-            idx = np.flatnonzero(vismask)
-            if len(idx):
-                keycodes = encode_keys(cols, bt.key_attrs)
-                ins, mrk = bt.state.insert_or_mark(
-                    did[idx],
-                    keycodes[idx],
-                    {a: cols[a][idx] for a in bt.state.retained_attrs},
-                    vismask[idx],
-                    emask[idx],
-                )
-                cost += cm["insert"] * ins + cm["mark"] * mrk
-            for m in act:
-                nsel = int(counts[m.slot])
-                m.rows_sunk += nsel
-                key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
-                engine.counters[key] += nsel * len(m.beneficiaries)
+            with span("graftdb.build"):
+                bt = self.build_target
+                if chain_sink is not None:
+                    # chain launches translate the sink words in-kernel and
+                    # return per-slot survivor counts alongside (§13)
+                    vismask, emask, counts = chain_sink
+                else:
+                    vis_tables, em_tables = plan["sink"]
+                    # all beneficiaries of all members tag in ONE translate +
+                    # one bitwise_or.at scatter inside insert_or_mark (§11)
+                    vismask = translate_bits(bits, vis_tables)
+                    emask = translate_bits(bits, em_tables)
+                    counts = slot_popcounts(bits)
+                engine.counters["fused_sink_rows"] += len(bits)
+                idx = np.flatnonzero(vismask)
+                if len(idx):
+                    keycodes = encode_keys(cols, bt.key_attrs)
+                    ins, mrk = bt.state.insert_or_mark(
+                        did[idx],
+                        keycodes[idx],
+                        {a: cols[a][idx] for a in bt.state.retained_attrs},
+                        vismask[idx],
+                        emask[idx],
+                    )
+                    cost += cm["insert"] * ins + cm["mark"] * mrk
+                for m in act:
+                    nsel = int(counts[m.slot])
+                    m.rows_sunk += nsel
+                    key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
+                    engine.counters[key] += nsel * len(m.beneficiaries)
         else:
             nsel_of: Dict[int, int] = {}
             for ck, ms, fold, needed in plan["cohorts"]:
@@ -1150,6 +1156,7 @@ class Pipeline:
             sink = (res["vismask"][keep], res["emask"][keep], res["slots"])
         return cost, out_cols, bits, did, sink
 
+    @spanned("graftdb.aggregate")
     def _agg_fold_cohort(
         self, engine, ck, ms: List[Member], needed, cols, bits: np.ndarray,
         part: int, nsel_of: Dict[int, int],
@@ -1263,6 +1270,7 @@ class Pipeline:
             engine.counters["agg_rows"] += nsel
             nsel_of[m.mid] = nsel
 
+    @spanned("graftdb.aggregate")
     def _agg_sink_rows(self, engine, m: Member, scols, nsel: int, part: int) -> None:
         """Fold one member's selected rows into its aggregate state (the
         per-member sink body, shared by the oracle path, singleton/distinct
@@ -1302,13 +1310,13 @@ class Pipeline:
         cm = engine.cost_model
         cost = 0.0
 
-        bits = self._source_bits(act, cols, n, engine)
+        with span("graftdb.filter"):
+            bits = self._source_bits(act, cols, n, engine)
+            keep = np.flatnonzero(bits)
+            cols = {k: v[keep] for k, v in cols.items()}
+            bits = bits[keep]
+            did = row_ids[keep].astype(np.int64)
         cost += cm["filter"] * n * len(act)
-
-        keep = np.flatnonzero(bits)
-        cols = {k: v[keep] for k, v in cols.items()}
-        bits = bits[keep]
-        did = row_ids[keep].astype(np.int64)
 
         # §14: same first-stage routing histogram as the fused path
         if engine.mesh_plan is not None and self.ops and len(did) > 0:
@@ -1320,92 +1328,95 @@ class Pipeline:
         for stage, op in enumerate(self.ops):
             if len(did) == 0:
                 break
-            keycodes = encode_keys(cols, op.probe_attrs)
-            # single-member probes resolve the state lens in-kernel when the
-            # backend can serve it; the runtime then skips visible_mask
-            lens_fused = False
-            if backend is not None:
-                if len(act) == 1:
-                    probe_visible = getattr(backend, "probe_visible", None)
-                    if probe_visible is not None:
-                        fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
-                        if fused_pair is not None:
-                            probe_idx, entry_idx = fused_pair
-                            lens_fused = True
-                            engine.counters["kernel_lens_probes"] += 1
-                if not lens_fused:
-                    probe_idx, entry_idx = _backend_probe(
-                        backend, op.state, keycodes, engine.counters
-                    )
-            else:
-                probe_idx, entry_idx = op.state.probe(keycodes)
-            if engine.mesh_plan is not None:
-                # §14 exchange charge — identical to the fused path's so
-                # the oracle stays clock-bit-identical under mesh
-                xr = engine.mesh_plan.exchange_rows(len(keycodes))
-                cost += cm["exchange"] * xr
-                engine.counters["mesh_exchange_rows"] += xr
-            cost += cm["probe"] * len(keycodes) + cm["match"] * len(probe_idx)
-            engine.counters["probe_rows"] += len(keycodes)
-            bits_in = bits[probe_idx]
-            new_bits = np.zeros(len(probe_idx), dtype=np.uint64)
-            for m in act:
-                if lens_fused:
-                    bm = bit_of(bits_in, m.slot)
+            with span("graftdb.join"):
+                keycodes = encode_keys(cols, op.probe_attrs)
+                # single-member probes resolve the state lens in-kernel when the
+                # backend can serve it; the runtime then skips visible_mask
+                lens_fused = False
+                if backend is not None:
+                    if len(act) == 1:
+                        probe_visible = getattr(backend, "probe_visible", None)
+                        if probe_visible is not None:
+                            fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
+                            if fused_pair is not None:
+                                probe_idx, entry_idx = fused_pair
+                                lens_fused = True
+                                engine.counters["kernel_lens_probes"] += 1
+                    if not lens_fused:
+                        probe_idx, entry_idx = _backend_probe(
+                            backend, op.state, keycodes, engine.counters
+                        )
                 else:
-                    vis = op.state.visible_mask(m.lens_qid, entry_idx)
-                    bm = bit_of(bits_in, m.slot) & vis
-                new_bits |= np.where(bm, m.bitval, U64_0)
-            cols = {k: v[probe_idx] for k, v in cols.items()}
-            for a, out in zip(op.payload, op.out_names):
-                cols[out] = op.state.cols[a].data[entry_idx]
-            if self.compose_did:
-                did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
-            else:
-                did = did[probe_idx]
-            bits = new_bits
+                    probe_idx, entry_idx = op.state.probe(keycodes)
+                if engine.mesh_plan is not None:
+                    # §14 exchange charge — identical to the fused path's so
+                    # the oracle stays clock-bit-identical under mesh
+                    xr = engine.mesh_plan.exchange_rows(len(keycodes))
+                    cost += cm["exchange"] * xr
+                    engine.counters["mesh_exchange_rows"] += xr
+                cost += cm["probe"] * len(keycodes) + cm["match"] * len(probe_idx)
+                engine.counters["probe_rows"] += len(keycodes)
+                bits_in = bits[probe_idx]
+                new_bits = np.zeros(len(probe_idx), dtype=np.uint64)
+                for m in act:
+                    if lens_fused:
+                        bm = bit_of(bits_in, m.slot)
+                    else:
+                        vis = op.state.visible_mask(m.lens_qid, entry_idx)
+                        bm = bit_of(bits_in, m.slot) & vis
+                    new_bits |= np.where(bm, m.bitval, U64_0)
+                cols = {k: v[probe_idx] for k, v in cols.items()}
+                for a, out in zip(op.payload, op.out_names):
+                    cols[out] = op.state.cols[a].data[entry_idx]
+                if self.compose_did:
+                    did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
+                else:
+                    did = did[probe_idx]
+                bits = new_bits
             # member post-join filters at this stage
-            for m in act:
-                for p in m.stage_filters.get(stage, ()):  # e.g. Q5 ColEq
-                    bm = bit_of(bits, m.slot) & evaluate(p, cols)
-                    bits = (bits & ~m.bitval) | np.where(bm, m.bitval, U64_0)
-            keep = np.flatnonzero(bits)
-            if len(keep) != len(bits):
-                cols = {k: v[keep] for k, v in cols.items()}
-                did = did[keep]
-                bits = bits[keep]
+            with span("graftdb.filter"):
+                for m in act:
+                    for p in m.stage_filters.get(stage, ()):  # e.g. Q5 ColEq
+                        bm = bit_of(bits, m.slot) & evaluate(p, cols)
+                        bits = (bits & ~m.bitval) | np.where(bm, m.bitval, U64_0)
+                keep = np.flatnonzero(bits)
+                if len(keep) != len(bits):
+                    cols = {k: v[keep] for k, v in cols.items()}
+                    did = did[keep]
+                    bits = bits[keep]
 
         # sinks
         if self.build_target is not None and len(did) > 0:
-            bt = self.build_target
-            vismask = np.zeros(len(did), dtype=np.uint64)
-            emask = np.zeros(len(did), dtype=np.uint64)
-            member_rows: List[Tuple[Member, int]] = []
-            for m in act:
-                sel = bit_of(bits, m.slot)
-                nsel = int(sel.sum())
-                if nsel:
-                    for b in m.beneficiaries:
-                        vismask[sel] |= bt.state.slots.mask(b)
-                    if m.eid >= 0:
-                        emask[sel] |= U64_1 << np.uint64(m.eid)
-                member_rows.append((m, nsel))
-            any_rows = vismask != 0
-            idx = np.flatnonzero(any_rows)
-            if len(idx):
-                keycodes = encode_keys(cols, bt.key_attrs)
-                ins, mrk = bt.state.insert_or_mark(
-                    did[idx],
-                    keycodes[idx],
-                    {a: cols[a][idx] for a in bt.state.retained_attrs},
-                    vismask[idx],
-                    emask[idx],
-                )
-                cost += cm["insert"] * ins + cm["mark"] * mrk
-            for m, nsel in member_rows:
-                m.rows_sunk += nsel
-                key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
-                engine.counters[key] += nsel * len(m.beneficiaries)
+            with span("graftdb.build"):
+                bt = self.build_target
+                vismask = np.zeros(len(did), dtype=np.uint64)
+                emask = np.zeros(len(did), dtype=np.uint64)
+                member_rows: List[Tuple[Member, int]] = []
+                for m in act:
+                    sel = bit_of(bits, m.slot)
+                    nsel = int(sel.sum())
+                    if nsel:
+                        for b in m.beneficiaries:
+                            vismask[sel] |= bt.state.slots.mask(b)
+                        if m.eid >= 0:
+                            emask[sel] |= U64_1 << np.uint64(m.eid)
+                    member_rows.append((m, nsel))
+                any_rows = vismask != 0
+                idx = np.flatnonzero(any_rows)
+                if len(idx):
+                    keycodes = encode_keys(cols, bt.key_attrs)
+                    ins, mrk = bt.state.insert_or_mark(
+                        did[idx],
+                        keycodes[idx],
+                        {a: cols[a][idx] for a in bt.state.retained_attrs},
+                        vismask[idx],
+                        emask[idx],
+                    )
+                    cost += cm["insert"] * ins + cm["mark"] * mrk
+                for m, nsel in member_rows:
+                    m.rows_sunk += nsel
+                    key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
+                    engine.counters[key] += nsel * len(m.beneficiaries)
         else:
             for m in act:
                 if m.sink is None:
@@ -1469,23 +1480,24 @@ class Pipeline:
                 mcols = {k: v[ks] for k, v in mcols.items()}
                 did = did[ks]
         if self.build_target is not None and len(did) > 0:
-            bt = self.build_target
-            w = np.uint64(0)
-            for b in m.beneficiaries:
-                w |= bt.state.slots.mask(b)
-            e = (U64_1 << np.uint64(m.eid)) if m.eid >= 0 else np.uint64(0)
-            keycodes = encode_keys(mcols, bt.key_attrs)
-            ins, mrk = bt.state.insert_or_mark(
-                did,
-                keycodes,
-                {a: mcols[a] for a in bt.state.retained_attrs},
-                np.full(len(did), w, dtype=np.uint64),
-                np.full(len(did), e, dtype=np.uint64),
-            )
-            cost += cm["insert"] * ins + cm["mark"] * mrk
-            m.rows_sunk += len(did)
-            key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
-            engine.counters[key] += len(did) * len(m.beneficiaries)
+            with span("graftdb.build"):
+                bt = self.build_target
+                w = np.uint64(0)
+                for b in m.beneficiaries:
+                    w |= bt.state.slots.mask(b)
+                e = (U64_1 << np.uint64(m.eid)) if m.eid >= 0 else np.uint64(0)
+                keycodes = encode_keys(mcols, bt.key_attrs)
+                ins, mrk = bt.state.insert_or_mark(
+                    did,
+                    keycodes,
+                    {a: mcols[a] for a in bt.state.retained_attrs},
+                    np.full(len(did), w, dtype=np.uint64),
+                    np.full(len(did), e, dtype=np.uint64),
+                )
+                cost += cm["insert"] * ins + cm["mark"] * mrk
+                m.rows_sunk += len(did)
+                key = "residual_build_rows" if m.kind == "residual" else "ordinary_build_rows"
+                engine.counters[key] += len(did) * len(m.beneficiaries)
         elif m.sink is not None and len(did) > 0:
             self._agg_sink_rows(engine, m, mcols, len(did), part)
             cost += cm["agg"] * len(did)
@@ -1589,7 +1601,14 @@ class ScanNode:
         with members still owed that shard. Physical read counted once
         (shared scan)."""
         idx = self.cursors[part]
-        if self.zone_maps and not self._wave_possible()[idx]:
+        with span("graftdb.scan"):
+            skip = self.zone_maps and not self._wave_possible()[idx]
+            if not skip:
+                start = idx * self.morsel_size
+                cols = self.table.morsel(start, self.morsel_size)
+                n = len(next(iter(cols.values())))
+                row_ids = np.arange(start, start + n, dtype=np.int64)
+        if skip:
             engine.counters["morsels_skipped"] += 1
             cost = engine.cost_model["scan"] * 8  # zone check, not a read
             # the morsel still counts toward every member's delivery cycle
@@ -1610,11 +1629,6 @@ class ScanNode:
                     engine.on_member_finished(p, m)
             self._bump_cursor(part)
             return cost
-        start = idx * self.morsel_size
-        cols = self.table.morsel(start, self.morsel_size)
-        n = len(next(iter(cols.values())))
-        row_ids = np.arange(start, start + n, dtype=np.int64)
-
         engine.counters["scan_rows"] += n
         engine.counters["scan_bytes"] += n * self.row_bytes
         cost = engine.cost_model["scan"] * n

@@ -43,6 +43,10 @@ from .grafting import candidate_states, graft_potential
 from .plans import Query
 from .reuse import reuse_potential
 from .runtime import Member, Pipeline, ScanNode
+from .tracing import span
+
+#: ``Runner._next_unit``: no work remains
+_STOP = object()
 
 # ---------------------------------------------------------------------------
 # Clocks
@@ -375,6 +379,10 @@ class Runner:
 
     def submit_now(self, query: Query) -> QueryHandle:
         """Admit one query immediately (query grafting happens here)."""
+        with span("graftdb.admit", qid=query.qid):
+            return self._submit(query)
+
+    def _submit(self, query: Query) -> QueryHandle:
         if self.submit_hook is not None:
             self.submit_hook(query)
         return self.engine.submit(query)
@@ -390,31 +398,32 @@ class Runner:
     def _try_admit(self, q: Query, now: float, t_queued: Optional[float] = None) -> bool:
         """Run one query through the admission controller; submit on admit,
         enqueue first-time deferrals. Returns True iff submitted."""
-        if self.admission is None:
-            self.submit_now(q)
-            return True
-        verdict, reason = self.admission.decide(self.engine, q)
-        if verdict == "admit":
-            delay = (now - t_queued) if t_queued is not None else 0.0
-            if t_queued is not None:
-                self.engine.counters["queue_delay_s_total"] += delay
-                self._unpin_candidates(q.qid)
-            self.admission_log[q.qid] = {
-                "decision": reason,
-                "queued": t_queued is not None,
-                "queue_delay_s": delay,
-                "t_admitted": now,
-            }
-            self.submit_now(q)
-            return True
-        if t_queued is None:
-            self.engine.counters["queued_admissions"] += 1
-            self._admit_queue.append((q.arrival, q.qid, q, now))
-            # pin the candidate states this arrival would graft onto: a
-            # queued-but-admissible lens must not lose its coverage to the
-            # evictor while it waits (§10)
-            self._pin_candidates(q)
-        return False
+        with span("graftdb.admit", qid=q.qid):
+            if self.admission is None:
+                self._submit(q)
+                return True
+            verdict, reason = self.admission.decide(self.engine, q)
+            if verdict == "admit":
+                delay = (now - t_queued) if t_queued is not None else 0.0
+                if t_queued is not None:
+                    self.engine.counters["queue_delay_s_total"] += delay
+                    self._unpin_candidates(q.qid)
+                self.admission_log[q.qid] = {
+                    "decision": reason,
+                    "queued": t_queued is not None,
+                    "queue_delay_s": delay,
+                    "t_admitted": now,
+                }
+                self._submit(q)
+                return True
+            if t_queued is None:
+                self.engine.counters["queued_admissions"] += 1
+                self._admit_queue.append((q.arrival, q.qid, q, now))
+                # pin the candidate states this arrival would graft onto: a
+                # queued-but-admissible lens must not lose its coverage to
+                # the evictor while it waits (§10)
+                self._pin_candidates(q)
+            return False
 
     def _pin_candidates(self, q: Query) -> None:
         """(Re-)snapshot the pins of one queued arrival: states that became
@@ -583,7 +592,13 @@ class Runner:
         self._after_events(on_complete)
 
     def worker_stats(self) -> Dict[str, object]:
-        """Per-worker utilization of the run so far (QueryFuture.stats)."""
+        """Per-worker utilization of the run so far (QueryFuture.stats).
+
+        ``busy_s`` is what each worker's clock counts as work: under a
+        ``WallClock`` the host seconds measured around its units' morsel
+        advances (the ``graftdb.unit`` spans), under a ``WorkClock`` the
+        cost model's modelled seconds of the same units. ``utilization``
+        divides it by the makespan on that clock."""
         makespan = max(c.now for c in self.clocks)
         return {
             "n": self.workers,
@@ -744,79 +759,99 @@ class Runner:
                 steps += 1
                 if steps > max_steps:
                     raise RuntimeError("executor exceeded max_steps — livelock?")
-                # least-advanced worker takes the next scheduling decision
-                wi = min(range(self.workers), key=lambda i: self.clocks[i].now)
-                wclock = self.clocks[wi]
-                self.clock.current = wclock
-                # due deadlines cancel before anything else at this step
-                self._apply_deadlines(wclock.now, on_complete)
-                # admit due arrivals (query grafting happens at submit)
-                self._admit_due(wclock.now, on_complete)
-                units = extract_ready_units(engine)
-                if not units:
-                    self.clock.current = None
-                    if self._heap:
-                        self.clock.advance_to(self._heap[0][0])
-                        continue
-                    if engine.has_active_work():
-                        # all remaining handles must be completable observers
-                        done = engine.sweep_completions()
-                        if done:
-                            self._after_events(on_complete, done)
-                            continue
-                        if self._admit_queue:
-                            # nothing completable: free the admit queue head
-                            self._force_admit_head(self.clock.now, on_complete)
-                            continue
-                        raise RuntimeError(
-                            f"deadlock: {len(engine.active_handles)} active queries, no ready fragments"
-                        )
-                    if self._admit_queue:
-                        self._force_admit_head(self.clock.now, on_complete)
-                        continue
+                with span("graftdb.schedule"):
+                    step = self._next_unit(on_complete)
+                if step is _STOP:
                     break
-                # mesh execution (§14): device affinity — partition p's
-                # state shard is resident on device p % workers, so only
-                # that worker's clock may advance it. The least-advanced
-                # worker defers to the least-advanced OWNER of a ready
-                # shard when it owns none itself (deterministic: owners
-                # sorted, ties resolve to the lowest device id).
-                if engine.mesh_plan is not None and self.workers > 1:
-                    owned = [u for u in units if u[1] % self.workers == wi]
-                    if not owned:
-                        owners = sorted({u[1] % self.workers for u in units})
-                        wi = min(owners, key=lambda i: self.clocks[i].now)
-                        wclock = self.clocks[wi]
-                        self.clock.current = wclock
-                        owned = [u for u in units if u[1] % self.workers == wi]
-                    units = owned
-                # round-robin over ready (scan × partition) units
-                unit = None
-                for cand in units:
-                    if (cand[0].sid, cand[1]) > self._rr:
-                        unit = cand
-                        break
-                if unit is None:
-                    unit = units[0]
-                node, part = unit
-                self._rr = (node.sid, part)
-                # max-at-barrier: wait for the unit's enabling events, then
-                # re-admit anything that became due during the wait
-                wclock.advance_to(unit_ready_time(node, part))
-                self._admit_due(wclock.now, on_complete)
-                if self._apply_deadlines(wclock.now, on_complete):
-                    continue  # the unit may be gone: re-extract
-                if self.faults is not None and not self._fault_gate(
-                    node, part, wclock, on_complete
-                ):
+                if step is None:
                     continue
-                cost = node.advance(engine, part)
+                wi, node, part = step
+                t0 = time.perf_counter()
+                with span("graftdb.unit", scan=node.sid, part=part, morsel=node.cursors[part]):
+                    cost = node.advance(engine, part)
+                elapsed = time.perf_counter() - t0
+                wclock = self.clocks[wi]
                 wclock.tick(cost)
-                self.busy_s[wi] += cost
-                self._after_events(on_complete)
+                self.busy_s[wi] += elapsed if isinstance(wclock, WallClock) else cost
+                with span("graftdb.schedule"):
+                    self._after_events(on_complete)
         finally:
             self.clock.current = None
         return engine.completed
+
+    def _next_unit(self, on_complete):
+        """One decision step of ``run``: due deadlines and admissions, then
+        the ready unit the least-advanced worker takes. Returns ``(worker,
+        scan, partition)``; None when the step ended without a unit to
+        advance (``run`` steps again); ``_STOP`` when no work remains."""
+        engine = self.engine
+        # least-advanced worker takes the next scheduling decision
+        wi = min(range(self.workers), key=lambda i: self.clocks[i].now)
+        wclock = self.clocks[wi]
+        self.clock.current = wclock
+        # due deadlines cancel before anything else at this step
+        self._apply_deadlines(wclock.now, on_complete)
+        # admit due arrivals (query grafting happens at submit)
+        self._admit_due(wclock.now, on_complete)
+        units = extract_ready_units(engine)
+        if not units:
+            self.clock.current = None
+            if self._heap:
+                self.clock.advance_to(self._heap[0][0])
+                return None
+            if engine.has_active_work():
+                # all remaining handles must be completable observers
+                done = engine.sweep_completions()
+                if done:
+                    self._after_events(on_complete, done)
+                    return None
+                if self._admit_queue:
+                    # nothing completable: free the admit queue head
+                    self._force_admit_head(self.clock.now, on_complete)
+                    return None
+                raise RuntimeError(
+                    f"deadlock: {len(engine.active_handles)} active queries, no ready fragments"
+                )
+            if self._admit_queue:
+                self._force_admit_head(self.clock.now, on_complete)
+                return None
+            return _STOP
+        # mesh execution (§14): device affinity — partition p's
+        # state shard is resident on device p % workers, so only
+        # that worker's clock may advance it. The least-advanced
+        # worker defers to the least-advanced OWNER of a ready
+        # shard when it owns none itself (deterministic: owners
+        # sorted, ties resolve to the lowest device id).
+        if engine.mesh_plan is not None and self.workers > 1:
+            owned = [u for u in units if u[1] % self.workers == wi]
+            if not owned:
+                owners = sorted({u[1] % self.workers for u in units})
+                wi = min(owners, key=lambda i: self.clocks[i].now)
+                wclock = self.clocks[wi]
+                self.clock.current = wclock
+                owned = [u for u in units if u[1] % self.workers == wi]
+            units = owned
+        # round-robin over ready (scan × partition) units
+        unit = None
+        for cand in units:
+            if (cand[0].sid, cand[1]) > self._rr:
+                unit = cand
+                break
+        if unit is None:
+            unit = units[0]
+        node, part = unit
+        self._rr = (node.sid, part)
+        # max-at-barrier: wait for the unit's enabling events, then
+        # re-admit anything that became due during the wait
+        wclock.advance_to(unit_ready_time(node, part))
+        self._admit_due(wclock.now, on_complete)
+        if self._apply_deadlines(wclock.now, on_complete):
+            return None  # the unit may be gone: re-extract
+        if self.faults is not None and not self._fault_gate(
+            node, part, wclock, on_complete
+        ):
+            return None
+        return wi, node, part
 
     def _after_events(self, on_complete, pre_done: Optional[List[QueryHandle]] = None) -> None:
         engine = self.engine
