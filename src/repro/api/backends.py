@@ -38,6 +38,7 @@ from typing import Optional, Protocol, Tuple, runtime_checkable
 
 import numpy as np
 
+from ..core.runtime import Launch
 from ..core.state import SharedHashBuildState, _bincount_segment_sum
 from ..core.tracing import span, spanned
 from ..core.visibility import join_words, split_words
@@ -56,9 +57,11 @@ class ExecutionBackend(Protocol):
     Backends may additionally provide ``probe_visible(state, keycodes,
     qid)`` / ``probe_visible_multi(state, keycodes)`` /
     ``probe_chain(cplan, cols, bits, host_keys)`` returning
-    visibility-resolved results (or None to decline); the runtime discovers
-    them via getattr, so they are not part of the required protocol
-    surface. A backend that sets ``probe_accepts_counters = True`` receives
+    visibility-resolved results (or None to decline), and each of these and
+    ``probe`` as a ``*_launch`` half returning a ``core.runtime.Launch``;
+    the runtime discovers the launch halves via getattr, so they are not
+    part of the required protocol surface. A backend that sets
+    ``probe_accepts_counters = True`` receives
     the engine's counter dict as a ``counters=`` kwarg on ``probe`` so
     per-reason fallback counters surface in ``QueryFuture.stats()``."""
 
@@ -277,6 +280,7 @@ class PallasBackend:
         self.d2h_bytes = 0
         self.device_rows = 0
         self.device_padded_rows = 0
+        self.device_launches = 0  # probe and chain launches
 
     def stats(self) -> dict:
         """Kernel-dispatch counters (surfaced via ``Session.stats``).
@@ -298,6 +302,7 @@ class PallasBackend:
             "d2h_bytes": self.d2h_bytes,
             "device_rows": self.device_rows,
             "device_padded_rows": self.device_padded_rows,
+            "device_launches": self.device_launches,
         }
         for r in FALLBACK_REASONS:
             out[f"fallback_{r}"] = self.fallback_reasons[r]
@@ -334,10 +339,14 @@ class PallasBackend:
         copy is queued behind the launch first, as ``jax.device_get``
         does, so the host's wait for the device and its wait for the
         copies are timed apart without a round trip between them."""
-        import jax
-
         for o in outs:
             o.copy_to_host_async()
+        return self._read_back(outs)
+
+    def _read_back(self, outs):
+        """Wait for outputs whose copies are queued, then take the copies."""
+        import jax
+
         with span("graftdb.device_wait"):
             jax.block_until_ready(outs)
         with span("graftdb.d2h"):
@@ -357,32 +366,53 @@ class PallasBackend:
         return self._h2d(_pad(kc, npad, EMPTY))
 
     # -- probe ---------------------------------------------------------------
-    @spanned("graftdb.backend.probe")
+    # Each probe and the chain come in two halves (DESIGN.md §11): the
+    # ``*_launch`` half does everything up to the device launch and queues
+    # the outputs' copies to the host; the returned ``Launch``'s
+    # ``collect()`` reads them back and finishes the host work. The
+    # one-call methods are launch-then-collect.
+    def _launched(self, name, outs, post) -> Launch:
+        """A probe or chain launch of the device outputs ``outs``: each
+        copy to the host is queued now, as ``jax.device_get`` does;
+        ``collect()`` runs ``post`` on the arrays read back (``_read_back``)."""
+        for o in outs:
+            o.copy_to_host_async()
+        self.device_launches += 1
+        return Launch(outs=outs, finish=lambda o: post(self._read_back(o)), name=name)
+
     def probe(self, state, keycodes, counters=None):
+        return self.probe_launch(state, keycodes, counters).collect()
+
+    @spanned("graftdb.backend.probe")
+    def probe_launch(self, state, keycodes, counters=None) -> Launch:
+        """Launch half of ``probe``; the reference fallbacks run here, and
+        their Launch holds the result."""
         if state.keycode.n == 0 or len(keycodes) == 0:
-            return np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64)
+            return Launch((np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64)))
         table = self._table_for(state)
         if table is None:
             self.fallback_probes += 1
             self.note_fallback("capacity", counters)
-            return self._ref.probe(state, keycodes)
+            return Launch(self._ref.probe(state, keycodes))
         if keycodes.min() < 0 or keycodes.max() > self._KEY_LIMIT:
             self.fallback_probes += 1
             self.note_fallback("keyrange", counters)
-            return self._ref.probe(state, keycodes)
+            return Launch(self._ref.probe(state, keycodes))
         tkeys, tones, slot_entry = table
         if self._qmask is None:  # lens off: pure key match
             self._qmask = self._h2d(np.array([0xFFFFFFFF], dtype=np.uint32))
-        (found_slots,) = self._d2h(
-            self._hash_probe_lens(self._device_keys(keycodes), tkeys, tones, self._qmask)
-        )
-        found_slots = found_slots[: len(keycodes)]
-        self.kernel_probes += 1
-        probe_idx = np.flatnonzero(found_slots >= 0).astype(np.int64)
-        entry_idx = slot_entry[found_slots[probe_idx]]
-        return probe_idx, entry_idx
+        n = len(keycodes)
 
-    @spanned("graftdb.backend.probe_visible")
+        def post(host):
+            found_slots = host[0][:n]
+            self.kernel_probes += 1
+            probe_idx = np.flatnonzero(found_slots >= 0).astype(np.int64)
+            entry_idx = slot_entry[found_slots[probe_idx]]
+            return probe_idx, entry_idx
+
+        out = self._hash_probe_lens(self._device_keys(keycodes), tkeys, tones, self._qmask)
+        return self._launched("graftdb.backend.probe", (out,), post)
+
     def probe_visible(self, state, keycodes, qid):
         """Single-query probe with the state lens fused in-kernel.
 
@@ -392,6 +422,12 @@ class PallasBackend:
         compiled form; unservable tables fall back entirely). The lens
         words are entry-indexed uint32 pairs, so any slot 0..63 serves —
         the former uint32-word slot<32 limit is gone (DESIGN.md §13)."""
+        launch = self.probe_visible_launch(state, keycodes, qid)
+        return None if launch is None else launch.collect()
+
+    @spanned("graftdb.backend.probe_visible")
+    def probe_visible_launch(self, state, keycodes, qid) -> Optional[Launch]:
+        """Launch half of ``probe_visible``; None where it declines."""
         if state.grants.get(qid):
             return None
         slot = state.slots.peek(qid)
@@ -408,24 +444,27 @@ class PallasBackend:
         self._sync_mirrors(ent, state)
         mask = np.uint64(1) << np.uint64(slot)
         mlo, mhi = split_words(np.array([mask], dtype=np.uint64))
-        (found,) = self._d2h(
-            self._hash_probe_lens64(
-                self._device_keys(keycodes),
-                ent.jkeys,
-                ent.jentry,
-                ent.jvlo,
-                ent.jvhi,
-                self._h2d(np.array([mlo[0], mhi[0]], dtype=np.uint32)),
-            )
-        )
-        found = found[: len(keycodes)]
-        self.kernel_probes += 1
-        self.kernel_lens_probes += 1
-        probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
-        entry_idx = ent.slot_entry[found[probe_idx]]
-        return probe_idx, entry_idx
+        slot_entry = ent.slot_entry
+        n = len(keycodes)
 
-    @spanned("graftdb.backend.probe_visible_multi")
+        def post(host):
+            found = host[0][:n]
+            self.kernel_probes += 1
+            self.kernel_lens_probes += 1
+            probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
+            entry_idx = slot_entry[found[probe_idx]]
+            return probe_idx, entry_idx
+
+        out = self._hash_probe_lens64(
+            self._device_keys(keycodes),
+            ent.jkeys,
+            ent.jentry,
+            ent.jvlo,
+            ent.jvhi,
+            self._h2d(np.array([mlo[0], mhi[0]], dtype=np.uint32)),
+        )
+        return self._launched("graftdb.backend.probe_visible", (out,), post)
+
     def probe_visible_multi(self, state, keycodes):
         """Multi-member probe with the packed lens words gathered in-kernel
         (§11): returns ``(probe_idx, entry_idx, vis_words)`` where
@@ -435,6 +474,12 @@ class PallasBackend:
         and identical to ``probe`` — ownership filtering happens in the
         runtime's packed translation — so results stay bit-identical to
         the reference path for every member count and any slot 0..63."""
+        launch = self.probe_visible_multi_launch(state, keycodes)
+        return None if launch is None else launch.collect()
+
+    @spanned("graftdb.backend.probe_visible_multi")
+    def probe_visible_multi_launch(self, state, keycodes) -> Optional[Launch]:
+        """Launch half of ``probe_visible_multi``; None where it declines."""
         if state.keycode.n == 0 or len(keycodes) == 0:
             return None
         table = self._table_for(state)
@@ -442,21 +487,25 @@ class PallasBackend:
             return None
         ent = self._tables[state]
         self._sync_mirrors(ent, state)
-        found, wlo, whi = self._d2h(
-            *self._hash_probe_lens_multi64(
-                self._device_keys(keycodes), ent.jkeys, ent.jentry, ent.jvlo, ent.jvhi
-            )
+        slot_entry = ent.slot_entry
+        n = len(keycodes)
+
+        def post(host):
+            found, wlo, whi = host
+            found = found[:n]
+            self.kernel_probes += 1
+            self.kernel_multi_probes += 1
+            probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
+            entry_idx = slot_entry[found[probe_idx]]
+            vis_words = join_words(wlo[probe_idx], whi[probe_idx])
+            return probe_idx, entry_idx, vis_words
+
+        outs = self._hash_probe_lens_multi64(
+            self._device_keys(keycodes), ent.jkeys, ent.jentry, ent.jvlo, ent.jvhi
         )
-        found = found[: len(keycodes)]
-        self.kernel_probes += 1
-        self.kernel_multi_probes += 1
-        probe_idx = np.flatnonzero(found >= 0).astype(np.int64)
-        entry_idx = ent.slot_entry[found[probe_idx]]
-        vis_words = join_words(wlo[probe_idx], whi[probe_idx])
-        return probe_idx, entry_idx, vis_words
+        return self._launched("graftdb.backend.probe_visible_multi", tuple(outs), post)
 
     # -- fused stage chain (DESIGN.md §13) -----------------------------------
-    @spanned("graftdb.backend.probe_chain")
     def probe_chain(self, cplan, cols, bits, host_keys, counters=None):
         """One fused launch for a morsel's entire stage chain.
 
@@ -473,6 +522,12 @@ class PallasBackend:
         chains — the sink visibility/provenance words. Device parameter
         uploads are cached on the plan (``cplan["_dev"]``), so steady-state
         morsels ship only the row-length arrays."""
+        launch = self.probe_chain_launch(cplan, cols, bits, host_keys, counters)
+        return None if launch is None else launch.collect()
+
+    @spanned("graftdb.backend.probe_chain")
+    def probe_chain_launch(self, cplan, cols, bits, host_keys, counters=None):
+        """Launch half of ``probe_chain``; None on a dynamic decline."""
         stages = cplan["stages"]
         n = len(bits)
         if n == 0:
@@ -612,28 +667,31 @@ class PallasBackend:
         spec = (tuple(spec_stages), sink is not None)
         dev_out = self._chain_launch(spec, tuple(arrays), mesh=self.mesh)
         self.chain_devices.update(dev_out[0].devices())
-        out = self._d2h(*dev_out)
         n_stages = len(stages)
-        res = {
-            "bits": join_words(out[0][:n], out[1][:n]),
-            "entries": [out[2 + s][:n].astype(np.int64) for s in range(n_stages)],
-            "stats": out[2 + n_stages].astype(np.int64),
-            "slots": out[3 + n_stages].astype(np.int64),
-        }
-        if sink is not None:
-            res["vismask"] = join_words(out[4 + n_stages][:n], out[5 + n_stages][:n])
-            res["emask"] = join_words(out[6 + n_stages][:n], out[7 + n_stages][:n])
-        self.kernel_probes += 1
-        self.chain_launches += 1
-        stats = res["stats"]
-        for s, st in enumerate(stages):
-            if stats[s, 0] == 0:
-                break
-            if st["use_post"]:
-                self.kernel_lens_probes += 1
-            else:
-                self.kernel_multi_probes += 1
-        return res
+
+        def post(out):
+            res = {
+                "bits": join_words(out[0][:n], out[1][:n]),
+                "entries": [out[2 + s][:n].astype(np.int64) for s in range(n_stages)],
+                "stats": out[2 + n_stages].astype(np.int64),
+                "slots": out[3 + n_stages].astype(np.int64),
+            }
+            if sink is not None:
+                res["vismask"] = join_words(out[4 + n_stages][:n], out[5 + n_stages][:n])
+                res["emask"] = join_words(out[6 + n_stages][:n], out[7 + n_stages][:n])
+            self.kernel_probes += 1
+            self.chain_launches += 1
+            stats = res["stats"]
+            for s, st in enumerate(stages):
+                if stats[s, 0] == 0:
+                    break
+                if st["use_post"]:
+                    self.kernel_lens_probes += 1
+                else:
+                    self.kernel_multi_probes += 1
+            return res
+
+        return self._launched("graftdb.backend.probe_chain", tuple(dev_out), post)
 
     def _grant_params(self, grants):
         """Device parameter matrices of one stage's compiled grants: the

@@ -207,6 +207,9 @@ class GraftEngine:
             # device-resident fused chain (§13) — one launch per morsel
             # stage chain, with per-reason kernel-decline attribution
             "kernel_chain_launches",
+            # probe and chain launches issued while another unit's launch
+            # was outstanding (§11 in-flight units, wall clock only)
+            "overlapped_launches",
             "fallback_probes_grants",
             "fallback_probes_slot_limit",
             "fallback_probes_keyrange",
@@ -483,6 +486,7 @@ class GraftEngine:
     def on_member_part_finished(self, pipeline: Pipeline, m: Member, part: int) -> None:
         """One scan partition of a member's delivery cycle completed: push
         the per-partition extent frontier (§9) of its build target."""
+        self.finish_events += 1
         if pipeline.build_target is not None and m.eid >= 0:
             pipeline.build_target.state.complete_extent_partition(
                 m.eid, part, pipeline.source.n_partitions
@@ -502,9 +506,12 @@ class GraftEngine:
         if pipeline.all_done():
             self.pipelines.pop(pipeline.key, None)
             pipeline.source.detach(pipeline)
-        self._dirty = True
+        self.finish_events += 1
 
-    _dirty = False
+    #: member (partition) finishes so far: gates, activations and
+    #: completions change only at one, so the runner's event sweep has
+    #: nothing to do while this is unchanged (§11 in-flight units)
+    finish_events = 0
 
     def check_activations(self) -> None:
         if self._lens_leases:
@@ -522,6 +529,17 @@ class GraftEngine:
                     # fragment first advances to the activation time (§9
                     # max-at-barrier clock merge)
                     m.t_activated = now
+
+    def completions_due(self) -> bool:
+        """Whether ``sweep_completions`` would complete a query now."""
+        return any(
+            not h.done and h.agg_gate is not None and h.agg_gate.open()
+            for h in self.active_handles
+        )
+
+    def has_lens_leases(self) -> bool:
+        """Whether ``check_activations`` may release a lens lease (§16)."""
+        return bool(self._lens_leases)
 
     def sweep_completions(self) -> List[QueryHandle]:
         done: List[QueryHandle] = []

@@ -125,6 +125,77 @@ def _backend_probe(backend, state, keycodes, counters):
     return backend.probe(state, keycodes)
 
 
+class Launch:
+    """One backend call split at its device launch (DESIGN.md §11).
+
+    ``pending`` while the launch's outputs are on their way to the host;
+    ``collect()`` waits for them and finishes the call's host work, under
+    the call's span. A call the device did not serve (an empty probe, a
+    reference fallback) is a Launch with its result already in hand."""
+
+    __slots__ = ("_result", "_outs", "_finish", "_name")
+
+    def __init__(self, result=None, outs=None, finish=None, name: str = ""):
+        self._result = result
+        self._outs = outs
+        self._finish = finish
+        self._name = name
+
+    @property
+    def pending(self) -> bool:
+        return self._outs is not None
+
+    def ready(self) -> bool:
+        """Whether ``collect()`` would not wait for the device."""
+        return self._outs is None or all(o.is_ready() for o in self._outs)
+
+    def collect(self):
+        if self._outs is not None:
+            with span(self._name):
+                self._result = self._finish(self._outs)
+            self._outs = None
+        return self._result
+
+
+def _stage_launch(backend, op, keycodes, act, grant_members, kernelable, counters):
+    """Launch half of one probe stage of the fused path: ``(launch, kind)``,
+    where ``kind`` names what ``launch.collect()`` returns: ``"lens"`` the
+    single member's visible pairs (lens resolved in the kernel), ``"multi"``
+    the pairs and the matched entries' packed lens words, ``"pairs"`` the
+    pre-visibility pairs."""
+    if backend is None:
+        return Launch(op.state.probe(keycodes)), "pairs"
+    if len(act) == 1 and not grant_members:
+        launch_visible = getattr(backend, "probe_visible_launch", None)
+        if launch_visible is not None:
+            launch = launch_visible(op.state, keycodes, act[0].lens_qid)
+            if launch is not None:
+                return launch, "lens"
+    elif kernelable and len(act) > 1:
+        # multi-member lens: one launch returns every probing member's
+        # ownership word (the matched entry's packed visibility word)
+        launch_multi = getattr(backend, "probe_visible_multi_launch", None)
+        if launch_multi is not None:
+            launch = launch_multi(op.state, keycodes)
+            if launch is not None:
+                return launch, "multi"
+    launch_probe = getattr(backend, "probe_launch", None)
+    if launch_probe is not None:
+        return launch_probe(op.state, keycodes, counters=counters), "pairs"
+    return Launch(_backend_probe(backend, op.state, keycodes, counters)), "pairs"
+
+
+def run_steps(steps) -> float:
+    """Drive a morsel's steps (``ScanNode.steps``, ``Pipeline.steps``) to
+    their end and return the modelled cost; each pending launch is collected
+    as soon as it is issued."""
+    while True:
+        try:
+            next(steps)
+        except StopIteration as stop:
+            return stop.value
+
+
 def _chain_grant_bounds(conj: Conjunction):
     """Compile a grant's retained conjunction to closed per-attribute
     intervals for the fused-chain kernel, mirroring ``evaluate_conj``
@@ -870,6 +941,13 @@ class Pipeline:
         path (§11) — or the retained per-member oracle path when the
         engine disables ``member_major``; slot-overflow members (beyond the
         64-bit word) run the member-at-a-time slow lane."""
+        return run_steps(self.steps(engine, cols, row_ids, part))
+
+    def steps(self, engine, cols, row_ids: np.ndarray, part: int = 0, overlap: bool = False):
+        """``process`` as a generator returning the cost. With ``overlap``
+        the fused path yields each device launch that is still pending, so
+        the caller can run another unit's host work before it resumes into
+        the launch's collect half (§11); no span is open at a yield."""
         act = self.active_members_for(part)
         if not act:
             return 0.0
@@ -878,7 +956,9 @@ class Pipeline:
         cost = 0.0
         if packed:
             if getattr(engine, "member_major", True):
-                cost += self._process_packed_fused(engine, packed, cols, row_ids, part)
+                cost += yield from self._process_packed_fused(
+                    engine, packed, cols, row_ids, part, overlap
+                )
             else:
                 cost += self._process_packed_members(engine, packed, cols, row_ids, part)
         for m in overflow:
@@ -901,13 +981,16 @@ class Pipeline:
 
     # -- member-major fused path (§11) --------------------------------------
     def _process_packed_fused(
-        self, engine, act: List[Member], cols, row_ids: np.ndarray, part: int
-    ) -> float:
+        self, engine, act: List[Member], cols, row_ids: np.ndarray, part: int,
+        overlap: bool = False,
+    ):
         """One morsel through every stage as packed uint64 mask
         transformations — per-stage cost independent of the member count:
         semijoin visibility is one lens-word translation, stage filters are
         one fused bound-check, sink tagging is one translate + scatter, and
-        aggregate cohorts fold via one (group × member) segmented pass."""
+        aggregate cohorts fold via one (group × member) segmented pass.
+        A generator returning the cost: with ``overlap`` it yields right
+        after each pending chain or probe launch."""
         n = len(row_ids)
         cm = engine.cost_model
         cost = 0.0
@@ -932,10 +1015,11 @@ class Pipeline:
         served = False
         chain_sink = None
         cplan = plan.get("chain")
-        probe_chain = (
-            getattr(backend, "probe_chain", None) if backend is not None else None
+        chain_launch = (
+            getattr(backend, "probe_chain_launch", None) if backend is not None else None
         )
-        if cplan is not None and probe_chain is not None and len(did) > 0:
+        if cplan is not None and chain_launch is not None and len(did) > 0:
+            launch = None
             with span("graftdb.join"):
                 if cplan["ok"]:
                     # one fused launch for the whole stage chain (§13); host
@@ -946,50 +1030,42 @@ class Pipeline:
                         for si, st in enumerate(cplan["stages"])
                         if st["key"][0] == "host"
                     }
-                    res = probe_chain(
+                    launch = chain_launch(
                         cplan, cols, bits, host_keys, counters=engine.counters
                     )
-                    if res is not None:
-                        engine.counters["kernel_chain_launches"] += 1
-                        cost, cols, bits, did, chain_sink = self._replay_chain(
-                            engine, plan, cplan, res, cols, did, cost
-                        )
-                        served = True
                 else:
                     backend.note_fallback(cplan["reason"], engine.counters)
+            if launch is not None:
+                if overlap and launch.pending:
+                    yield launch
+                with span("graftdb.join"):
+                    res = launch.collect()
+                    engine.counters["kernel_chain_launches"] += 1
+                    cost, cols, bits, did, chain_sink = self._replay_chain(
+                        engine, plan, cplan, res, cols, did, cost
+                    )
+                    served = True
         for stage, op in enumerate(self.ops):
             if served or len(did) == 0:
                 break
             with span("graftdb.join"):
                 keycodes = encode_keys(cols, op.probe_attrs)
                 vis_tables, grant_members, kernelable = plan["stages"][stage]
-                lens_fused = False
+                launch, kind = _stage_launch(
+                    backend, op, keycodes, act, grant_members, kernelable, engine.counters
+                )
+            if overlap and launch.pending:
+                yield launch
+            with span("graftdb.join"):
+                lens_fused = kind == "lens"
                 words = None
-                if backend is not None:
-                    if len(act) == 1 and not grant_members:
-                        probe_visible = getattr(backend, "probe_visible", None)
-                        if probe_visible is not None:
-                            fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
-                            if fused_pair is not None:
-                                probe_idx, entry_idx = fused_pair
-                                lens_fused = True
-                                engine.counters["kernel_lens_probes"] += 1
-                    elif kernelable and len(act) > 1:
-                        # multi-member lens: one launch returns every probing
-                        # member's ownership word (the matched entry's packed
-                        # visibility word), translated below
-                        probe_multi = getattr(backend, "probe_visible_multi", None)
-                        if probe_multi is not None:
-                            trip = probe_multi(op.state, keycodes)
-                            if trip is not None:
-                                probe_idx, entry_idx, words = trip
-                                engine.counters["kernel_multi_lens_probes"] += 1
-                    if not lens_fused and words is None:
-                        probe_idx, entry_idx = _backend_probe(
-                            backend, op.state, keycodes, engine.counters
-                        )
+                if kind == "multi":
+                    probe_idx, entry_idx, words = launch.collect()
+                    engine.counters["kernel_multi_lens_probes"] += 1
                 else:
-                    probe_idx, entry_idx = op.state.probe(keycodes)
+                    probe_idx, entry_idx = launch.collect()
+                    if lens_fused:
+                        engine.counters["kernel_lens_probes"] += 1
                 if engine.mesh_plan is not None:
                     # §14: probe rows cross the bucketed all_to_all to their
                     # key shard's device before the shard-local probe
@@ -1600,6 +1676,13 @@ class ScanNode:
         """Emit partition ``part``'s next morsel to every attached pipeline
         with members still owed that shard. Physical read counted once
         (shared scan)."""
+        return run_steps(self.steps(engine, part))
+
+    def steps(self, engine, part: int = 0, overlap: bool = False):
+        """``advance`` as a generator returning the cost; with ``overlap``
+        it passes on each pending launch its pipelines yield
+        (``Pipeline.steps``). The morsel accounting and the cursor bump run
+        when it ends, as in ``advance``."""
         idx = self.cursors[part]
         with span("graftdb.scan"):
             skip = self.zone_maps and not self._wave_possible()[idx]
@@ -1634,7 +1717,7 @@ class ScanNode:
         cost = engine.cost_model["scan"] * n
 
         for p in list(self.pipelines):
-            cost += p.process(engine, cols, row_ids, part)
+            cost += yield from p.steps(engine, cols, row_ids, part, overlap)
         self._bump_cursor(part)
         return cost
 

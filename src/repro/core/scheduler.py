@@ -36,13 +36,13 @@ from __future__ import annotations
 import heapq
 import time
 from dataclasses import dataclass
-from typing import Callable, Dict, Iterable, List, Optional, Tuple
+from typing import Callable, Dict, Generator, Iterable, List, Optional, Tuple
 
 from .engine import GraftEngine, QueryHandle
 from .grafting import candidate_states, graft_potential
 from .plans import Query
 from .reuse import reuse_potential
-from .runtime import Member, Pipeline, ScanNode
+from .runtime import Launch, Member, Pipeline, ScanNode
 from .tracing import span
 
 #: ``Runner._next_unit``: no work remains
@@ -194,6 +194,47 @@ def extract_ready_units(engine: GraftEngine) -> List[Tuple[ScanNode, int]]:
     return units
 
 
+def unit_states(node: ScanNode, part: int) -> Tuple[frozenset, frozenset]:
+    """Ids of the states one unit reads and writes: its active pipelines'
+    probe ops read; their build targets and their members' aggregate sinks
+    are written."""
+    reads, writes = set(), set()
+    for p in node.pipelines:
+        act = p.active_members_for(part)
+        if not act:
+            continue
+        reads.update(id(op.state) for op in p.ops)
+        if p.build_target is not None:
+            writes.add(id(p.build_target.state))
+        writes.update(id(m.sink.agg_state) for m in act if m.sink is not None)
+    return frozenset(reads), frozenset(writes)
+
+
+def _host_only(node: ScanNode, part: int) -> bool:
+    """Whether no active pipeline of the unit probes: it never launches."""
+    return not any(p.ops for p in node.pipelines if p.active_members_for(part))
+
+
+@dataclass
+class _InFlight:
+    """A unit suspended at a pending device launch (``Runner.run``)."""
+
+    node: ScanNode
+    part: int
+    morsel: int
+    steps: Generator
+    reads: frozenset
+    writes: frozenset
+    launch: Optional[Launch] = None
+
+    def independent(self, node: ScanNode, part: int, reads, writes) -> bool:
+        """Another shard, and no state that one unit writes and the other
+        reads or writes."""
+        if node is self.node and part == self.part:
+            return False
+        return not (writes & (self.reads | self.writes) or self.writes & reads)
+
+
 def unit_ready_time(node: ScanNode, part: int) -> float:
     """Barrier time of one unit: the latest activation among the members it
     would serve — a worker adopting the unit advances its clock here first
@@ -315,6 +356,13 @@ class Runner:
 
     One worker with one partition is byte-identical to the seed
     single-worker executor: same unit order, same clock, same timestamps.
+
+    Under a ``WallClock``, with a backend that launches on a device, no
+    mesh and no fault plane, ``run`` keeps up to two independent units in
+    flight (DESIGN.md §11): while one waits on its device launch, the host
+    runs the next ready unit that shares no shard and no written state
+    with it, then resumes the older one. Every other configuration runs
+    each unit to its end in turn, as before.
     """
 
     def __init__(
@@ -371,6 +419,10 @@ class Runner:
         self.faults = getattr(engine, "faults", None)
         self.deadlines: Dict[int, float] = {}
         self.cancelled_qids: Dict[int, str] = {}
+        # units suspended at a pending device launch, oldest first, and
+        # the engine's finish-event count at the last event sweep (§11)
+        self._inflight: List[_InFlight] = []
+        self._events_seen = 0
 
     def add_arrival(self, query: Query) -> None:
         # keyed by (arrival, qid): permuted add_arrival orders of one trace
@@ -753,12 +805,17 @@ class Runner:
         engine = self.engine
         for q in arrivals:
             self.add_arrival(q)
+        overlap = self._overlaps()
+        self._events_seen = engine.finish_events
         steps = 0
         try:
-            while self._heap or self._admit_queue or engine.has_active_work():
+            while self._inflight or self._heap or self._admit_queue or engine.has_active_work():
                 steps += 1
                 if steps > max_steps:
                     raise RuntimeError("executor exceeded max_steps — livelock?")
+                if self._inflight:
+                    self._overlap_step(on_complete)
+                    continue
                 with span("graftdb.schedule"):
                     step = self._next_unit(on_complete)
                 if step is _STOP:
@@ -766,6 +823,9 @@ class Runner:
                 if step is None:
                     continue
                 wi, node, part = step
+                if overlap:
+                    self._start(node, part, on_complete)
+                    continue
                 t0 = time.perf_counter()
                 with span("graftdb.unit", scan=node.sid, part=part, morsel=node.cursors[part]):
                     cost = node.advance(engine, part)
@@ -776,8 +836,108 @@ class Runner:
                 with span("graftdb.schedule"):
                     self._after_events(on_complete)
         finally:
+            for unit in self._inflight:
+                unit.steps.close()
+            self._inflight.clear()
             self.clock.current = None
         return engine.completed
+
+    # -- two units in flight (§11) -------------------------------------------
+    def _overlaps(self) -> bool:
+        """Whether ``run`` may hold two units in flight: real time on one
+        worker, a backend with launch halves, no mesh (§14 keeps its
+        device-affinity order) and no fault plane (a fault gate precedes
+        every unit)."""
+        return (
+            self.workers == 1
+            and isinstance(self.clocks[0], WallClock)
+            and self.engine.mesh_plan is None
+            and self.faults is None
+            and hasattr(self.engine.backend, "probe_chain_launch")
+        )
+
+    def _start(self, node: ScanNode, part: int, on_complete) -> None:
+        reads, writes = unit_states(node, part)
+        steps = node.steps(self.engine, part, overlap=True)
+        self._resume(_InFlight(node, part, node.cursors[part], steps, reads, writes), on_complete)
+
+    def _resume(self, unit: _InFlight, on_complete) -> None:
+        """Run one unit on to its next pending launch, where it joins the
+        in-flight list, or to its end, and then the event sweep. Where the
+        sweep will complete a query (release its states, call back, admit
+        and graft) or release a lens lease, the other units drain first;
+        activations alone only widen the state sets of the units in flight
+        on the activated members' scans."""
+        engine = self.engine
+        t0 = time.perf_counter()
+        with span("graftdb.unit", scan=unit.node.sid, part=unit.part, morsel=unit.morsel):
+            try:
+                unit.launch = next(unit.steps)
+                ended = False
+            except StopIteration:
+                ended = True
+        self.busy_s[0] += time.perf_counter() - t0
+        if not ended:
+            if self._inflight:
+                engine.counters["overlapped_launches"] += 1
+            self._inflight.append(unit)
+            return
+        if self._inflight and engine.finish_events != self._events_seen:
+            if engine.completions_due() or engine.has_lens_leases():
+                self._drain(on_complete)
+        with span("graftdb.schedule"):
+            self._after_events(on_complete)
+            for u in self._inflight:
+                u.reads, u.writes = unit_states(u.node, u.part)
+        self._events_seen = engine.finish_events
+
+    def _drain(self, on_complete) -> None:
+        """Run every in-flight unit to its end, oldest first."""
+        while self._inflight:
+            self._resume(self._inflight.pop(0), on_complete)
+
+    def _overlap_step(self, on_complete) -> None:
+        """One step with units in flight: beside a lone one, start an
+        independent partner (or drain when there is none); while the
+        oldest one's launch still runs, independent units that never
+        launch fill the wait; then resume the oldest."""
+        if len(self._inflight) == 1:
+            with span("graftdb.schedule"):
+                partner = self._partner()
+            if partner is None:
+                self._drain(on_complete)
+                return
+            self._start(*partner, on_complete)
+        while self._inflight and not self._inflight[0].launch.ready():
+            with span("graftdb.schedule"):
+                filler = self._partner(host_only=True)
+            if filler is None:
+                break
+            self._start(*filler, on_complete)
+        if self._inflight:
+            self._resume(self._inflight.pop(0), on_complete)
+
+    def _partner(self, host_only: bool = False) -> Optional[Tuple[ScanNode, int]]:
+        """The next ready unit in round-robin order that is independent of
+        the ones in flight (with ``host_only``, one that never launches),
+        or None when they have to drain first: a deadline, an arrival or a
+        queued admission is due (each acts at a decision step), or no
+        ready unit is independent."""
+        now = self.clock.now
+        if (self._heap and self._heap[0][0] <= now) or self._admit_queue:
+            return None
+        if any(d <= now for d in self.deadlines.values()):
+            return None
+        units = extract_ready_units(self.engine)
+        later = [u for u in units if (u[0].sid, u[1]) > self._rr]
+        for node, part in later + units[: len(units) - len(later)]:
+            if unit_ready_time(node, part) > now or (host_only and not _host_only(node, part)):
+                continue
+            reads, writes = unit_states(node, part)
+            if all(u.independent(node, part, reads, writes) for u in self._inflight):
+                self._rr = (node.sid, part)
+                return node, part
+        return None
 
     def _next_unit(self, on_complete):
         """One decision step of ``run``: due deadlines and admissions, then

@@ -92,8 +92,32 @@ def test_each_span_occurs(traced):
     assert not missing, missing
 
 
-def test_children_close_inside_their_parents(traced):
-    t, _ = traced
+@pytest.fixture(scope="module")
+def pipelined(db, tmp_path_factory):
+    """One traced session with two units in flight: four isolated queries
+    on the ``pallas`` backend under the wall clock."""
+    import jax
+
+    sys.path.insert(0, str(ROOT))
+    from bench.harness import program_spans, trace
+
+    out = tmp_path_factory.mktemp("xplane")
+    session = graftdb.connect(db, EngineConfig(mode="isolated", backend="pallas", clock="wall",
+                                               morsel_size=8192))
+    rng = np.random.default_rng(5)
+    qs = [queries.make_query(db, t, queries._sample_params(t, rng), arrival=session.now)
+          for t in ("q3", "q5", "q10", "q9")]
+    with jax.profiler.trace(str(out)):
+        futs = session.submit_all(qs)
+        session.run()
+    assert all(f.status == "done" for f in futs)
+    assert session.counters["overlapped_launches"] > 0
+    return program_spans.load(trace.latest_xplane(str(out)))
+
+
+def _nested_spans(t):
+    """Checks that every span closes inside the span open around its start
+    on its thread; returns how many program spans have a parent."""
     nested = 0
     for spans in t["threads"].values():
         stack = []
@@ -104,7 +128,38 @@ def test_children_close_inside_their_parents(traced):
                 assert e <= stack[-1][1], (name, "inside", stack[-1][2])
                 nested += name.startswith("graftdb.")
             stack.append((s, e, name))
-    assert nested > 100
+    return nested
+
+
+def test_children_close_inside_their_parents(traced):
+    t, _ = traced
+    assert _nested_spans(t) > 100
+
+
+def test_spans_nest_with_units_in_flight(pipelined):
+    """No span straddles a unit's yield: every span nests on its thread,
+    and the self times on each thread add up to no more than its extent."""
+    from bench.harness import program_spans
+
+    assert _nested_spans(pipelined) > 100
+    units = 0
+    for key, spans in pipelined["threads"].items():
+        own = [sp for sp in spans if sp[2].startswith("graftdb.")]
+        if not own:
+            continue
+        lo, hi = min(s for s, _, _ in own), max(e for _, e, _ in own)
+        self_s = program_spans.self_times({key: own}, lo, hi)
+        assert sum(self_s.values()) <= (hi - lo) / 1e9 + 1e-9
+        units += sum(n == "graftdb.unit" for _, _, n in own)
+    metas = [m for (_, _, n), m in pipelined["meta"].items() if n == "graftdb.unit"]
+    # a unit resumed after its launch records one span per piece, each
+    # with the unit's own scan, partition and morsel
+    assert units == len(metas) and all({"scan", "part", "morsel"} <= set(m) for m in metas)
+    pieces = {}
+    for m in metas:
+        k = (m["scan"], m["part"], m["morsel"])
+        pieces[k] = pieces.get(k, 0) + 1
+    assert max(pieces.values()) > 1
 
 
 def test_admit_spans_carry_their_query_ids(traced):
