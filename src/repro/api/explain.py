@@ -31,8 +31,8 @@ from ..core.descriptors import aggregate_signature, hash_build_signature
 from ..core.grafting import (
     all_boundaries,
     build_spine,
+    boundary_demand,
     demand_keycodes,
-    estimate_demand,
     plan_spine,
 )
 from ..core.hashindex import key_partition
@@ -272,12 +272,14 @@ def _build_table(join: HashJoin) -> str:
 
 def _demand_split(engine, join: HashJoin, demand: int) -> np.ndarray:
     """Key-hash partition split of this boundary's isolated-plan demand
-    (sums to ``estimate_demand`` exactly — same row set, same masks).
+    (sums to ``boundary_demand`` exactly — same row set, same masks).
     Unpartitioned engines short-circuit: the split is trivially [demand]
     and the per-row keycode pass is skipped."""
     if engine.n_partitions == 1:
         return np.array([demand], dtype=np.int64)
     codes = demand_keycodes(engine, join.build, tuple(join.build_keys))
+    if join.kind == "semi":  # a semi state holds each key once
+        codes = np.unique(codes)
     parts = key_partition(codes, engine.n_partitions)
     return np.bincount(parts, minlength=engine.n_partitions).astype(np.int64)
 
@@ -289,7 +291,7 @@ def _zeros_like(split: np.ndarray) -> Tuple[int, ...]:
 def _eliminated(
     engine, join: HashJoin, depth: int, served_from_cache: bool = False
 ) -> BoundaryExplain:
-    demand = estimate_demand(engine, join.build)
+    demand = boundary_demand(engine, join)
     split = _demand_split(engine, join, demand)
     return BoundaryExplain(
         build_table=_build_table(join),
@@ -312,7 +314,7 @@ def _explain_boundary(engine, join: HashJoin, depth: int) -> BoundaryExplain:
     mode = engine.mode
     sig = hash_build_signature(join)
     b_q = Conjunction.from_pred(collect_subtree_pred(join.build))
-    demand = estimate_demand(engine, join.build)
+    demand = boundary_demand(engine, join)
     table = _build_table(join)
     split = _demand_split(engine, join, demand)
 
@@ -341,13 +343,10 @@ def _explain_boundary(engine, join: HashJoin, depth: int) -> BoundaryExplain:
     # Represented extent: proven containment against allowed coverage.
     if candidate is not None and mode.allow_represented and b_q is not None:
         retained = candidate.retained_attrs
-        b_ret = Conjunction({a: c for a, c in b_q.constraints.items() if a in retained})
-        b_nonret = Conjunction(
-            {a: c for a, c in b_q.constraints.items() if a not in retained}
-        )
+        b_ret, b_nonret = b_q.split(retained)
         allowed = (
             ALL_EXTENTS
-            if not b_nonret.constraints
+            if b_nonret.is_true()
             else candidate.allowed_extents_for(b_nonret)
         )
         if allowed:

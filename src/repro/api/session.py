@@ -62,11 +62,12 @@ class Session:
             mesh_plan=self._mesh_plan,
             faults=self.config.faults,
         )
-        if self._mesh_plan is not None and hasattr(self.backend, "probe_chain"):
-            # single-device data mesh: the fused stage chain runs inside
-            # shard_map on the session mesh (§14); multi-device routing goes
-            # through the bucketed exchange instead
-            self.backend.mesh = self.mesh if self._mesh_plan.n_shards == 1 else None
+        precompile = getattr(self.backend, "precompile", None)
+        if precompile is not None:
+            # every device program's shape comes from a ladder fixed by the
+            # database and the morsel size; compile it now, so that no
+            # query waits for a compile (DESIGN.md §13)
+            precompile(db, self.config.morsel_size)
         admission = self.config.make_admission()
         batch_kw = dict(
             batch_planning=self.config.batch_planning,

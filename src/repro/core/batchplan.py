@@ -39,7 +39,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from .descriptors import StateSignature, aggregate_signature
-from .grafting import boundary_key, build_spine, coverage_probe, estimate_demand, plan_spine
+from .grafting import boundary_demand, boundary_key, build_spine, coverage_probe, plan_spine
 from .plans import PlanNode, Query
 from .predicates import Conjunction
 
@@ -100,7 +100,7 @@ def _profile_join(engine, join) -> BoundaryProfile:
     sig, b_q = boundary_key(join)
     _, inner = build_spine(join.build)
     children = tuple(_profile_join(engine, ij) for ij in inner)
-    return BoundaryProfile(sig, b_q, estimate_demand(engine, join.build), children)
+    return BoundaryProfile(sig, b_q, boundary_demand(engine, join), children)
 
 
 def profile_query(engine, query: Query) -> QueryProfile:

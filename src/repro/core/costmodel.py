@@ -80,9 +80,9 @@ def score_arrival(engine, query) -> Dict[str, object]:
     live = graft_potential(engine, query)
     cached = reuse_potential(engine, query)
 
-    from .grafting import all_boundaries, estimate_demand
+    from .grafting import all_boundaries, boundary_demand
 
-    demand = sum(estimate_demand(engine, b.build) for b in all_boundaries(query.plan))
+    demand = sum(boundary_demand(engine, b) for b in all_boundaries(query.plan))
     recompute_s = demand * row
     scores = {
         "recompute_s": recompute_s,

@@ -3,8 +3,9 @@
 A *state signature* fixes the exact non-predicate identity of a shared state:
 
 * hash-build state: build relation subtree (structure only), build keys,
-  payload layout, and required upstream state (captured structurally by the
-  subtree skeleton). Predicates are NOT part of the signature — they live in
+  payload layout, join kind (a semi-join's state holds keys, not rows, so
+  no inner join may read it), and required upstream state (captured
+  structurally by the subtree skeleton). Predicates are NOT part of the signature — they live in
   coverage metadata, so one physical table can cover several predicate
   extents.
 * aggregate state: exact aggregate identity — the aggregate input *including
@@ -42,6 +43,7 @@ def hash_build_signature(join: HashJoin) -> StateSignature:
             strip_pred_subtree(join.build),
             tuple(join.build_keys),
             tuple(join.payload),
+            join.kind,
         ),
     )
 
@@ -49,9 +51,10 @@ def hash_build_signature(join: HashJoin) -> StateSignature:
 def aggregate_signature(agg: Aggregate) -> Optional[StateSignature]:
     """Exact aggregate identity. Includes the canonicalized per-query input
     condition; returns None when the input condition is outside the
-    supported predicate fragment (identity then unprovable -> no sharing)."""
+    supported predicate fragment or compares two columns (identity then
+    unprovable -> no sharing)."""
     cond = Conjunction.from_pred(collect_subtree_pred(agg.input))
-    if cond is None:
+    if cond is None or cond.atoms:
         return None
     return StateSignature(
         kind="aggregate",

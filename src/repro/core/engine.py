@@ -27,11 +27,11 @@ import numpy as np
 from ..relational.table import Database
 from .descriptors import StateSignature, aggregate_signature
 from .faults import FaultPlan, FaultPlane
-from .grafting import all_boundaries, estimate_demand, plan_spine, resolve_boundary
-from .plans import Aggregate, OrderBy, Query
+from .grafting import all_boundaries, boundary_demand, build_spine, plan_spine, resolve_boundary
+from .plans import Aggregate, OrderBy, Query, Scan
 from .predicates import TRUE, Conjunction
 from .reuse import ReusePlane
-from .runtime import AggGate, AggSink, Gate, Member, Pipeline, ProbeOp, ScanNode
+from .runtime import AggGate, AggSink, Gate, Member, Pipeline, ProbeOp, ScanNode, encode_keys
 from .state import SharedAggregateState, SharedHashBuildState, StateLifecycle
 from .tracing import span
 
@@ -119,6 +119,11 @@ class QueryHandle:
         # the query unfolded to isolated execution after a fault (§16):
         # surfaced in stats() and as the EXPLAIN GRAFT ``degraded`` flag
         self.degraded = False
+        # EXPLAIN GRAFT accounting of the admission: entries its boundaries
+        # demand, and of those the entries represented by shared state
+        # (engine counters add them up when the query completes)
+        self.graft_demand_rows = 0
+        self.graft_represented_rows = 0
 
     @property
     def latency(self) -> float:
@@ -216,6 +221,14 @@ class GraftEngine:
             "fallback_probes_capacity",
             "fallback_probes_predicate",
             "agg_cohort_rows",
+            # semi-joins: semi states built, and attachments of a query to
+            # a semi state another query built
+            "semi_builds",
+            "semi_attaches",
+            # EXPLAIN GRAFT accounting summed over completed queries:
+            # demanded entries, and those represented by grafted state
+            "graft_demand_rows",
+            "graft_represented_rows",
             "overflow_members",
             "partition_merges",
             "partition_probe_merges",
@@ -307,6 +320,7 @@ class GraftEngine:
         # residual producers. The greedy path never sets it.
         self.cohort_ctx: Optional[Dict[int, List]] = None
         self._domains: Dict[str, int] = {}
+        self._distinct_keys: Dict[tuple, int] = {}
         self._next_state_id = 0
         self._agg_producers: Dict[int, SharedAggregateState] = {}  # member.mid -> agg
         # engine-scoped runtime-object ids (no class-counter leaks across
@@ -331,6 +345,8 @@ class GraftEngine:
         state.attach(handle.qid)
         handle.attached_states.append(state)
         self.lifecycle.revive(state)
+        if state.semi:
+            self.counters["semi_attaches"] += 1
 
     def next_member_id(self) -> int:
         self._next_mid += 1
@@ -365,7 +381,24 @@ class GraftEngine:
             did_domain,
             counters=self.counters,
             n_partitions=self.n_partitions,
+            semi=join.kind == "semi",
+            max_entries=self._max_entries(join),
         )
+
+    def _max_entries(self, join) -> int:
+        """The most entries the boundary's state can hold, from the
+        database: its build source's rows, or for a semi-join over a plain
+        scan the source's distinct keys (cached per table and key)."""
+        scan, _ = build_spine(join.build)
+        table = self.db[scan.table]
+        if join.kind != "semi" or not isinstance(join.build, Scan):
+            return table.nrows
+        key = (scan.table, tuple(join.build_keys))
+        n = self._distinct_keys.get(key)
+        if n is None:
+            codes = encode_keys(table.columns, tuple(join.build_keys))
+            n = self._distinct_keys[key] = len(np.unique(codes))
+        return n
 
     # -- submission (query grafting, §5.2) ------------------------------------
     def submit(self, query: Query) -> QueryHandle:
@@ -404,9 +437,11 @@ class GraftEngine:
                 handle.agg_gate = AggGate(existing)
                 self.counters["agg_attaches"] += 1
                 for b in all_boundaries(query.plan):
-                    d = estimate_demand(self, b.build)
+                    d = boundary_demand(self, b)
                     self.counters["demand_rows"] += d
                     self.counters["eliminated_rows"] += d
+                    handle.graft_demand_rows += d
+                    handle.graft_represented_rows += d
                 self.state_gen += 1
                 self._maybe_complete(handle)
                 return
@@ -420,7 +455,8 @@ class GraftEngine:
             gates.append(att.gate)
             out_names = j.payload_as if j.payload_as is not None else j.payload
             ops.append(
-                ProbeOp(att.state, tuple(j.probe_keys), tuple(j.payload), tuple(out_names))
+                ProbeOp(att.state, tuple(j.probe_keys), tuple(j.payload), tuple(out_names),
+                        j.kind)
             )
             if j.post_filter is not TRUE:
                 stage_filters.setdefault(stage, []).append(j.post_filter)
@@ -561,6 +597,8 @@ class GraftEngine:
             self.active_handles.remove(handle)
             self.completed.append(handle)
             self.counters["completed"] += 1
+            self.counters["graft_demand_rows"] += handle.graft_demand_rows
+            self.counters["graft_represented_rows"] += handle.graft_represented_rows
             self._release(handle)
         return True
 

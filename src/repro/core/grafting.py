@@ -150,6 +150,17 @@ def _subtree_count(engine, node: PlanNode, need_keys: Optional[Tuple[str, ...]])
     return result
 
 
+def boundary_demand(engine, join: HashJoin) -> int:
+    """Entries an isolated execution would build at this boundary: the rows
+    of its build subtree, or for a semi-join the distinct build keys (its
+    state holds each key once), so that represented entries, residual and
+    unattached work count in the same unit."""
+    if join.kind == "semi":
+        _, keys = _subtree_count(engine, join.build, tuple(join.build_keys))
+        return len(keys)
+    return estimate_demand(engine, join.build)
+
+
 def demand_keycodes(engine, node: PlanNode, key_attrs: Tuple[str, ...]) -> np.ndarray:
     """Per-row key codes (``key_attrs``) of every row an isolated execution
     would feed into the enclosing boundary's hash build — the non-unique
@@ -203,7 +214,7 @@ def graft_potential(engine, query) -> float:
         return 0.0
     total = shared = 0
     for j in all_boundaries(query.plan):
-        d = estimate_demand(engine, j.build)
+        d = boundary_demand(engine, j)
         total += d
         if engine.state_index.get(hash_build_signature(j)):
             shared += d
@@ -257,9 +268,8 @@ def coverage_probe(engine, sig: StateSignature, b_q: Optional[Conjunction], dema
     if candidate is None:
         return False, 0
     retained = candidate.retained_attrs
-    b_ret = Conjunction({a: c for a, c in b_q.constraints.items() if a in retained})
-    b_nonret = Conjunction({a: c for a, c in b_q.constraints.items() if a not in retained})
-    allowed = ALL_EXTENTS if not b_nonret.constraints else candidate.allowed_extents_for(b_nonret)
+    b_ret, b_nonret = b_q.split(retained)
+    allowed = ALL_EXTENTS if b_nonret.is_true() else candidate.allowed_extents_for(b_nonret)
     if not allowed:
         return False, 0
     if candidate.covers_with(b_q, allowed):
@@ -312,8 +322,9 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
     sig, b_q = boundary_key(join)
 
     # counters: isolated-plan demand at this boundary
-    demand = estimate_demand(engine, join.build)
+    demand = boundary_demand(engine, join)
     engine.counters["demand_rows"] += demand
+    handle.graft_demand_rows += demand
 
     # -- CheckLensCompatibility: exact non-predicate identity via signature
     candidate: Optional[SharedHashBuildState] = None
@@ -331,13 +342,8 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
     # -- Represented extent: proven containment against allowed coverage
     if candidate is not None and mode.allow_represented and b_q is not None:
         retained = candidate.retained_attrs
-        b_ret = Conjunction(
-            {a: c for a, c in b_q.constraints.items() if a in retained}
-        )
-        b_nonret = Conjunction(
-            {a: c for a, c in b_q.constraints.items() if a not in retained}
-        )
-        if not b_nonret.constraints:
+        b_ret, b_nonret = b_q.split(retained)
+        if b_nonret.is_true():
             allowed = ALL_EXTENTS
         else:
             allowed = candidate.allowed_extents_for(b_nonret)
@@ -352,7 +358,7 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
             for p_eid, p_conj, p_member in engine.cohort_ctx.get(
                 candidate.state_id, ()
             ):
-                if not b_nonret.constraints or p_conj.implies(b_nonret):
+                if b_nonret.is_true() or p_conj.implies(b_nonret):
                     pend_mask |= np.uint64(1) << np.uint64(p_eid)
                     pend_members.append(p_member)
                     pend_conjs.append(p_conj)
@@ -361,11 +367,8 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
             engine.attach_shared(handle, candidate)
             candidate.add_grant(qid, allowed, b_ret)
             engine.counters["represented_rows"] += candidate.count_granted(allowed, b_ret)
-            # upstream producer work eliminated by this state-lens obs.
-            for up in all_boundaries(join.build):
-                d = estimate_demand(engine, up.build)
-                engine.counters["demand_rows"] += d
-                engine.counters["eliminated_rows"] += d
+            handle.graft_represented_rows += demand
+            _eliminate_upstream(engine, handle, join)
             gate = Gate(candidate, b_q, allowed)
             gate.owner_qid = qid
             return Attachment(candidate, gate, created=False)
@@ -380,10 +383,8 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
             engine.attach_shared(handle, candidate)
             candidate.add_grant(qid, allowed | pend_mask, b_ret)
             engine.counters["represented_rows"] += candidate.count_granted(allowed, b_ret)
-            for up in all_boundaries(join.build):
-                d = estimate_demand(engine, up.build)
-                engine.counters["demand_rows"] += d
-                engine.counters["eliminated_rows"] += d
+            handle.graft_represented_rows += demand
+            _eliminate_upstream(engine, handle, join)
             gate = Gate(candidate, b_q, allowed | pend_mask)
             gate.owner_qid = qid
             for p_member in pend_members:
@@ -396,7 +397,9 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
             # allowed set so the gate can open on its completion).
             engine.attach_shared(handle, candidate)
             candidate.add_grant(qid, allowed, b_ret)
-            engine.counters["represented_rows"] += candidate.count_granted(allowed, b_ret)
+            granted = candidate.count_granted(allowed, b_ret)
+            engine.counters["represented_rows"] += granted
+            handle.graft_represented_rows += min(granted, demand)
             member, eid = _install_producer(engine, handle, join, candidate, b_q, kind="residual")
             _record_cohort_extent(engine, candidate, eid, b_q, member)
             if eid >= 0:
@@ -432,6 +435,8 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
 
     # -- Ordinary-plan work: fresh state (which becomes shared state itself)
     state = engine.new_hash_state(sig, join, did_domain=_did_domain(engine, join.build))
+    if state.semi:
+        engine.counters["semi_builds"] += 1
     state.attach(qid)
     handle.attached_states.append(state)
     if mode.share_state:
@@ -445,6 +450,18 @@ def _resolve_boundary(engine, handle, join: HashJoin) -> Attachment:
     if mode.qpipe:
         engine.qpipe_registry[_qpipe_key(sig, join, b_q)] = (member, state)
     return Attachment(state, gate, created=True, producer_member=member)
+
+
+def _eliminate_upstream(engine, handle, join: HashJoin) -> None:
+    """Count the producer work a represented attachment eliminates: every
+    boundary nested in the build subtree, as EXPLAIN GRAFT's
+    ``eliminated`` rows (represented in full)."""
+    for up in all_boundaries(join.build):
+        d = boundary_demand(engine, up)
+        engine.counters["demand_rows"] += d
+        engine.counters["eliminated_rows"] += d
+        handle.graft_demand_rows += d
+        handle.graft_represented_rows += d
 
 
 def _record_cohort_extent(engine, state, eid: int, b_q, member) -> None:
@@ -471,7 +488,8 @@ def _install_producer(
         inner_gates.append(att.gate)
         out_names = ij.payload_as if ij.payload_as is not None else ij.payload
         inner_ops.append(
-            ProbeOp(att.state, tuple(ij.probe_keys), tuple(ij.payload), tuple(out_names))
+            ProbeOp(att.state, tuple(ij.probe_keys), tuple(ij.payload), tuple(out_names),
+                    ij.kind)
         )
         from .predicates import TRUE
 
@@ -520,7 +538,8 @@ def _did_domain(engine, subtree: PlanNode) -> int:
         scan, joins = build_spine(subtree)
         d = engine.db[scan.table].nrows
         for j in joins:
-            d *= _did_domain(engine, j.build)
+            if j.kind != "semi":  # a semi stage keeps its probe row's id
+                d *= _did_domain(engine, j.build)
         return d
     raise TypeError(subtree)
 

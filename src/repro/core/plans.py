@@ -116,8 +116,12 @@ class Scan:
 
 @dataclass
 class HashJoin:
-    """Inner equi hash join. ``build`` is the state-side input subtree;
+    """Equi hash join. ``build`` is the state-side input subtree;
     ``probe`` drives lookups (consumer-side data flow, §3.3).
+
+    ``kind`` is ``"inner"`` or ``"semi"``. A semi-join (SQL's ``EXISTS``)
+    emits each probe row at most once, when any build row matches its key;
+    it carries no payload, and its state holds each build key once.
 
     ``payload_as`` optionally renames payload attrs in the join output (the
     state keeps canonical names so sharing is preserved; e.g. TPC-H Q7 probes
@@ -132,6 +136,13 @@ class HashJoin:
     payload: Tuple[str, ...]  # build-side attrs carried to output (RetainedAttrs)
     payload_as: Optional[Tuple[str, ...]] = None
     post_filter: Pred = TRUE
+    kind: str = "inner"
+
+    def __post_init__(self):
+        if self.kind not in ("inner", "semi"):
+            raise ValueError(f"join kind must be 'inner' or 'semi', got {self.kind!r}")
+        if self.kind == "semi" and self.payload:
+            raise ValueError("a semi-join carries no payload")
 
 
 @dataclass(frozen=True)
@@ -235,6 +246,7 @@ def strip_pred_subtree(node: PlanNode):
             tuple(node.probe_keys),
             tuple(node.payload),
             tuple(node.payload_as) if node.payload_as is not None else None,
+            node.kind,
         )
     if isinstance(node, Aggregate):
         return (

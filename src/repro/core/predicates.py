@@ -9,8 +9,10 @@ Implements the sound-but-incomplete predicate fragment of GraftDB §4.2:
 * per-attribute range-containment rules applied independently over comparable
   scalar domains.
 
-Anything outside the fragment (disjunctions, NULL-sensitive forms, cross
-attribute expressions) canonicalizes to ``None`` and is treated as UNPROVEN.
+A comparison between two attributes (``ColCmp``) enters a conjunction as an
+opaque atom that only an identical atom implies. Anything else outside the
+fragment (disjunctions, NULL-sensitive forms) canonicalizes to ``None`` and
+is treated as UNPROVEN.
 Unproven obligations never classify an extent as represented — they fall to
 residual production or ordinary-plan work (lost sharing, never unsafe
 sharing).
@@ -69,10 +71,11 @@ _COL_OPS = ("<", "<=", ">", ">=", "==", "!=")
 @dataclass(frozen=True)
 class ColCmp:
     """attr_a <op> attr_b — cross-attribute comparison (e.g. TPC-H Q5's
-    c_nationkey = s_nationkey, Q4's l_commitdate < l_receiptdate). Evaluable,
-    but OUTSIDE the prover fragment: canonicalization returns None, so such
-    predicates are never used to classify an extent as represented
-    (unproven -> lost sharing, never unsafe sharing)."""
+    c_nationkey = s_nationkey, Q4's l_commitdate < l_receiptdate). The
+    prover treats it as an opaque *atom*: a conjunction implies another only
+    if it carries every atom of the other, letter for letter (up to swapping
+    sides), so a parameter-free comparison such as Q4's can prove one
+    extent covers another, and any other use only loses sharing."""
 
     lhs: str
     op: str
@@ -84,6 +87,17 @@ class ColCmp:
 
 
 TRUE = And(())
+
+_FLIP = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "==": "==", "!=": "!="}
+
+
+def _atom(p: ColCmp) -> Tuple[str, str, str]:
+    """Canonical (lhs, op, rhs) of a column comparison: ``>``/``>=`` turn
+    round to ``<``/``<=``, and symmetric ops order their sides."""
+    lhs, op, rhs = p.lhs, p.op, p.rhs
+    if op in (">", ">=") or (op in ("==", "!=") and rhs < lhs):
+        lhs, op, rhs = rhs, _FLIP[op], lhs
+    return (lhs, op, rhs)
 
 Pred = object  # Cmp | InSet | And | ColCmp
 
@@ -217,20 +231,27 @@ class AttrConstraint:
 class Conjunction:
     """Canonical conjunction: attr -> AttrConstraint. Hash/eq by content."""
 
-    __slots__ = ("constraints",)
+    __slots__ = ("constraints", "atoms")
 
-    def __init__(self, constraints: Optional[Dict[str, AttrConstraint]] = None):
+    def __init__(
+        self,
+        constraints: Optional[Dict[str, AttrConstraint]] = None,
+        atoms: Iterable[Tuple[str, str, str]] = (),
+    ):
         cons = dict(constraints or {})
         # Normalize away no-op constraints so TRUE has a unique form.
         self.constraints: Dict[str, AttrConstraint] = {
             a: c for a, c in cons.items() if not c.is_unconstrained()
         }
+        #: canonical column comparisons (``_atom``), matched by identity
+        self.atoms: FrozenSet[Tuple[str, str, str]] = frozenset(atoms)
 
     # -- construction ------------------------------------------------------
     @staticmethod
     def from_pred(pred: Pred) -> Optional["Conjunction"]:
         """Canonicalize a predicate. Returns None outside the fragment."""
         cons: Dict[str, AttrConstraint] = {}
+        atoms: List[Tuple[str, str, str]] = []
 
         def add(attr: str, c: AttrConstraint):
             cons[attr] = cons[attr].intersect(c) if attr in cons else c
@@ -256,11 +277,14 @@ class Conjunction:
             if isinstance(p, InSet):
                 add(p.attr, AttrConstraint(members=frozenset(float(v) for v in p.values)))
                 return True
+            if isinstance(p, ColCmp):
+                atoms.append(_atom(p))
+                return True
             return False  # unsupported node -> outside the fragment
 
         if not walk(pred):
             return None
-        return Conjunction(cons)
+        return Conjunction(cons, atoms)
 
     # -- relations ----------------------------------------------------------
     def implies(self, other: "Conjunction") -> bool:
@@ -269,6 +293,8 @@ class Conjunction:
         on our side means we are weaker there -> unproven."""
         if self.is_empty():
             return True
+        if not other.atoms <= self.atoms:
+            return False
         for attr, oc in other.constraints.items():
             sc = self.constraints.get(attr)
             if sc is None:
@@ -281,17 +307,37 @@ class Conjunction:
         cons = dict(self.constraints)
         for a, c in other.constraints.items():
             cons[a] = cons[a].intersect(c) if a in cons else c
-        return Conjunction(cons)
+        return Conjunction(cons, self.atoms | other.atoms)
 
     def is_empty(self) -> bool:
         return any(c.is_empty() for c in self.constraints.values())
 
+    def is_true(self) -> bool:
+        """No constraint and no atom: every row satisfies it."""
+        return not self.constraints and not self.atoms
+
     def attrs(self) -> FrozenSet[str]:
-        return frozenset(self.constraints)
+        out = frozenset(self.constraints)
+        for lhs, _, rhs in self.atoms:
+            out = out | {lhs, rhs}
+        return out
+
+    def split(self, attrs) -> Tuple["Conjunction", "Conjunction"]:
+        """(the part evaluable on ``attrs``, the rest): constraints by their
+        attribute, atoms whole — an atom goes to the first part only if both
+        of its columns are in ``attrs``."""
+        inside = {a: c for a, c in self.constraints.items() if a in attrs}
+        outside = {a: c for a, c in self.constraints.items() if a not in attrs}
+        a_in = [t for t in self.atoms if t[0] in attrs and t[2] in attrs]
+        a_out = [t for t in self.atoms if not (t[0] in attrs and t[2] in attrs)]
+        return Conjunction(inside, a_in), Conjunction(outside, a_out)
 
     # -- hashing ------------------------------------------------------------
     def key(self):
-        return tuple(sorted((a, c.key()) for a, c in self.constraints.items()))
+        k = tuple(sorted((a, c.key()) for a, c in self.constraints.items()))
+        if self.atoms:
+            k = k + (("__atoms__", tuple(sorted(self.atoms))),)
+        return k
 
     def __eq__(self, other):
         return isinstance(other, Conjunction) and self.key() == other.key()
@@ -300,9 +346,9 @@ class Conjunction:
         return hash(self.key())
 
     def __repr__(self):
-        if not self.constraints:
+        if self.is_true():
             return "Conjunction(TRUE)"
-        parts = []
+        parts = [f"{lhs} {op} {rhs}" for lhs, op, rhs in sorted(self.atoms)]
         for a, c in sorted(self.constraints.items()):
             s = a
             if c.members is not None:
@@ -327,6 +373,8 @@ def _try_merge(a: Conjunction, b: Conjunction) -> Optional[Conjunction]:
     """Merge two conjunctions that agree on all attrs except at most one,
     where their intervals overlap or touch. Sound widening used only for
     coverage bookkeeping (the union of complete extents stays complete)."""
+    if a.atoms != b.atoms:
+        return None
     attrs = set(a.constraints) | set(b.constraints)
     diff = [
         t
@@ -347,7 +395,7 @@ def _try_merge(a: Conjunction, b: Conjunction) -> Optional[Conjunction]:
             merged = AttrConstraint(ca.lo, ca.lo_inc, ca.hi, ca.hi_inc, ca.members | cb.members)
             cons = dict(a.constraints)
             cons[t] = merged
-            return Conjunction(cons)
+            return Conjunction(cons, a.atoms)
         return None
     lo_first, hi_first = (ca, cb) if (ca.lo, not ca.lo_inc) <= (cb.lo, not cb.lo_inc) else (cb, ca)
     # Overlap or touch: second interval must start at or before first's end.
@@ -360,7 +408,7 @@ def _try_merge(a: Conjunction, b: Conjunction) -> Optional[Conjunction]:
     merged = AttrConstraint(lo_first.lo, lo_first.lo_inc, hi, hi_inc, None)
     cons = dict(a.constraints)
     cons[t] = merged
-    return Conjunction(cons)
+    return Conjunction(cons, a.atoms)
 
 
 class Coverage:
@@ -490,4 +538,6 @@ def evaluate_conj(conj: Conjunction, cols: Dict[str, np.ndarray]) -> np.ndarray:
         if c.members is not None:
             vals = np.fromiter(c.members, dtype=np.float64, count=len(c.members))
             mask &= np.isin(col, vals)
+    for lhs, op, rhs in conj.atoms:
+        mask &= evaluate(ColCmp(lhs, op, rhs), cols)
     return mask

@@ -506,6 +506,8 @@ class ReusePlane:
             "state_id": st.state_id,
             "key_attrs": st.key_attrs,
             "payload": st.payload,
+            "semi": st.semi,
+            "max_entries": st.max_entries,
             "did_domain": st.did_domain,
             "extents": extents,  # (conj | None, complete) in eid order
             "extent_parts": {
@@ -560,16 +562,13 @@ class ReusePlane:
             full, granted = hit
             return demand if full else min(granted, demand)
         retained = frozenset(art.meta["payload"]) | frozenset(art.meta["key_attrs"])
-        b_ret = Conjunction({a: c for a, c in b_q.constraints.items() if a in retained})
-        b_nonret = Conjunction(
-            {a: c for a, c in b_q.constraints.items() if a not in retained}
-        )
+        b_ret, b_nonret = b_q.split(retained)
         completed = [
             (eid, conj)
             for eid, (conj, done) in enumerate(art.meta["extents"])
             if done and conj is not None
         ]
-        if not b_nonret.constraints:
+        if b_nonret.is_true():
             allowed = ALL_EXTENTS
         else:
             allowed = np.uint64(0)
@@ -624,7 +623,7 @@ class ReusePlane:
         """Rows an isolated execution of ``plan`` would process that an
         aggregate-identity cache hit eliminates: the aggregate's input
         cardinality plus every boundary's build demand."""
-        from .grafting import all_boundaries, estimate_demand
+        from .grafting import all_boundaries, boundary_demand, estimate_demand
 
         saved = 0
         # the full-plan input count is only estimable when probe keys live
@@ -636,7 +635,7 @@ class ReusePlane:
             pass
         for b in all_boundaries(plan):
             try:
-                saved += estimate_demand(engine, b.build)
+                saved += boundary_demand(engine, b)
             except (TypeError, KeyError):
                 pass
         return saved
@@ -666,6 +665,8 @@ class ReusePlane:
             did_domain=meta["did_domain"],
             counters=counters,
             n_partitions=n_partitions,
+            semi=meta["semi"],
+            max_entries=meta["max_entries"],
         )
         arrays = art.arrays
         n = meta["n_entries"]
@@ -821,7 +822,7 @@ def reuse_potential(engine, query) -> float:
     reuse = getattr(engine, "reuse", None)
     if reuse is None:
         return 0.0
-    from .grafting import all_boundaries, estimate_demand, plan_spine
+    from .grafting import all_boundaries, boundary_demand, plan_spine
 
     mode = engine.mode
     _, _, agg, _ = plan_spine(query.plan)
@@ -834,7 +835,7 @@ def reuse_potential(engine, query) -> float:
         return 0.0
     total = cached = 0
     for j in all_boundaries(query.plan):
-        d = estimate_demand(engine, j.build)
+        d = boundary_demand(engine, j)
         total += d
         sig = hash_build_signature(j)
         if engine.state_index.get(sig):

@@ -206,7 +206,9 @@ def _chain_grant_bounds(conj: Conjunction):
     sets compile only at size one. Returns the constrained-attr tuple
     ``((attr, lo, hi), ...)`` or None when the conjunction is not
     interval-compilable in-kernel (the chain then declines with reason
-    ``grants``)."""
+    ``grants``): column-comparison atoms are not."""
+    if conj.atoms:
+        return None
     bounds = []
     for attr, c in conj.constraints.items():
         lo, hi = -math.inf, math.inf
@@ -247,7 +249,7 @@ def _bounds_of_conj(conj: Optional[Conjunction]):
     with values beyond 2^53 would lose the int-exact comparison the
     per-predicate ``evaluate`` path performs; such domains must not fuse.
     """
-    if conj is None:
+    if conj is None or conj.atoms:
         return None
     bounds: Dict[str, Tuple[float, float]] = {}
     for attr, c in conj.constraints.items():
@@ -579,10 +581,16 @@ class Member:
 
 @dataclass
 class ProbeOp:
+    """One probe stage. A ``semi`` stage (``HashJoin(kind="semi")``) keeps
+    the probe rows whose key is visible to the member and gathers no
+    payload: its state holds each key once, so it runs as an inner probe
+    with an empty payload, and the row keeps its own derivation id."""
+
     state: SharedHashBuildState
     probe_attrs: Tuple[str, ...]
     payload: Tuple[str, ...]  # entry attrs (canonical names in the state)
     out_names: Tuple[str, ...] = ()  # names in the row stream (renames)
+    kind: str = "inner"
 
     def __post_init__(self):
         if not self.out_names:
@@ -696,6 +704,12 @@ class Pipeline:
             ci.release(m.mid)
             if not ci.maps:
                 del self._cohort_state[ck]
+
+    @property
+    def join_kind(self) -> str:
+        """``"semi"`` when a stage of the pipeline is a semi-join: the kind
+        a ``graftdb.join`` span over the whole fused chain carries."""
+        return "semi" if any(op.kind == "semi" for op in self.ops) else "inner"
 
     def active_members(self) -> List[Member]:
         return [m for m in self.members if m.active and not m.done]
@@ -1020,7 +1034,7 @@ class Pipeline:
         )
         if cplan is not None and chain_launch is not None and len(did) > 0:
             launch = None
-            with span("graftdb.join"):
+            with span("graftdb.join", kind=self.join_kind):
                 if cplan["ok"]:
                     # one fused launch for the whole stage chain (§13); host
                     # keys validated backend-side over the full morsel — any
@@ -1038,7 +1052,7 @@ class Pipeline:
             if launch is not None:
                 if overlap and launch.pending:
                     yield launch
-                with span("graftdb.join"):
+                with span("graftdb.join", kind=self.join_kind):
                     res = launch.collect()
                     engine.counters["kernel_chain_launches"] += 1
                     cost, cols, bits, did, chain_sink = self._replay_chain(
@@ -1048,7 +1062,7 @@ class Pipeline:
         for stage, op in enumerate(self.ops):
             if served or len(did) == 0:
                 break
-            with span("graftdb.join"):
+            with span("graftdb.join", kind=op.kind):
                 keycodes = encode_keys(cols, op.probe_attrs)
                 vis_tables, grant_members, kernelable = plan["stages"][stage]
                 launch, kind = _stage_launch(
@@ -1056,7 +1070,7 @@ class Pipeline:
                 )
             if overlap and launch.pending:
                 yield launch
-            with span("graftdb.join"):
+            with span("graftdb.join", kind=op.kind):
                 lens_fused = kind == "lens"
                 words = None
                 if kind == "multi":
@@ -1091,7 +1105,7 @@ class Pipeline:
                 cols = {k: v[probe_idx] for k, v in cols.items()}
                 for a, out in zip(op.payload, op.out_names):
                     cols[out] = op.state.cols[a].data[entry_idx]
-                if self.compose_did:
+                if self.compose_did and op.kind != "semi":
                     did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
                 else:
                     did = did[probe_idx]
@@ -1226,7 +1240,8 @@ class Pipeline:
         did = did[keep]
         if self.compose_did:
             for s, op in enumerate(self.ops):
-                did = did * np.int64(op.state.did_domain) + op.state.did.data[entries[s]]
+                if op.kind != "semi":  # a semi stage passes its probe row on
+                    did = did * np.int64(op.state.did_domain) + op.state.did.data[entries[s]]
         sink = None
         if "vismask" in res:
             sink = (res["vismask"][keep], res["emask"][keep], res["slots"])
@@ -1404,7 +1419,7 @@ class Pipeline:
         for stage, op in enumerate(self.ops):
             if len(did) == 0:
                 break
-            with span("graftdb.join"):
+            with span("graftdb.join", kind=op.kind):
                 keycodes = encode_keys(cols, op.probe_attrs)
                 # single-member probes resolve the state lens in-kernel when the
                 # backend can serve it; the runtime then skips visible_mask
@@ -1444,7 +1459,7 @@ class Pipeline:
                 cols = {k: v[probe_idx] for k, v in cols.items()}
                 for a, out in zip(op.payload, op.out_names):
                     cols[out] = op.state.cols[a].data[entry_idx]
-                if self.compose_did:
+                if self.compose_did and op.kind != "semi":
                     did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
                 else:
                     did = did[probe_idx]
@@ -1544,7 +1559,7 @@ class Pipeline:
             mcols = {k: v[probe_idx] for k, v in mcols.items()}
             for a, out in zip(op.payload, op.out_names):
                 mcols[out] = op.state.cols[a].data[entry_idx]
-            if self.compose_did:
+            if self.compose_did and op.kind != "semi":
                 did = did[probe_idx] * np.int64(op.state.did_domain) + op.state.did.data[entry_idx]
             else:
                 did = did[probe_idx]

@@ -247,7 +247,18 @@ class SharedHashBuildState:
     "GraftDB stores one build entry and records the visibility needed by
     those queries"). Under ``n_partitions > 1`` the did and probe indexes
     shard by key hash (DESIGN.md §9) while entry storage stays one global
-    SoA with P-independent entry ids."""
+    SoA with P-independent entry ids.
+
+    A ``semi`` state (the build side of a semi-join) holds distinct keys:
+    a row's derivation id is its key, so insert-or-mark keeps one entry per
+    key and ORs into it the visibility and provenance of every row
+    incorporated for that key. A query's lens then sees a key iff some row
+    its build predicate accepts was incorporated for it, and every key has
+    one entry, so probes never meet a multi-match chain.
+
+    ``max_entries`` bounds the entries the state can reach, from the
+    database (the rows of its build source, or the source's distinct keys
+    for a semi state); the device backend sizes its mirrors from it."""
 
     def __init__(
         self,
@@ -258,9 +269,13 @@ class SharedHashBuildState:
         did_domain: int = 1 << 62,
         counters: Optional[Dict] = None,
         n_partitions: int = 1,
+        semi: bool = False,
+        max_entries: Optional[int] = None,
     ):
         self.state_id = state_id
         self.sig = sig
+        self.semi = bool(semi)
+        self.max_entries = max_entries
         self.key_attrs = tuple(key_attrs)
         self.payload = tuple(payload)
         self.retained_attrs = frozenset(self.payload) | frozenset(self.key_attrs)
@@ -516,8 +531,8 @@ class SharedHashBuildState:
         if len(dids) == 0:
             return 0, 0
         self._check_live()
-        dids = np.asarray(dids, dtype=np.int64)
         keycodes = np.asarray(keycodes, dtype=np.int64)
+        dids = keycodes if self.semi else np.asarray(dids, dtype=np.int64)
         n0 = self.did.n
         if self.n_partitions == 1:
             ids, is_new = self._did_index.lookup_or_insert(dids)

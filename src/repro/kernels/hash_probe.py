@@ -16,12 +16,12 @@ what the chip runs. Each runs under the ``graft_probe`` name scope.
 Unique-key tables only (FK-keyed dimension states); the engine routes
 multi-match states through the reference path.
 
-``hash_probe_lens_multi64`` is the multi-member variant (DESIGN.md §11/§13):
-one launch returns, per probe key, the matched slot AND the matched entry's
-packed visibility word — the per-row ownership mask of every probing member
-at once. The host translates the word from state-slot space into pipeline
-ownership bits (``core.visibility.translate_bits``), so per-morsel device
-cost is independent of how many queries share the probe.
+The engine probes through ``probe_slots`` inside the chain stage program
+(``kernels/fused_chain.py`` ``graft_chain_stage``, DESIGN.md §13), which
+serves the single-query lens, the multi-member probe (each matched
+entry's packed visibility word) and every stage of a fused chain;
+``hash_probe_lens`` is the plain single-query probe over slot-indexed
+words.
 
 ``hash_build_insert`` is the batch-insert companion, a Pallas kernel: one
 call builds the whole open-addressing table from a key batch (linear-probe
@@ -94,51 +94,6 @@ def hash_probe_lens(
         hit = slot >= 0
         vis = (table_vis[jnp.where(hit, slot, 0)] & query_mask[0]) != 0
         return jnp.where(hit & vis, slot, -1)
-
-
-@jax.jit
-def hash_probe_lens64(
-    probe_keys: jnp.ndarray,  # [N] int32
-    table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
-    table_entry: jnp.ndarray,  # [T] int32 slot -> entry index
-    evis_lo: jnp.ndarray,  # [E] uint32 entry-indexed visibility low words
-    evis_hi: jnp.ndarray,  # [E] uint32 entry-indexed visibility high words
-    query_mask: jnp.ndarray,  # [2] uint32 (lo, hi) lens mask
-) -> jnp.ndarray:
-    """Single-query fused-lens probe over the full 64-slot space
-    (DESIGN.md §13): visibility words are entry-indexed uint32 pairs, so
-    any slot 0..63 resolves on device and rebuilds leave the mirror
-    untouched. Returns the matched table slot per probe key (-1 = no
-    visible match)."""
-    with jax.named_scope("graft_probe"):
-        slot = probe_slots(probe_keys, table_keys)
-        hit = slot >= 0
-        entry = jnp.where(hit, table_entry[jnp.where(hit, slot, 0)], 0)
-        vis = (
-            (evis_lo[entry] & query_mask[0]) | (evis_hi[entry] & query_mask[1])
-        ) != 0
-        return jnp.where(hit & vis, slot, -1)
-
-
-@jax.jit
-def hash_probe_lens_multi64(
-    probe_keys: jnp.ndarray,  # [N] int32
-    table_keys: jnp.ndarray,  # [T] int32, power-of-two T, EMPTY sentinel
-    table_entry: jnp.ndarray,  # [T] int32 slot -> entry index
-    evis_lo: jnp.ndarray,  # [E] uint32 entry-indexed visibility low words
-    evis_hi: jnp.ndarray,  # [E] uint32 entry-indexed visibility high words
-):
-    """Multi-member probe returning the full uint64 lens word as (lo, hi)
-    uint32 halves (DESIGN.md §13), served for all 64 slots from
-    entry-indexed (rebuild-invariant) mirrors. The pair stream is
-    pre-visibility and identical to ``probe``."""
-    with jax.named_scope("graft_probe"):
-        found = probe_slots(probe_keys, table_keys)
-        matched = found >= 0
-        entry = jnp.where(matched, table_entry[jnp.where(matched, found, 0)], 0)
-        wlo = jnp.where(matched, evis_lo[entry], jnp.uint32(0))
-        whi = jnp.where(matched, evis_hi[entry], jnp.uint32(0))
-        return found, wlo, whi
 
 
 def _insert_kernel(keys_ref, tkeys_ref, tentry_ref, ok_ref):

@@ -43,28 +43,28 @@ def _mesh_label(mesh) -> str:
 
 
 def _chain_parity(mesh, rows: int) -> Dict:
-    """Run one minimal fused stage chain both unsharded and shard-locally
-    inside shard_map on ``mesh``, and compare every output bit-for-bit
-    (stats/slot counts are psum'd global; row outputs gather in shard
-    order, which is row order for row-partitioned inputs)."""
+    """Run one fused chain stage (``graft_chain_stage``) both unsharded and
+    shard-locally inside shard_map on ``mesh``, and compare every output
+    bit-for-bit (stats are psum'd global; row outputs gather in shard
+    order, which is row order for row-partitioned inputs). The stage
+    translates its lens through identity tables, filters on a row operand
+    and exports an entry column, so every output block carries data."""
     import numpy as np
 
-    from ..kernels.fused_chain import chain_launch
-    from ..kernels.hash_probe import EMPTY
+    from ..kernels import fused_chain as ch
+    from ..kernels.hash_probe import EMPTY, MULT
 
     d = int(mesh.shape["data"])
     rows = max(rows, d)
     rows = (rows // d) * d
     cap = 64
-    ecap = 64
+    ecap = 32
     rng = np.random.default_rng(7)
-    n_entries = 40
+    n_entries = 24
     # open-addressed table: entry keys 1..n_entries at their probe slots
     keys_host = np.arange(1, n_entries + 1, dtype=np.int32)
     tkeys = np.full(cap, EMPTY, np.int32)
     tentry = np.zeros(cap, np.int32)
-    from ..kernels.hash_probe import MULT
-
     for e, k in enumerate(keys_host):
         pos = (int(k) * MULT) & (cap - 1)
         while tkeys[pos] != EMPTY:
@@ -79,13 +79,35 @@ def _chain_parity(mesh, rows: int) -> Dict:
     for b in range(4):
         ttlo[b] = np.arange(256, dtype=np.uint32) << np.uint32(8 * b)
         tthi[4 + b] = np.arange(256, dtype=np.uint32) << np.uint32(8 * b)
-    probe_keys = rng.integers(1, 2 * n_entries, rows).astype(np.int32)
-    bits_lo = np.ones(rows, np.uint32)
-    bits_hi = np.zeros(rows, np.uint32)
-    spec = (((-1, 0, 0, None),), False)
-    arrays = (bits_lo, bits_hi, probe_keys, tkeys, tentry, evlo, evhi, ttlo, tthi)
-    ref = chain_launch(spec, arrays)
-    shd = chain_launch(spec, arrays, mesh=mesh)
+    g, m, a = ch.G_MAX, ch.M_MAX, ch.A_MAX
+    flags = np.zeros(ch.N_FLAGS, np.int32)
+    flags[1] = 1  # the filter
+    flags[ch.F_EXPORT] = 1  # export the entry keys
+    flags[ch.F_FOWN:ch.F_EPOST] = -1  # filter operands come in as rows
+    # member 0 (slot 0) keeps rows whose operand 0 lies in [0, 2**31]
+    flo = np.zeros((m, a, 2), np.uint32)
+    fhi = np.zeros((m, a, 2), np.uint32)
+    fhi[0, 0] = (1 << 31, 0)
+    fcon = np.zeros((m, a), np.int32)
+    fcon[0, 0] = 1
+    fbit = np.zeros((m, 2), np.uint32)
+    fbit[0, 0] = 1
+    row0 = np.zeros(rows, np.uint32)
+    fvals = ((rng.integers(0, 1 << 32, rows, dtype=np.uint32), row0),) + ((row0, row0),) * (a - 1)
+    entry_keys = np.zeros(ecap, np.uint32)
+    entry_keys[:n_entries] = keys_host.view(np.uint32)
+    args = (
+        rng.integers(0, 1 << 32, rows, dtype=np.uint32),  # bits_lo
+        np.zeros(rows, np.uint32),  # bits_hi
+        rng.integers(1, 2 * n_entries, rows).astype(np.int32).view(np.uint32),
+        tkeys, tentry, evlo, evhi, ttlo, tthi, flags,
+        evlo, evhi,  # provenance words
+        np.zeros((g, 2), np.uint32), np.zeros((g, 2), np.uint32), np.zeros((g, a), np.int32),
+        np.zeros((g, a, 2), np.uint32), np.zeros((g, a, 2), np.uint32), ((evlo, evhi),) * a,
+        fvals, flo, fhi, fcon, fbit, (entry_keys,) * ch.X_MAX,
+    )
+    ref = ch.graft_chain_stage(*args)
+    shd = ch.graft_chain_stage_sharded(mesh)(*args)
     ok = all(
         np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(ref, shd)
     )
@@ -93,7 +115,7 @@ def _chain_parity(mesh, rows: int) -> Dict:
         "rows": int(rows),
         "data_shards": d,
         "parity": bool(ok),
-        "matched_rows": int(np.asarray(ref[-2])[0, 1]),
+        "matched_rows": int(np.asarray(ref[3])[1]),
     }
 
 

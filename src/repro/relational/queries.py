@@ -126,6 +126,26 @@ def q4_plan(db: Database, p: Dict) -> object:
     return OrderBy(agg, ("o_orderpriority",), (True,))
 
 
+def q4_semi_plan(db: Database, p: Dict) -> object:
+    """Q4 as clause 2.4.4 writes it: orders in ``[date, date + 3 months)``
+    that have a late line (``EXISTS`` lineitem with ``l_commitdate <
+    l_receiptdate``), counted by ``o_orderpriority``. The EXISTS is a
+    semi-join whose build side is parameter-free, so every concurrent Q4
+    can graft onto one lineitem key state. ``BUILDERS["q4"]`` keeps the
+    inner-join form with ``count(distinct)``."""
+    d0 = p["date"]
+    d1 = d0 + 92  # + 3 months
+    orders = Scan(
+        "orders",
+        And((Cmp("o_orderdate", ">=", d0), Cmp("o_orderdate", "<", d1))),
+        ("o_orderkey", "o_orderpriority"),
+    )
+    late = Scan("lineitem", ColCmp("l_commitdate", "<", "l_receiptdate"), ("l_orderkey",))
+    join = HashJoin(late, orders, ("l_orderkey",), ("o_orderkey",), (), kind="semi")
+    agg = Aggregate(join, ("o_orderpriority",), (AggSpec("count", name="order_count"),))
+    return OrderBy(agg, ("o_orderpriority",), (True,))
+
+
 def q5_plan(db: Database, p: Dict) -> object:
     region, d0 = p["region"], p["date"]
     d1 = d0 + 365

@@ -31,6 +31,12 @@ def _exec(db: Database, node: PlanNode) -> Dict[str, np.ndarray]:
         probe = _exec(db, node.probe)
         bkeys = _codes(build, node.build_keys)
         pkeys = _codes(probe, node.probe_keys)
+        if node.kind == "semi":
+            out = {k: v[np.isin(pkeys, bkeys)] for k, v in probe.items()}
+            if node.post_filter is not TRUE:
+                m = evaluate(node.post_filter, out)
+                out = {k: v[m] for k, v in out.items()}
+            return out
         order = np.argsort(bkeys, kind="stable")
         sb = bkeys[order]
         lo = np.searchsorted(sb, pkeys, "left")

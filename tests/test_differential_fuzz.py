@@ -130,6 +130,69 @@ def test_differential_fuzzer_parity(db):
 
 
 # ---------------------------------------------------------------------------
+# Semi-join differential leg
+# ---------------------------------------------------------------------------
+
+#: seeds of the semi-join leg
+SEMI_SEEDS = range(8)
+
+
+def _semi_query(db, rng, arrival):
+    """Q4 as its EXISTS semi-join, or a semi-join of a year of orders on the
+    lineitem rows in a random quantity band (the same key state as Q4's,
+    under another build predicate)."""
+    from repro.core.plans import AggSpec, Aggregate, HashJoin, OrderBy, Query, Scan
+    from repro.core.predicates import And, Cmp
+
+    if rng.random() < 0.5:
+        p = queries._sample_params("q4", rng)
+        return Query(0, "q4", queries.q4_semi_plan(db, p), p, arrival)
+    lo = float(rng.integers(1, 40))
+    p = {"lo": lo, "hi": lo + float(rng.integers(1, 20)), "date": float(rng.integers(0, 6)) * 365}
+    lines = Scan("lineitem", And((Cmp("l_quantity", ">=", p["lo"]),
+                                  Cmp("l_quantity", "<", p["hi"]))), ("l_orderkey",))
+    orders = Scan("orders", And((Cmp("o_orderdate", ">=", p["date"]),
+                                 Cmp("o_orderdate", "<", p["date"] + 365))),
+                  ("o_orderkey", "o_orderpriority"))
+    j = HashJoin(lines, orders, ("l_orderkey",), ("o_orderkey",), (), kind="semi")
+    agg = Aggregate(j, ("o_orderpriority",), (AggSpec("count", name="n"),))
+    return Query(0, "semi", OrderBy(agg, ("o_orderpriority",), (True,)), p, arrival)
+
+
+def test_semi_join_differential_leg(db):
+    """Semi-joins among the other templates, racing and spread, in graft
+    mode (with eviction and admission, partitioned, on the device path) and
+    in isolated mode, all vs the reference executor."""
+    from dataclasses import replace
+
+    checks = attaches = 0
+    for seed in SEMI_SEEDS:
+        rng = np.random.default_rng(20_000 + seed)
+        qs, t = [], 0.0
+        for _ in range(int(rng.integers(3, 7))):
+            t += float(rng.choice([0.0, 0.002, 0.02]))
+            qs.append(_semi_query(db, rng, t) if rng.random() < 0.6
+                      else queries.sample_query(db, rng, arrival=t))
+        refs = [refexec.execute(db, q.plan) for q in qs]
+        runs = (
+            ("graft-w1", dict(EVICT, workers=1, partitions=1)),
+            ("graft-w4", dict(EVICT, workers=4, partitions=4)),
+            ("graft-pallas", dict(mode="graft", backend="pallas", morsel_size=4096)),
+            ("isolated", dict(mode="isolated", morsel_size=4096)),
+        )
+        for label, cfg in runs:
+            fresh = [replace(q, qid=i + 1) for i, q in enumerate(qs)]
+            session, futs = _run_all(db, fresh, **cfg)
+            for i, (f, ref) in enumerate(zip(futs, refs)):
+                _assert_parity(f.result(), ref, ctx=f"seed{seed}/{label}/q{i}")
+                checks += 1
+            attaches += session.stats()["semi_attaches"]
+            session.close()
+    assert checks >= 100
+    assert attaches > 0, "no query ever grafted onto a semi state"
+
+
+# ---------------------------------------------------------------------------
 # Batch-planning differential leg (§15)
 # ---------------------------------------------------------------------------
 

@@ -112,6 +112,32 @@ def test_total_order_bound_scalar_matches_array():
         assert total_order_bound(v) == (int(hi[0]), int(lo[0]))
 
 
+@pytest.mark.parametrize("seed", [0, 11])
+def test_device_translation_equals_the_host_byte_tables(seed):
+    """The chain's word translation (one select per slot bit, reading each
+    slot's target off the split byte tables) ORs exactly what
+    ``translate_bits`` does, for any targets and any words."""
+    from repro.core.visibility import join_words, split_words, translate_bits, translation_table
+    from repro.kernels.fused_chain import _translate
+
+    rng = np.random.default_rng(seed)
+    top = np.iinfo(np.uint64).max
+    target = rng.integers(0, top, 64, dtype=np.uint64, endpoint=True)
+    target[rng.random(64) < 0.3] = 0
+    words = rng.integers(0, top, 4096, dtype=np.uint64, endpoint=True)
+    words &= rng.integers(0, top, 4096, dtype=np.uint64, endpoint=True)  # sparser bits
+    words[:64] = np.uint64(1) << np.arange(64, dtype=np.uint64)
+    words[64] = 0
+    tables = translation_table(target)
+    tlo, thi = split_words(tables.ravel())
+    wlo, whi = split_words(words)
+    olo, ohi = _translate(jax.numpy.asarray(wlo), jax.numpy.asarray(whi),
+                          jax.numpy.asarray(tlo.reshape(8, 256)),
+                          jax.numpy.asarray(thi.reshape(8, 256)))
+    got = join_words(np.asarray(olo), np.asarray(ohi))
+    np.testing.assert_array_equal(got, translate_bits(words, tables))
+
+
 # ---------------------------------------------------------------------------
 # Chain-served sessions: bit-exact against oracle + reference, all modes
 # ---------------------------------------------------------------------------
@@ -288,22 +314,23 @@ def _mini_state(n0=64):
 
 
 def test_mirror_appends_and_marks_patch_incrementally():
-    """Growing the state (which rebuilds the probe table) and marking
-    existing entries must NOT regather the lens mirror: appends and mark-log
-    entries patch in place (``mirror_patched_rows``), and rebuilds leave the
-    entry-indexed mirror untouched (``mirror_full_regathers == 0``)."""
+    """Growing the state (within the entry capacity its ``max_entries``
+    bound sizes) and marking existing entries must NOT regather the lens
+    mirror: appends and mark-log entries patch in place
+    (``mirror_patched_rows``), and so do the probe table's new slots
+    (``mirror_full_regathers == 0``)."""
     from repro.api.backends import PallasBackend
 
     s, keys = _mini_state(64)
+    s.max_entries = 256
     slot = s.slots.get(7)
     backend = PallasBackend(interpret=True)
     first = backend.probe_visible(s, keys, 7)
     assert first is not None and len(first[0]) == 0  # nothing marked for q7
     assert backend.mirror_full_regathers == 0
 
-    # append enough to force a probe-table rebuild (64 -> 200 keys doubles
-    # the 128-slot table) while staying inside the mirror's entry capacity,
-    # and mark a few entries visible to q7's slot — both must patch
+    # append 136 keys (64 -> 200) inside the mirror's entry capacity, and
+    # mark a few entries visible to q7's slot — both must patch
     new = np.arange(64, 200, dtype=np.int64)
     s.insert_or_mark(
         new, new, {"k": new.astype(float), "x": new.astype(float)},

@@ -207,7 +207,7 @@ def test_db_plane_validator_rejects_broken_records():
 
 
 def test_sharded_chain_launch_parity_on_smoke_mesh():
-    """chain_launch(mesh=...) wraps the identical kernel in shard_map;
+    """graft_chain_stage_sharded wraps the identical stage in shard_map;
     on the smoke mesh every output is bit-identical to the plain launch."""
     from repro.launch.db_plane import _chain_parity
 

@@ -54,9 +54,13 @@ def test_inset_containment():
 
 
 def test_outside_fragment_unproven():
-    p = ColCmp("a", "<", "b")  # cross-column: outside the fragment
+    p = ColCmp("a", "<", "b")  # cross-column: an opaque atom, not an interval
     assert not prove_implies(p, Cmp("a", "<", 5))
-    assert Conjunction.from_pred(p) is None
+    assert not prove_implies(Cmp("a", "<", 5), p)
+    assert Conjunction.from_pred(p).atoms == {("a", "<", "b")}
+    # only the identical comparison (sides swapped or not) proves it
+    assert prove_implies(And((p, Cmp("a", "<", 5))), ColCmp("b", ">", "a"))
+    assert not prove_implies(p, ColCmp("a", "<=", "b"))
 
 
 def test_coverage_interval_merge():

@@ -1,8 +1,9 @@
 """Compile rehearsal: the main path's device programs compile for a TPU v5e.
 
-Each test lowers and compiles one program of the data plane — the probes
-and the fused stage chain — for one chip of a described (not attached)
-``v5e:2x2`` topology, at TPC-H SF-1 sizes: a 65,536-row morsel, a
+Each test lowers and compiles programs of the data plane — the chain
+stage that serves every probe, the build chain's sink, and the stage
+shard-locally over a data mesh — for the chips of a described (not
+attached) ``v5e:2x2`` topology, at TPC-H SF-1 sizes: a 65,536-row morsel, a
 4,194,304-slot probe table (the orders state's ~1.5M keys at the 50% load
 factor) and 2,097,152-entry mirrors. Nothing runs; the chip's compiler
 refuses here what it would refuse on the chip (an unsupported gather, a
@@ -13,13 +14,13 @@ one process may load the TPU library, and every test worker imports every
 test file.
 """
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fused_chain import _chain_fn
-from repro.kernels.hash_probe import hash_probe_lens64, hash_probe_lens_multi64
+from repro.kernels import fused_chain as ch
 
 ROWS = 65536
 SLOTS = 1 << 22
@@ -28,10 +29,10 @@ HBM_BYTES = 16 * 10**9  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The described chips of a ``v5e:2x2`` host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     cache_on = jax.config.jax_enable_compilation_cache
     # a compile for a described chip is written to the persistent cache but
@@ -47,8 +48,15 @@ def one_chip():
         except Exception as e:  # no TPU compiler in this installation
             jax.config.update("jax_enable_compilation_cache", cache_on)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo.devices
     jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -80,52 +88,69 @@ def _probe_args(sh):
     ]
 
 
-def _chain_args(spec, sh):
-    """Shapes of ``input_kinds(spec)``'s traversal at the SF-1 sizes."""
+def _stage_args(sh):
+    """``graft_chain_stage``'s inputs at the SF-1 sizes, with its grant,
+    filter and export blocks."""
     i32, u32 = jnp.int32, jnp.uint32
-    stages, sink = spec
-    args = [_sds((ROWS,), u32, sh), _sds((ROWS,), u32, sh)]
-    for key_mode, n_grants, grant_attrs, filt in stages:
-        args.append(_sds((ROWS if key_mode == -1 else ENTRIES,), i32, sh))
-        args += [_sds((SLOTS,), i32, sh), _sds((SLOTS,), i32, sh)]
-        args += [_sds((ENTRIES,), u32, sh), _sds((ENTRIES,), u32, sh)]
-        args += [_sds((8, 256), u32, sh), _sds((8, 256), u32, sh)]
-        if n_grants:
-            g, a = n_grants, grant_attrs
-            args += [_sds((ENTRIES,), u32, sh), _sds((ENTRIES,), u32, sh)]
-            args += [_sds((g, 2), u32, sh), _sds((g, 2), u32, sh)]
-            args += [_sds((g, a), i32, sh)]
-            args += [_sds((g, a, 2), u32, sh), _sds((g, a, 2), u32, sh)]
-            args += [_sds((ENTRIES,), u32, sh)] * (2 * a)
-        if filt is not None:
-            m, srcs = filt
-            for src in srcs:
-                args += [_sds((ROWS if src == -1 else ENTRIES,), u32, sh)] * 2
-            a = len(srcs)
-            args += [_sds((m, a, 2), u32, sh), _sds((m, a, 2), u32, sh)]
-            args += [_sds((m, a), i32, sh), _sds((m, 2), u32, sh)]
-    if sink:
-        args += [_sds((8, 256), u32, sh)] * 4
-    return args
+    keys, tkeys, tentry, evlo, evhi = _probe_args(sh)
+    row = _sds((ROWS,), u32, sh)
+    tt = _sds((8, 256), u32, sh)
+    g, m, a = ch.G_MAX, ch.M_MAX, ch.A_MAX
+    return [row, row, _sds((ROWS,), u32, sh), tkeys, tentry, evlo, evhi, tt, tt,
+            _sds((ch.N_FLAGS,), i32, sh), evlo, evhi,
+            _sds((g, 2), u32, sh), _sds((g, 2), u32, sh), _sds((g, a), i32, sh),
+            _sds((g, a, 2), u32, sh), _sds((g, a, 2), u32, sh), ((evlo, evhi),) * a,
+            ((row, row),) * a, _sds((m, a, 2), u32, sh), _sds((m, a, 2), u32, sh),
+            _sds((m, a), i32, sh), _sds((m, 2), u32, sh), (evlo,) * ch.X_MAX]
 
 
 def test_single_lens_probe_compiles(one_chip):
-    args = _probe_args(one_chip) + [_sds((2,), jnp.uint32, one_chip)]
-    _compile(hash_probe_lens64, args)
+    # a single-query lens probe is a one-stage launch of the chain stage
+    _compile(ch.graft_chain_stage, _stage_args(one_chip))
 
 
 def test_multi_member_probe_compiles(one_chip):
-    _compile(hash_probe_lens_multi64, _probe_args(one_chip))
+    # the multi-member probe is the same stage program; a build chain ends
+    # in the sink program, and mirrors are made and patched on the device
+    row, tt = _sds((ROWS,), jnp.uint32, one_chip), _sds((8, 256), jnp.uint32, one_chip)
+    _compile(ch.graft_chain_sink, [row, row, tt, tt, tt, tt])
+    _compile(ch.graft_fill(ENTRIES, "uint32"), [_sds((), jnp.uint32, one_chip)])
 
 
-def test_one_stage_chain_with_sink_compiles(one_chip):
-    spec = (((-1, 0, 0, None),), True)
-    _compile(_chain_fn(spec), _chain_args(spec, one_chip))
+def _sharded_stage_args(mesh):
+    """``_stage_args`` placed for the shard-local stage: rows split over
+    the mesh's data axis, state and parameters replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    row, rep = NamedSharding(mesh, PartitionSpec("data")), NamedSharding(mesh, PartitionSpec())
+    args = _stage_args(rep)
+
+    def place(tree, sharding):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sharding), tree)
+
+    for i in (0, 1, 2, 18):  # bl, bh, keys, fvals
+        args[i] = place(args[i], row)
+    return args
 
 
-def test_two_stage_chain_with_grants_and_filter_compiles(one_chip):
-    # stage 1 takes its keys from stage 0's matched entries, resolves two
-    # compiled grants over one attr, and filters three members on a
-    # row-sourced and an entry-sourced attr
-    spec = (((-1, 0, 0, None), (0, 2, 1, (3, (-1, 0)))), False)
-    _compile(_chain_fn(spec), _chain_args(spec, one_chip))
+def test_one_stage_chain_with_sink_compiles(v5e_2x2, one_chip):
+    # the mesh check's program on a one-chip data mesh, and the sink that
+    # ends a build chain
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(v5e_2x2[:1]), ("data",))
+    _compile(ch.graft_chain_stage_sharded(mesh), _sharded_stage_args(mesh))
+    row = _sds((ROWS,), jnp.uint32, one_chip)
+    tt = _sds((8, 256), jnp.uint32, one_chip)
+    _compile(ch.graft_chain_sink, [row, row, tt, tt, tt, tt])
+
+
+def test_two_stage_chain_with_grants_and_filter_compiles(v5e_2x2):
+    # the stage, with its grant and filter blocks, shard-locally over the
+    # four chips of the host: the rows split four ways, the stats psum'd
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(v5e_2x2), ("data",))
+    assert mesh.shape["data"] == 4
+    compiled = _compile(ch.graft_chain_stage_sharded(mesh), _sharded_stage_args(mesh))
+    assert "all-reduce" in compiled.as_text()

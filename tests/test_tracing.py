@@ -172,8 +172,9 @@ def test_admit_spans_carry_their_query_ids(traced):
 
 def test_transfer_counters_count_one_probe_by_hand():
     """A second ``probe_visible`` of 100 keys against an unchanged state
-    ships 128 padded int32 keys and the two-word uint32 lens mask, and reads
-    back 128 int32 slots; nothing of the state moves again."""
+    ships 128 padded uint32 keys (the all-ones ownership words of 128 rows
+    stay on the device from the first) and reads back 128 int32 entries;
+    nothing of the state moves again."""
     from repro.api.backends import PallasBackend
 
     s, keys = _mini_state()
@@ -184,7 +185,7 @@ def test_transfer_counters_count_one_probe_by_hand():
     after = backend.stats()
     assert len(pair[0]) == 100
     delta = {k: after[k] - before[k] for k in after}
-    assert delta["h2d_bytes"] == 128 * 4 + 2 * 4
+    assert delta["h2d_bytes"] == 128 * 4
     assert delta["d2h_bytes"] == 128 * 4
     assert (delta["device_rows"], delta["device_padded_rows"]) == (100, 128)
 
@@ -253,3 +254,31 @@ def test_work_clock_busy_time_stays_modelled(db):
     w = session.worker_stats()
     # one worker, nothing idle: modelled busy time is the whole makespan
     assert w["busy_s"][0] == pytest.approx(w["makespan_s"])
+
+
+def test_precompile_and_semi_join_spans_nest(db, tmp_path):
+    """A session opened under the profiler records its ladder compile as
+    ``graftdb.precompile``, and a semi-join stage's work as ``graftdb.join``
+    with ``kind="semi"``; every span nests on its thread."""
+    import jax
+
+    sys.path.insert(0, str(ROOT))
+    from bench.harness import program_spans, trace
+
+    rng = np.random.default_rng(9)
+    with jax.profiler.trace(str(tmp_path)):
+        session = graftdb.connect(db, EngineConfig(mode="graft", backend="pallas"))
+        qs = []
+        for t in ("q4", "q3", "q4"):
+            p = queries._sample_params(t, rng)
+            plan = queries.q4_semi_plan(db, p) if t == "q4" else queries.BUILDERS[t](db, p)
+            qs.append(queries.Query(qid=len(qs) + 1, template=t, plan=plan, params=p))
+        futs = session.submit_all(qs)
+        session.run()
+    assert all(f.status == "done" for f in futs)
+    t = program_spans.load(trace.latest_xplane(str(tmp_path)))
+    names = {n for spans in t["threads"].values() for _, _, n in spans}
+    assert "graftdb.precompile" in names
+    kinds = [m.get("kind") for (_, _, name), m in t["meta"].items() if name == "graftdb.join"]
+    assert "semi" in kinds and "inner" in kinds
+    assert _nested_spans(t) > 10
