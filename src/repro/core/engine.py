@@ -215,6 +215,10 @@ class GraftEngine:
             # probe and chain launches issued while another unit's launch
             # was outstanding (§11 in-flight units, wall clock only)
             "overlapped_launches",
+            # probe and chain launches issued while an earlier pipeline of
+            # the same unit held an uncollected launch (§11 launch-ahead);
+            # disjoint from overlapped_launches
+            "ahead_launches",
             "fallback_probes_grants",
             "fallback_probes_slot_limit",
             "fallback_probes_keyrange",

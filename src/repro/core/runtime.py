@@ -133,13 +133,16 @@ class Launch:
     the call's span. A call the device did not serve (an empty probe, a
     reference fallback) is a Launch with its result already in hand."""
 
-    __slots__ = ("_result", "_outs", "_finish", "_name")
+    __slots__ = ("_result", "_outs", "_finish", "_name", "ahead")
 
     def __init__(self, result=None, outs=None, finish=None, name: str = ""):
         self._result = result
         self._outs = outs
         self._finish = finish
         self._name = name
+        # issued while an earlier pipeline of its unit held an uncollected
+        # launch (``ScanNode.steps``, counted in ``ahead_launches``)
+        self.ahead = False
 
     @property
     def pending(self) -> bool:
@@ -723,6 +726,18 @@ class Pipeline:
 
     def all_done(self) -> bool:
         return all(m.done for m in self.members)
+
+    def states(self, part: int) -> Tuple[frozenset, frozenset]:
+        """Ids of the states one morsel of scan partition ``part`` reads
+        (the probe ops' states) and writes (the build target, the active
+        members' aggregate sinks); both empty when no member is owed it."""
+        act = self.active_members_for(part)
+        if not act:
+            return frozenset(), frozenset()
+        writes = {id(m.sink.agg_state) for m in act if m.sink is not None}
+        if self.build_target is not None:
+            writes.add(id(self.build_target.state))
+        return frozenset(id(op.state) for op in self.ops), frozenset(writes)
 
     # -- execution ----------------------------------------------------------
     def _source_bits(self, act: List[Member], cols, n: int, engine) -> np.ndarray:
@@ -1595,6 +1610,54 @@ class Pipeline:
         return cost
 
 
+def _launch_ahead(engine, pipelines: List[Pipeline], cols, row_ids: np.ndarray, part: int):
+    """One morsel through ``pipelines`` with each one's first device launch
+    issued before the earlier ones are collected (DESIGN.md §11). A
+    generator returning the pipelines' costs in their order.
+
+    Each pipeline starts in turn and runs to its first pending launch,
+    where it is held, or to its end. One that reads a state a held
+    pipeline writes, or writes a state a held pipeline reads or writes,
+    waits until the held ones are collected. Held pipelines resume in their
+    order, each pending launch yielded before its collect, so every state
+    takes its writes in the serial order."""
+    costs = [0.0] * len(pipelines)
+    held: List[tuple] = []  # (position, steps, launch), in pipeline order
+    reads, writes = set(), set()  # states of the held pipelines
+
+    def collect_held():
+        for i, steps, launch in held:
+            while True:
+                yield launch
+                try:
+                    launch = next(steps)
+                except StopIteration as stop:
+                    costs[i] = stop.value
+                    break
+        held.clear()
+        reads.clear()
+        writes.clear()
+
+    for i, p in enumerate(pipelines):
+        r, w = p.states(part)
+        if held and (r & writes or w & (reads | writes)):
+            yield from collect_held()
+        steps = p.steps(engine, cols, row_ids, part, overlap=True)
+        try:
+            launch = next(steps)
+        except StopIteration as stop:
+            costs[i] = stop.value
+            continue
+        if held:
+            launch.ahead = True
+            engine.counters["ahead_launches"] += 1
+        held.append((i, steps, launch))
+        reads |= r
+        writes |= w
+    yield from collect_held()
+    return costs
+
+
 # ---------------------------------------------------------------------------
 # Scan node (§4.4 shared cyclic scans)
 # ---------------------------------------------------------------------------
@@ -1695,9 +1758,10 @@ class ScanNode:
 
     def steps(self, engine, part: int = 0, overlap: bool = False):
         """``advance`` as a generator returning the cost; with ``overlap``
-        it passes on each pending launch its pipelines yield
-        (``Pipeline.steps``). The morsel accounting and the cursor bump run
-        when it ends, as in ``advance``."""
+        it issues its pipelines' first launches ahead of their collects
+        (``_launch_ahead``) and passes on each pending launch before it is
+        collected. The morsel accounting and the cursor bump run when it
+        ends, as in ``advance``."""
         idx = self.cursors[part]
         with span("graftdb.scan"):
             skip = self.zone_maps and not self._wave_possible()[idx]
@@ -1731,8 +1795,13 @@ class ScanNode:
         engine.counters["scan_bytes"] += n * self.row_bytes
         cost = engine.cost_model["scan"] * n
 
-        for p in list(self.pipelines):
-            cost += yield from p.steps(engine, cols, row_ids, part, overlap)
+        pipelines = list(self.pipelines)
+        if overlap:
+            costs = yield from _launch_ahead(engine, pipelines, cols, row_ids, part)
+        else:
+            costs = [run_steps(p.steps(engine, cols, row_ids, part)) for p in pipelines]
+        for c in costs:  # in pipeline order, as the serial schedule sums
+            cost += c
         self._bump_cursor(part)
         return cost
 

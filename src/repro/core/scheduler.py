@@ -200,13 +200,9 @@ def unit_states(node: ScanNode, part: int) -> Tuple[frozenset, frozenset]:
     are written."""
     reads, writes = set(), set()
     for p in node.pipelines:
-        act = p.active_members_for(part)
-        if not act:
-            continue
-        reads.update(id(op.state) for op in p.ops)
-        if p.build_target is not None:
-            writes.add(id(p.build_target.state))
-        writes.update(id(m.sink.agg_state) for m in act if m.sink is not None)
+        r, w = p.states(part)
+        reads |= r
+        writes |= w
     return frozenset(reads), frozenset(writes)
 
 
@@ -878,7 +874,7 @@ class Runner:
                 ended = True
         self.busy_s[0] += time.perf_counter() - t0
         if not ended:
-            if self._inflight:
+            if self._inflight and not unit.launch.ahead:
                 engine.counters["overlapped_launches"] += 1
             self._inflight.append(unit)
             return
