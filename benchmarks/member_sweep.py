@@ -38,6 +38,7 @@ import numpy as np
 
 import graftdb
 from graftdb import EngineConfig
+from repro.api.backends import ReferenceBackend
 from repro.core.descriptors import StateSignature
 from repro.core.engine import DEFAULT_COST_MODEL
 from repro.core.plans import AggSpec, BinOp, Col
@@ -64,8 +65,9 @@ class _BenchEngine:
     def __init__(self, member_major: bool):
         self.cost_model = dict(DEFAULT_COST_MODEL)
         self.counters = defaultdict(float)
-        self.backend = None
+        self.backend = ReferenceBackend()
         self.member_major = member_major
+        self.mesh_plan = None
 
     def on_member_part_finished(self, pipeline, m, part):
         pass

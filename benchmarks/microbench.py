@@ -295,7 +295,7 @@ def bench_fused_chain(size: int, rng) -> Dict:
     only the data plane changes. The legs alternate morsel-by-morsel and
     the reported times are per-rep medians, as in member_sweep, so shared
     -host CPU weather hits both sides alike."""
-    from repro.api.backends import PallasBackend
+    from repro.api.backends import PallasBackend, ReferenceBackend
 
     from .member_sweep import _build_micro
 
@@ -305,7 +305,7 @@ def bench_fused_chain(size: int, rng) -> Dict:
     reps = 3
 
     legs = []
-    for member_major, backend in ((False, None), (True, PallasBackend())):
+    for member_major, backend in ((False, ReferenceBackend()), (True, PallasBackend())):
         engine, pipeline, cols = _build_micro(n_members, member_major, morsel, seed=7)
         engine.backend = backend
         row_ids = np.arange(morsel, dtype=np.int64)

@@ -1,21 +1,23 @@
-"""ExecutionBackend: pluggable data-plane kernels behind one Session.
+"""ExecutionBackend: the data plane behind one Session.
 
-The engine's two hot vectorized operations — hash-probe against a shared
-build state (§4.3) and segmented aggregation into shared accumulators
-(§4.5) — are routed through a per-session backend:
+The runtime's per-morsel device work — hash probes against shared build
+states (§4.3), a morsel's fused stage chain (§13) and segmented sums into
+shared accumulators (§4.5) — goes through one interface, which both
+backends implement in full:
 
-* ``ReferenceBackend`` — the NumPy row engine (incremental hash/dup-run
-  probe index in ``core.state``, ``np.bincount`` reductions). Always
-  available; the correctness oracle path (``relational/refexec.py``
-  semantics).
-* ``PallasBackend`` — the device data plane: the probes and the fused
-  stage chain are jitted XLA programs (``kernels/hash_probe.py``,
-  ``kernels/fused_chain.py``) that run unchanged on every platform; the
-  opt-in batch insert (``hash_build_insert``) and segmented aggregate
-  (``kernels/seg_aggregate.py``) are Pallas kernels, compiled on the TPU
-  and interpreted elsewhere. States that the device programs cannot serve
+* ``ReferenceBackend`` — the NumPy row engine: the state's own
+  incremental probe index (``core.state``) and ``np.bincount``
+  reductions. The default, and the correctness oracle's path
+  (``relational/refexec.py`` semantics). Its launches finish at once, and
+  it declines the lens, multi-member and chain launches.
+* ``PallasBackend`` — the device data plane. Every probe and every stage
+  of a chain is one launch of ``graft_chain_stage``, a build chain's sink
+  one of ``graft_chain_sink`` (``kernels/fused_chain.py``, both built on
+  ``kernels/hash_probe.probe_slots``); ``graft_fill`` and ``graft_patch``
+  make and patch the device mirrors. They are jitted XLA programs that run
+  unchanged on every platform. States the programs cannot serve
   (multi-match keys, out-of-range keycodes, over-long probe clusters) fall
-  back to the reference path per-call; per-reason fallback counters
+  back to the reference probe per call; per-reason fallback counters
   record why.
 
 The Pallas backend keeps a device-resident mirror of every served state's
@@ -34,65 +36,106 @@ from __future__ import annotations
 import functools
 import math
 import weakref
-from typing import Optional, Protocol, Tuple, runtime_checkable
+from typing import Optional, Protocol, runtime_checkable
 
 import numpy as np
 
-from ..core.runtime import Launch
+from ..core.runtime import Launch, encode_keys
 from ..core.state import SharedHashBuildState, _bincount_segment_sum
 from ..core.tracing import span, spanned
 from ..core.visibility import join_words, split_words, translation_table
 
 #: chain-level / probe-level decline reasons (DESIGN.md §13). ``grants``,
 #: ``predicate`` and ``slot_limit`` are chain-plan declines (the staged
-#: kernels may still serve the probes); ``keyrange`` and ``capacity`` are
+#: probes may still run on the device); ``keyrange`` and ``capacity`` are
 #: table-level declines that route the probe to the reference path.
 FALLBACK_REASONS = ("grants", "slot_limit", "keyrange", "capacity", "predicate")
 
 
 @runtime_checkable
 class ExecutionBackend(Protocol):
-    """Data-plane operations a Session's engine dispatches per morsel.
+    """Everything the runtime calls on a Session's data plane.
 
-    Backends may additionally provide ``probe_visible(state, keycodes,
-    qid)`` / ``probe_visible_multi(state, keycodes)`` /
-    ``probe_chain(cplan, cols, bits, host_keys)`` returning
-    visibility-resolved results (or None to decline), and each of these and
-    ``probe`` as a ``*_launch`` half returning a ``core.runtime.Launch``;
-    the runtime discovers the launch halves via getattr, so they are not
-    part of the required protocol surface. A backend that sets
-    ``probe_accepts_counters = True`` receives
-    the engine's counter dict as a ``counters=`` kwarg on ``probe`` so
-    per-reason fallback counters surface in ``QueryFuture.stats()``."""
+    Each probe is split at its device launch (DESIGN.md §11): a
+    ``*_launch`` call does the work up to the launch and returns a
+    ``core.runtime.Launch``, whose ``collect()`` waits for the outputs and
+    finishes the host work; a call served on the host returns a Launch
+    with its result in hand. The lens, multi-member and chain launches may
+    return None to decline: the runtime then probes pre-visibility
+    (``probe_launch``) or runs the staged loop."""
 
     name: str
+    #: whether launches run asynchronously on a device, so that the runner
+    #: may hold two units in flight (``Runner._overlaps``)
+    launches_on_device: bool
 
-    def probe(
+    def probe_launch(
+        self, state: SharedHashBuildState, keycodes: np.ndarray, counters=None
+    ) -> Launch:
+        """All (probe_row_idx, entry_idx) match pairs, pre-visibility.
+        ``counters`` is the engine's counter dict, where a backend counts
+        its fallbacks by reason."""
+        ...
+
+    def probe_visible_launch(
+        self, state: SharedHashBuildState, keycodes: np.ndarray, qid
+    ) -> Optional[Launch]:
+        """The pairs visible to query ``qid`` through its state lens."""
+        ...
+
+    def probe_visible_multi_launch(
         self, state: SharedHashBuildState, keycodes: np.ndarray
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """All (probe_row_idx, entry_idx) match pairs, pre-visibility."""
+    ) -> Optional[Launch]:
+        """``probe_launch``'s pairs and each matched entry's packed lens
+        word: ``(probe_idx, entry_idx, vis_words)``."""
+        ...
+
+    def probe_chain_launch(self, cplan, cols, bits, counters=None) -> Optional[Launch]:
+        """A morsel's whole stage chain (DESIGN.md §13); the backend
+        counts why it declines."""
         ...
 
     def segment_sum(
         self, gids: np.ndarray, values: Optional[np.ndarray], n_groups: int
     ) -> np.ndarray:
-        """Per-group sum of ``values`` (counts when values is None)."""
+        """Per-group float64 sum of ``values`` (counts when values is None)."""
+        ...
+
+    def precompile(self, db, morsel_size: int) -> int:
+        """Compile the programs a session on ``db`` launches, when it
+        opens; returns how many."""
+        ...
+
+    def stats(self) -> dict:
+        """Counters, surfaced as ``backend_*`` in ``Session.stats``."""
         ...
 
 
 class ReferenceBackend:
-    """NumPy data plane — delegates to the state's own incremental probe
-    index (shard-routed under ``n_partitions > 1``, DESIGN.md §9) and the
-    core bincount reduction (the same code that runs with no backend)."""
+    """NumPy data plane: the state's own incremental probe index
+    (shard-routed under ``n_partitions > 1``, DESIGN.md §9) and the core
+    bincount reduction. It launches nothing on a device."""
 
     name = "reference"
-    probe_accepts_counters = True
+    launches_on_device = False
 
-    def probe(self, state, keycodes, counters=None):
-        return state.probe(keycodes)
+    def probe_launch(self, state, keycodes, counters=None) -> Launch:
+        return Launch(state.probe(keycodes))
+
+    def probe_visible_launch(self, state, keycodes, qid) -> Optional[Launch]:
+        return None
+
+    def probe_visible_multi_launch(self, state, keycodes) -> Optional[Launch]:
+        return None
+
+    def probe_chain_launch(self, cplan, cols, bits, counters=None) -> Optional[Launch]:
+        return None
 
     def segment_sum(self, gids, values, n_groups):
         return _bincount_segment_sum(gids, values, n_groups)
+
+    def precompile(self, db, morsel_size: int) -> int:
+        return 0
 
     def stats(self) -> dict:
         return {}
@@ -235,17 +278,10 @@ class PallasBackend:
     Unique-key states probe through one device program,
     ``graft_chain_stage`` (``kernels/fused_chain.py``), over entry-indexed
     device mirrors: a fused stage chain launches it once per stage, and
-    each single probe is a one-stage launch of it. ``probe_visible`` turns
-    the query's slot bit (any of the 64) into the lens, so visibility
-    resolves on device and the runtime skips its NumPy ``visible_mask``
-    pass; ``probe_visible_multi`` returns the matched entries' full packed
-    uint64 words; ``probe_chain`` runs a morsel's entire stage chain —
-    probe → lens translation → compiled grant predicates → interval stage
-    filters, then the build sink's word translation — without a host round
-    trip between stages. Everything the kernels cannot serve (multi-match
-    keys, out-of-range keycodes, over-long probe clusters) falls back to
-    the reference probe, with the decline reason counted in
-    ``fallback_reasons``.
+    each single probe is a one-stage launch of it. Everything the programs
+    cannot serve (multi-match keys, out-of-range keycodes, over-long probe
+    clusters) falls back to the reference probe, with the decline reason
+    counted in ``fallback_reasons``.
 
     Every program's static shape comes from the session's ``ShapeLadder``,
     and ``precompile`` compiles the whole ladder (or reads it from JAX's
@@ -253,48 +289,25 @@ class PallasBackend:
     distinct program shapes compiled or launched, ``precompiled_programs``
     the ladder's.
 
-    Probe-table maintenance is batch-oriented: new keys insert via
-    vectorized per-slot winner election (``_batch_insert``), or through the
-    Pallas ``hash_build_insert`` kernel when ``use_insert_kernel`` is set
-    (opt-in: the in-kernel insert loop is sequential).
-
-    ``interpret`` applies to the two Pallas kernels only; None takes it
-    from the platform (compiled on the TPU, interpreted elsewhere).
-
-    Segmented sums route through the one-hot MXU kernel below
-    ``max_kernel_groups`` groups when ``use_agg_kernel`` is set; it
-    accumulates in float32, so it is opt-in — the default keeps aggregate
-    accumulation in float64 to preserve exact oracle parity.
+    Probe-table maintenance is batch-oriented: new keys insert on the host
+    by vectorized per-slot winner election (``_batch_insert``), and only
+    the slots they fill cross to the device. Segmented sums stay on the
+    host in float64, the reference backend's reduction.
     """
 
     name = "pallas"
-    probe_accepts_counters = True
+    launches_on_device = True
 
     # Keycodes must fit int32 and stay clear of the kernel's EMPTY sentinel.
     _KEY_LIMIT = 2**31 - 2
 
-    def __init__(
-        self,
-        interpret: Optional[bool] = None,
-        max_kernel_groups: int = 4096,
-        use_agg_kernel: bool = False,
-        use_insert_kernel: bool = False,
-    ):
+    def __init__(self):
         import jax
 
-        from ..kernels import default_interpret, fused_chain
-        from ..kernels.hash_probe import hash_build_insert
-        from ..kernels.seg_aggregate import seg_aggregate
+        from ..kernels import fused_chain
 
         self._chain = fused_chain
-        self._hash_build_insert = hash_build_insert
-        self._seg_aggregate = seg_aggregate
         self._total_order_u32 = fused_chain.total_order_u32
-        self.interpret = default_interpret() if interpret is None else interpret
-        self.max_kernel_groups = max_kernel_groups
-        self.use_agg_kernel = use_agg_kernel
-        self.use_insert_kernel = use_insert_kernel
-        self._ref = ReferenceBackend()
         # donated in-place mirror patches (CPU jax warns on donation)
         self._donate = jax.default_backend() != "cpu"
         self.ladder = ShapeLadder()
@@ -366,9 +379,9 @@ class PallasBackend:
                 out.update(ent.jkeys.devices())
         return out
 
-    def note_fallback(self, reason: str, counters=None) -> None:
-        """Record one kernel decline by reason, on the backend and (when
-        the engine's counter dict is handed in) in the session counters."""
+    def _note_fallback(self, reason: str, counters=None) -> None:
+        """Record one decline by reason, on the backend and (when the
+        engine's counter dict is handed in) in the session counters."""
         self.fallback_reasons[reason] += 1
         if counters is not None:
             counters[f"fallback_probes_{reason}"] += 1
@@ -514,17 +527,10 @@ class PallasBackend:
         self.h2d_bytes += out.nbytes
         return out
 
-    def _d2h(self, *outs):
-        """Read a launch's outputs back, counted in ``d2h_bytes``. Every
-        copy is queued behind the launch first, as ``jax.device_get``
-        does, so the host's wait for the device and its wait for the
-        copies are timed apart without a round trip between them."""
-        for o in outs:
-            o.copy_to_host_async()
-        return self._read_back(outs)
-
     def _read_back(self, outs):
-        """Wait for outputs whose copies are queued, then take the copies."""
+        """Wait for outputs whose copies are queued, then take the copies,
+        counted in ``d2h_bytes``. The host's wait for the device and its
+        wait for the copies are timed apart."""
         import jax
 
         with span("graftdb.device_wait"):
@@ -547,11 +553,10 @@ class PallasBackend:
         return rows, self._h2d(_pad(kc, rows, _EMPTY_U32)), live
 
     # -- probe ---------------------------------------------------------------
-    # Each probe and the chain come in two halves (DESIGN.md §11): the
-    # ``*_launch`` half does everything up to the device launch and queues
-    # the outputs' copies to the host; the returned ``Launch``'s
-    # ``collect()`` reads them back and finishes the host work. The
-    # one-call methods are launch-then-collect.
+    # Each probe and the chain are split at the device launch (DESIGN.md
+    # §11): the ``*_launch`` call does everything up to the launch and
+    # queues the outputs' copies to the host; the returned ``Launch``'s
+    # ``collect()`` reads them back and finishes the host work.
     def _launched(self, name, outs, post) -> Launch:
         """A probe or chain launch of the device outputs ``outs``: each
         copy to the host is queued now, as ``jax.device_get`` does;
@@ -580,23 +585,20 @@ class PallasBackend:
 
         return self._const("flags_lens", make)
 
-    def probe(self, state, keycodes, counters=None):
-        return self.probe_launch(state, keycodes, counters).collect()
-
     @spanned("graftdb.backend.probe")
     def probe_launch(self, state, keycodes, counters=None) -> Launch:
-        """Launch half of ``probe``; the reference fallbacks run here, and
-        their Launch holds the result."""
+        """All (probe_idx, entry_idx) match pairs, pre-visibility. The
+        reference fallbacks run here, and their Launch holds the result."""
         if state.keycode.n == 0 or len(keycodes) == 0:
             return Launch((np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64)))
         if self._table_for(state) is None:
             self.fallback_probes += 1
-            self.note_fallback("capacity", counters)
-            return Launch(self._ref.probe(state, keycodes))
+            self._note_fallback("capacity", counters)
+            return Launch(state.probe(keycodes))
         if keycodes.min() < 0 or keycodes.max() > self._KEY_LIMIT:
             self.fallback_probes += 1
-            self.note_fallback("keyrange", counters)
-            return Launch(self._ref.probe(state, keycodes))
+            self._note_fallback("keyrange", counters)
+            return Launch(state.probe(keycodes))
         n = len(keycodes)
 
         def post(host):
@@ -608,21 +610,16 @@ class PallasBackend:
         outs = self._single_stage(state, keycodes, self._identity_tables())
         return self._launched("graftdb.backend.probe", (outs[2],), post)
 
-    def probe_visible(self, state, keycodes, qid):
-        """Single-query probe with the state lens fused in-kernel.
+    @spanned("graftdb.backend.probe_visible")
+    def probe_visible_launch(self, state, keycodes, qid) -> Optional[Launch]:
+        """Single-query probe with the state lens fused in the program.
 
-        Returns visibility-filtered (probe_idx, entry_idx) pairs, or None
-        when the kernel cannot take over the lens (extent-scoped grants
-        need predicate evaluation — unless routed through ``probe_chain``'s
+        Collects visibility-filtered (probe_idx, entry_idx) pairs. None
+        when the program cannot take over the lens (extent-scoped grants
+        need predicate evaluation — unless routed through the chain's
         compiled form; unservable tables fall back entirely). The lens
         words are entry-indexed uint32 pairs, so any slot 0..63 serves
         (DESIGN.md §13)."""
-        launch = self.probe_visible_launch(state, keycodes, qid)
-        return None if launch is None else launch.collect()
-
-    @spanned("graftdb.backend.probe_visible")
-    def probe_visible_launch(self, state, keycodes, qid) -> Optional[Launch]:
-        """Launch half of ``probe_visible``; None where it declines."""
         if state.grants.get(qid):
             return None
         slot = state.slots.peek(qid)
@@ -646,21 +643,17 @@ class PallasBackend:
         outs = self._single_stage(state, keycodes, self._lens_tables(slot), self._lens_flags())
         return self._launched("graftdb.backend.probe_visible", (outs[2],), post)
 
-    def probe_visible_multi(self, state, keycodes):
-        """Multi-member probe with the packed lens words gathered in-kernel
-        (§11): returns ``(probe_idx, entry_idx, vis_words)`` where
-        ``vis_words[i]`` is the matched entry's full uint64 visibility
-        word (rejoined from the kernel's uint32 halves), or None when the
-        kernel cannot serve the state. The pair stream is pre-visibility
-        and identical to ``probe`` — ownership filtering happens in the
-        runtime's packed translation — so results stay bit-identical to
-        the reference path for every member count and any slot 0..63."""
-        launch = self.probe_visible_multi_launch(state, keycodes)
-        return None if launch is None else launch.collect()
-
     @spanned("graftdb.backend.probe_visible_multi")
     def probe_visible_multi_launch(self, state, keycodes) -> Optional[Launch]:
-        """Launch half of ``probe_visible_multi``; None where it declines."""
+        """Multi-member probe with the packed lens words gathered in the
+        program (§11): collects ``(probe_idx, entry_idx, vis_words)`` where
+        ``vis_words[i]`` is the matched entry's full uint64 visibility
+        word (rejoined from the program's uint32 halves); None when the
+        program cannot serve the state. The pair stream is pre-visibility
+        and identical to ``probe_launch``'s — ownership filtering happens
+        in the runtime's packed translation — so results stay
+        bit-identical to the reference path for every member count and
+        any slot 0..63."""
         if state.keycode.n == 0 or len(keycodes) == 0:
             return None
         if self._table_for(state) is None or keycodes.min() < 0 or keycodes.max() > self._KEY_LIMIT:
@@ -680,26 +673,6 @@ class PallasBackend:
         return self._launched("graftdb.backend.probe_visible_multi", outs[2:3] + outs[:2], post)
 
     # -- fused stage chain (DESIGN.md §13) -----------------------------------
-    def probe_chain(self, cplan, cols, bits, host_keys, counters=None):
-        """A morsel's entire stage chain, one stage program per stage with
-        no host round trip between them.
-
-        ``cplan`` is the runtime's compiled chain plan (``Pipeline.
-        _build_chain_plan``): per stage the target state, lens translation
-        tables, key sourcing, compiled grants and interval filter matrices;
-        plus the sink translation tables. ``cols`` are the morsel's
-        source-compacted columns, ``bits`` the packed ownership words and
-        ``host_keys`` the per-stage host-encoded keycodes for
-        source-origin keys. Returns None on a dynamic decline (reason
-        counted), else a dict with the final packed words, per-stage
-        matched entry indices, per-stage (alive, matched,
-        matched_visible) stats, and — for build chains — the sink
-        visibility/provenance words and per-slot survivor counts. Device
-        parameter uploads are cached on the plan (``cplan["_dev"]``), so
-        steady-state morsels ship only the row-length arrays."""
-        launch = self.probe_chain_launch(cplan, cols, bits, host_keys, counters)
-        return None if launch is None else launch.collect()
-
     @staticmethod
     def _chain_layout(stages):
         """Where each stage's inputs come from: the entry columns each
@@ -729,8 +702,27 @@ class PallasBackend:
         return {"exports": exports, "keys": keys, "filters": filters}
 
     @spanned("graftdb.backend.probe_chain")
-    def probe_chain_launch(self, cplan, cols, bits, host_keys, counters=None):
-        """Launch half of ``probe_chain``; None on a dynamic decline."""
+    def probe_chain_launch(self, cplan, cols, bits, counters=None) -> Optional[Launch]:
+        """A morsel's entire stage chain, one stage program per stage with
+        no host round trip between them.
+
+        ``cplan`` is the runtime's compiled chain plan (``Pipeline.
+        _build_chain_plan``): per stage the target state, lens translation
+        tables, key sourcing, compiled grants and interval filter matrices;
+        plus the sink translation tables. A plan that could not compile
+        (``cplan["ok"]`` false) is a static decline under its
+        ``cplan["reason"]``. ``cols`` are the morsel's source-compacted
+        columns, ``bits`` the packed ownership words; source-origin keys
+        are encoded from ``cols`` here. Returns None on a decline (reason
+        counted), else a Launch collecting a dict with the final packed
+        words, per-stage matched entry indices, per-stage (alive, matched,
+        matched_visible) stats, and — for build chains — the sink
+        visibility/provenance words and per-slot survivor counts. Device
+        parameter uploads are cached on the plan (``cplan["_dev"]``), so
+        steady-state morsels ship only the row-length arrays."""
+        if not cplan["ok"]:
+            self._note_fallback(cplan["reason"], counters)
+            return None
         ch = self._chain
         stages = cplan["stages"]
         n = len(bits)
@@ -741,15 +733,15 @@ class PallasBackend:
         if layout is None:
             layout = dev["layout"] = self._chain_layout(stages)
         if any(len(x) > ch.X_MAX for x in layout["exports"]):
-            self.note_fallback("keyrange", counters)
+            self._note_fallback("keyrange", counters)
             return None
         for st in stages:
             if len(st["grants"]) > ch.G_MAX:
-                self.note_fallback("grants", counters)
+                self._note_fallback("grants", counters)
                 return None
             f = st["filter"]
             if f is not None and (f["n_members"] > ch.M_MAX or len(f["attrs"]) > ch.A_MAX):
-                self.note_fallback("predicate", counters)
+                self._note_fallback("predicate", counters)
                 return None
 
         # collect per-state mirror needs across the chain
@@ -782,7 +774,7 @@ class PallasBackend:
                 (nd["keys"] if kind == "key" else nd["ords"]).add(attr)
         for st in stages:
             if self._table_for(st["state"]) is None:
-                self.note_fallback("capacity", counters)
+                self._note_fallback("capacity", counters)
                 return None
         for nd in needs.values():
             state = nd["state"]
@@ -796,12 +788,16 @@ class PallasBackend:
             )
             for a in nd["keys"]:
                 if a in ent.badkeys:
-                    self.note_fallback("keyrange", counters)
+                    self._note_fallback("keyrange", counters)
                     return None
-        for si, st in enumerate(stages):
-            kc = host_keys.get(si)
-            if kc is not None and len(kc) and (kc.min() < 0 or kc.max() > self._KEY_LIMIT):
-                self.note_fallback("keyrange", counters)
+        host_keys = {
+            si: encode_keys(cols, st["key"][1])
+            for si, st in enumerate(stages)
+            if st["key"][0] == "host"
+        }
+        for kc in host_keys.values():
+            if len(kc) and (kc.min() < 0 or kc.max() > self._KEY_LIMIT):
+                self._note_fallback("keyrange", counters)
                 return None
 
         rows = self.ladder.rows_for(n)
@@ -834,7 +830,7 @@ class PallasBackend:
                 if gp is None:
                     gp = dev[("g", si)] = self._grant_params(st["grants"])
                 if gp is False:
-                    self.note_fallback("grants", counters)
+                    self._note_fallback("grants", counters)
                     return None
                 gattr_names, grants = gp[0], gp[1:]
                 gattrs = [(ent.ords[a][0], ent.ords[a][1]) for a in gattr_names]
@@ -1143,8 +1139,7 @@ class PallasBackend:
         """Insert keys[ent.n:n] into the table. The table is built at the
         state's entry capacity (``bound`` on the ladder) and rebuilt only
         if the state outgrows it. Insertion is one batched winner-election
-        pass (or the Pallas insert kernel on full rebuilds when
-        ``use_insert_kernel`` is set) — never a per-key Python loop.
+        pass — never a per-key Python loop.
         Rebuilds reassign table slots but leave the entry-indexed mirrors
         untouched unless the capacity changes (they are keyed by entry id,
         not slot — the §13 incremental-maintenance invariant)."""
@@ -1157,21 +1152,14 @@ class PallasBackend:
         if ent.tkeys is None or n > ent.ecap:
             ent.ecap = self.ladder.entries_for(max(bound, n))
             cap = 2 * ent.ecap
-            if self.use_insert_kernel:
-                if not self._kernel_rebuild(ent, keys[:n], cap):
-                    ent.bad = True
-                    return
-                ent.jkeys = self._h2d(ent.tkeys)
-                ent.jentry = self._h2d(ent.slot_entry)
-            else:
-                ent.tkeys = np.full(cap, EMPTY, dtype=np.int32)
-                ent.slot_entry = np.full(cap, -1, dtype=np.int32)
-                placed = self._batch_insert(ent, keys[:n], 0)
-                if placed is None:
-                    ent.bad = True
-                    return
-                ent.jkeys = self._patch(self._fill(cap, "int32", EMPTY), placed, ent.tkeys[placed])
-                ent.jentry = self._patch(self._fill(cap, "int32", 0), placed, ent.slot_entry[placed])
+            ent.tkeys = np.full(cap, EMPTY, dtype=np.int32)
+            ent.slot_entry = np.full(cap, -1, dtype=np.int32)
+            placed = self._batch_insert(ent, keys[:n], 0)
+            if placed is None:
+                ent.bad = True
+                return
+            ent.jkeys = self._patch(self._fill(cap, "int32", EMPTY), placed, ent.tkeys[placed])
+            ent.jentry = self._patch(self._fill(cap, "int32", 0), placed, ent.slot_entry[placed])
         else:
             placed = self._batch_insert(ent, new, ent.n)
             if placed is None:
@@ -1236,42 +1224,9 @@ class PallasBackend:
             pending = pr
         return np.concatenate(placed)
 
-    def _kernel_rebuild(self, ent: "_ProbeTable", keys, cap: int) -> bool:
-        """Full-table rebuild through the Pallas batch-insert kernel."""
-        self._programs.add(("hash_build_insert", len(keys), cap))
-        tkeys, tentry, ok = self._d2h(
-            *self._hash_build_insert(
-                self._h2d(np.asarray(keys, dtype=np.int32)), capacity=cap,
-                interpret=self.interpret,
-            )
-        )
-        if int(ok[0]) == 0:
-            return False
-        ent.tkeys = tkeys
-        ent.slot_entry = tentry.astype(np.int32)
-        return True
-
     # -- segmented aggregation ------------------------------------------------
     def segment_sum(self, gids, values, n_groups):
-        if n_groups == 0 or len(gids) == 0:
-            return np.zeros(n_groups, dtype=np.float64)
-        if not self.use_agg_kernel or n_groups > self.max_kernel_groups:
-            return self._ref.segment_sum(gids, values, n_groups)
-        vals = (
-            np.ones((len(gids), 1))
-            if values is None
-            else np.asarray(values, dtype=np.float64).reshape(-1, 1)
-        )
-        self._programs.add(("seg_aggregate", len(gids), n_groups))
-        (out,) = self._d2h(
-            self._seg_aggregate(
-                self._h2d(np.asarray(gids, dtype=np.int32)),
-                self._h2d(np.asarray(vals, dtype=np.float32)),
-                n_groups,
-                interpret=self.interpret,
-            )
-        )
-        return out.astype(np.float64)[:, 0]
+        return _bincount_segment_sum(gids, values, n_groups)
 
 
 def resolve_backend(spec) -> ExecutionBackend:

@@ -72,7 +72,7 @@ class EngineConfig:
       class), invoked per session; or a clock instance — which is then
       SHARED by every session built from this config (advanced use).
     * ``backend`` — ``"reference"`` (NumPy row engine) or ``"pallas"``
-      (vectorized jax_pallas probe/aggregate kernels), or an
+      (probes and fused stage chains as device programs), or an
       ``ExecutionBackend`` instance.
     * ``retention`` — shared-state retention policy: ``"refcount"`` is the
       evaluated prototype's release-at-zero-refs policy; ``"epoch"``

@@ -62,12 +62,10 @@ class Session:
             mesh_plan=self._mesh_plan,
             faults=self.config.faults,
         )
-        precompile = getattr(self.backend, "precompile", None)
-        if precompile is not None:
-            # every device program's shape comes from a ladder fixed by the
-            # database and the morsel size; compile it now, so that no
-            # query waits for a compile (DESIGN.md §13)
-            precompile(db, self.config.morsel_size)
+        # every device program's shape comes from a ladder fixed by the
+        # database and the morsel size; compile it now, so that no query
+        # waits for a compile (DESIGN.md §13)
+        self.backend.precompile(db, self.config.morsel_size)
         admission = self.config.make_admission()
         batch_kw = dict(
             batch_planning=self.config.batch_planning,
@@ -328,10 +326,8 @@ class Session:
         out["batch_window"] = self.config.batch_window
         out["memory_budget"] = self.config.memory_budget
         out["reuse_cache_budget"] = self.config.reuse_cache_budget
-        backend_stats = getattr(self.backend, "stats", None)
-        if backend_stats is not None:
-            for k, v in backend_stats().items():
-                out[f"backend_{k}"] = v
+        for k, v in self.backend.stats().items():
+            out[f"backend_{k}"] = v
         return out
 
     # -- lifecycle -----------------------------------------------------------

@@ -156,9 +156,11 @@ class GraftEngine:
         # mesh session over an older calibrated dict still charges it
         self.cost_model.setdefault("exchange", DEFAULT_COST_MODEL["exchange"])
         self.zone_maps = zone_maps  # beyond-paper morsel skipping (§Perf)
-        # Data-plane backend (api/backends.py ExecutionBackend); None keeps
-        # the built-in NumPy paths (state.probe / np.bincount reductions).
-        self.backend = backend
+        # Data-plane backend (api/backends.py ExecutionBackend); the NumPy
+        # ReferenceBackend unless one is given.
+        from ..api.backends import ReferenceBackend
+
+        self.backend = backend or ReferenceBackend()
         # Partition-parallel data plane (DESIGN.md §9): scans shard into
         # P morsel ranges, states shard their indexes / partial aggregates
         # P ways. P == 1 is byte-identical to the seed single-stream engine.

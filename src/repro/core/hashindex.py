@@ -30,8 +30,9 @@ reduce to one int64 stream with no collision risk (dense ids stay far below
 2^32).  Float columns are keyed by their exact bit patterns (with -0.0
 canonicalized to +0.0 so numpy float equality and bit equality agree).
 
-The core is NumPy-only; the Pallas batch-insert path for the probe-table
-mirror lives in ``kernels/hash_probe.py`` (``hash_build_insert``).
+The core is NumPy-only; the device backend's probe table is built the same
+batched way (``api/backends.py`` ``PallasBackend._batch_insert``) and
+probed on the device by ``kernels/hash_probe.py`` (``probe_slots``).
 """
 
 from __future__ import annotations

@@ -70,7 +70,6 @@ from .state import (
     GrowArray,
     SharedAggregateState,
     SharedHashBuildState,
-    _bincount_segment_sum,
 )
 from .tracing import span, spanned
 from .visibility import (
@@ -117,14 +116,6 @@ def encode_keys(cols: Dict[str, np.ndarray], attrs: Sequence[str]) -> np.ndarray
     return code
 
 
-def _backend_probe(backend, state, keycodes, counters):
-    """Generic pre-visibility probe, handing the engine counter dict to
-    backends that attribute their fallbacks by reason (DESIGN.md §13)."""
-    if getattr(backend, "probe_accepts_counters", False):
-        return backend.probe(state, keycodes, counters=counters)
-    return backend.probe(state, keycodes)
-
-
 class Launch:
     """One backend call split at its device launch (DESIGN.md §11).
 
@@ -166,26 +157,17 @@ def _stage_launch(backend, op, keycodes, act, grant_members, kernelable, counter
     single member's visible pairs (lens resolved in the kernel), ``"multi"``
     the pairs and the matched entries' packed lens words, ``"pairs"`` the
     pre-visibility pairs."""
-    if backend is None:
-        return Launch(op.state.probe(keycodes)), "pairs"
     if len(act) == 1 and not grant_members:
-        launch_visible = getattr(backend, "probe_visible_launch", None)
-        if launch_visible is not None:
-            launch = launch_visible(op.state, keycodes, act[0].lens_qid)
-            if launch is not None:
-                return launch, "lens"
+        launch = backend.probe_visible_launch(op.state, keycodes, act[0].lens_qid)
+        if launch is not None:
+            return launch, "lens"
     elif kernelable and len(act) > 1:
         # multi-member lens: one launch returns every probing member's
         # ownership word (the matched entry's packed visibility word)
-        launch_multi = getattr(backend, "probe_visible_multi_launch", None)
-        if launch_multi is not None:
-            launch = launch_multi(op.state, keycodes)
-            if launch is not None:
-                return launch, "multi"
-    launch_probe = getattr(backend, "probe_launch", None)
-    if launch_probe is not None:
-        return launch_probe(op.state, keycodes, counters=counters), "pairs"
-    return Launch(_backend_probe(backend, op.state, keycodes, counters)), "pairs"
+        launch = backend.probe_visible_multi_launch(op.state, keycodes)
+        if launch is not None:
+            return launch, "multi"
+    return backend.probe_launch(op.state, keycodes, counters=counters), "pairs"
 
 
 def run_steps(steps) -> float:
@@ -1044,26 +1026,11 @@ class Pipeline:
         served = False
         chain_sink = None
         cplan = plan.get("chain")
-        chain_launch = (
-            getattr(backend, "probe_chain_launch", None) if backend is not None else None
-        )
-        if cplan is not None and chain_launch is not None and len(did) > 0:
-            launch = None
+        if cplan is not None and len(did) > 0:
+            # one fused launch for the whole stage chain (§13); a decline
+            # (counted by the backend) falls through to the staged loop below
             with span("graftdb.join", kind=self.join_kind):
-                if cplan["ok"]:
-                    # one fused launch for the whole stage chain (§13); host
-                    # keys validated backend-side over the full morsel — any
-                    # dynamic decline falls through to the staged loop below
-                    host_keys = {
-                        si: encode_keys(cols, st["key"][1])
-                        for si, st in enumerate(cplan["stages"])
-                        if st["key"][0] == "host"
-                    }
-                    launch = chain_launch(
-                        cplan, cols, bits, host_keys, counters=engine.counters
-                    )
-                else:
-                    backend.note_fallback(cplan["reason"], engine.counters)
+                launch = backend.probe_chain_launch(cplan, cols, bits, counters=engine.counters)
             if launch is not None:
                 if overlap and launch.pending:
                     yield launch
@@ -1308,10 +1275,7 @@ class Pipeline:
         pair_gids = gids if pr is None else gids[pr]
         code = pair_gids * np.int64(k) + pm
         nbuckets = ng * k
-        backend = engine.backend
-        segment_sum = (
-            backend.segment_sum if backend is not None else _bincount_segment_sum
-        )
+        segment_sum = engine.backend.segment_sum
         counts2d = segment_sum(code, None, nbuckets).reshape(ng, k)
         vals = []
         for a in sink.aggs:
@@ -1382,7 +1346,6 @@ class Pipeline:
         per-member sink body, shared by the oracle path, singleton/distinct
         cohorts, and the overflow slow lane)."""
         sink = m.sink
-        backend = engine.backend
         key_cols = [scols[k] for k in sink.group_keys]
         vals = [
             expr_eval(a.expr, scols) if a.expr is not None else None
@@ -1398,7 +1361,7 @@ class Pipeline:
             key_cols,
             vals,
             nsel,
-            segment_sum=backend.segment_sum if backend is not None else None,
+            segment_sum=engine.backend.segment_sum,
             part=part,
         )
         m.rows_sunk += nsel
@@ -1436,24 +1399,18 @@ class Pipeline:
                 break
             with span("graftdb.join", kind=op.kind):
                 keycodes = encode_keys(cols, op.probe_attrs)
-                # single-member probes resolve the state lens in-kernel when the
-                # backend can serve it; the runtime then skips visible_mask
-                lens_fused = False
-                if backend is not None:
-                    if len(act) == 1:
-                        probe_visible = getattr(backend, "probe_visible", None)
-                        if probe_visible is not None:
-                            fused_pair = probe_visible(op.state, keycodes, act[0].lens_qid)
-                            if fused_pair is not None:
-                                probe_idx, entry_idx = fused_pair
-                                lens_fused = True
-                                engine.counters["kernel_lens_probes"] += 1
-                    if not lens_fused:
-                        probe_idx, entry_idx = _backend_probe(
-                            backend, op.state, keycodes, engine.counters
-                        )
+                # single-member probes resolve the state lens in the device
+                # program when the backend serves it; the runtime then skips
+                # visible_mask
+                launch = None
+                if len(act) == 1:
+                    launch = backend.probe_visible_launch(op.state, keycodes, act[0].lens_qid)
+                lens_fused = launch is not None
+                if lens_fused:
+                    engine.counters["kernel_lens_probes"] += 1
                 else:
-                    probe_idx, entry_idx = op.state.probe(keycodes)
+                    launch = backend.probe_launch(op.state, keycodes, counters=engine.counters)
+                probe_idx, entry_idx = launch.collect()
                 if engine.mesh_plan is not None:
                     # §14 exchange charge — identical to the fused path's so
                     # the oracle stays clock-bit-identical under mesh
@@ -1554,12 +1511,9 @@ class Pipeline:
             if len(did) == 0:
                 break
             keycodes = encode_keys(mcols, op.probe_attrs)
-            if backend is not None:
-                probe_idx, entry_idx = _backend_probe(
-                    backend, op.state, keycodes, engine.counters
-                )
-            else:
-                probe_idx, entry_idx = op.state.probe(keycodes)
+            probe_idx, entry_idx = backend.probe_launch(
+                op.state, keycodes, counters=engine.counters
+            ).collect()
             if engine.mesh_plan is not None:
                 # §14 exchange charge — the slow lane's rows route through
                 # the same bucketed all_to_all as the packed path's

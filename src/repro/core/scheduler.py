@@ -841,15 +841,15 @@ class Runner:
     # -- two units in flight (§11) -------------------------------------------
     def _overlaps(self) -> bool:
         """Whether ``run`` may hold two units in flight: real time on one
-        worker, a backend with launch halves, no mesh (§14 keeps its
-        device-affinity order) and no fault plane (a fault gate precedes
-        every unit)."""
+        worker, a backend whose launches run on a device, no mesh (§14
+        keeps its device-affinity order) and no fault plane (a fault gate
+        precedes every unit)."""
         return (
             self.workers == 1
             and isinstance(self.clocks[0], WallClock)
             and self.engine.mesh_plan is None
             and self.faults is None
-            and hasattr(self.engine.backend, "probe_chain_launch")
+            and self.engine.backend.launches_on_device
         )
 
     def _start(self, node: ScanNode, part: int, on_complete) -> None:

@@ -969,9 +969,9 @@ class SharedAggregateState:
         """Fold one morsel of rows into partition ``part``'s accumulators
         (segment reduce).
 
-        ``segment_sum(gids, values_or_None, n_groups)`` lets an execution
-        backend (api/backends.py) supply the grouped reduction — e.g. the
-        Pallas one-hot MXU kernel; defaults to ``np.bincount``."""
+        ``segment_sum(gids, values_or_None, n_groups)`` is the execution
+        backend's grouped reduction (api/backends.py); defaults to
+        ``np.bincount``."""
         if n == 0:
             return
         self._check_live()

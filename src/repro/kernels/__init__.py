@@ -1,6 +1,7 @@
-"""Device programs of the data plane: XLA programs for the probes and the
-fused stage chain, Pallas kernels for the opt-in batch insert and segmented
-aggregate (and the LM stack's attention/recurrence)."""
+"""Device programs: the data plane's jitted XLA programs — the fused stage
+chain and its sink (``fused_chain``), built on the hash probe
+(``hash_probe.probe_slots``) — and the LM stack's Pallas kernels
+(attention, linear recurrence)."""
 
 import jax
 

@@ -1,5 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness references the
-shape/dtype sweep tests assert against)."""
+"""Pure-jnp oracles for the LM stack's Pallas kernels (the correctness
+references the shape/dtype sweep tests assert against)."""
 
 from __future__ import annotations
 
@@ -7,23 +7,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-
-def hash_probe_lens_ref(probe_keys, table_keys, table_vis, query_mask):
-    """For each probe key: index of the matching, query-visible entry in the
-    open-addressing table, else -1. (Unique keys.)"""
-    T = table_keys.shape[0]
-    eq = probe_keys[:, None] == table_keys[None, :]  # [N, T]
-    vis = (table_vis & query_mask[0]) != 0
-    hit = eq & vis[None, :]
-    idx = jnp.argmax(hit, axis=1).astype(jnp.int32)
-    return jnp.where(hit.any(axis=1), idx, -1)
-
-
-def seg_aggregate_ref(codes, values, n_groups):
-    return jax.ops.segment_sum(
-        values.astype(jnp.float32), codes, num_segments=n_groups
-    )
 
 
 def flash_attention_ref(q, k, v, *, window=None):

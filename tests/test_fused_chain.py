@@ -324,9 +324,9 @@ def test_mirror_appends_and_marks_patch_incrementally():
     s, keys = _mini_state(64)
     s.max_entries = 256
     slot = s.slots.get(7)
-    backend = PallasBackend(interpret=True)
-    first = backend.probe_visible(s, keys, 7)
-    assert first is not None and len(first[0]) == 0  # nothing marked for q7
+    backend = PallasBackend()
+    first = backend.probe_visible_launch(s, keys, 7).collect()
+    assert len(first[0]) == 0  # nothing marked for q7
     assert backend.mirror_full_regathers == 0
 
     # append 136 keys (64 -> 200) inside the mirror's entry capacity, and
@@ -342,8 +342,7 @@ def test_mirror_appends_and_marks_patch_incrementally():
         {"k": marked.astype(float), "x": marked.astype(float)},
         np.full(3, np.uint64(1) << np.uint64(slot)), np.zeros(3, np.uint64),
     )
-    second = backend.probe_visible(s, np.arange(200, dtype=np.int64), 7)
-    assert second is not None
+    second = backend.probe_visible_launch(s, np.arange(200, dtype=np.int64), 7).collect()
     np.testing.assert_array_equal(np.sort(second[0]), marked)
     assert backend.mirror_full_regathers == 0
     assert backend.mirror_patched_rows > 0
@@ -361,16 +360,16 @@ def test_detach_bumps_vis_epoch_and_regathers_once():
         keys, keys, {"k": keys.astype(float), "x": keys.astype(float)},
         np.full(64, np.uint64(1) << np.uint64(slot)), np.zeros(64, np.uint64),
     )
-    backend = PallasBackend(interpret=True)
-    first = backend.probe_visible(s, keys, 9)
-    assert first is not None and len(first[0]) == 64
+    backend = PallasBackend()
+    first = backend.probe_visible_launch(s, keys, 9).collect()
+    assert len(first[0]) == 64
 
     epoch_before = s.vis_epoch
     s.detach(9)
     assert s.vis_epoch == epoch_before + 1
     s.slots.get(9)  # reattach: same qid, fresh (unmarked) slot
-    again = backend.probe_visible(s, keys, 9)
-    assert again is not None and len(again[0]) == 0  # cleared bits observed
+    again = backend.probe_visible_launch(s, keys, 9).collect()
+    assert len(again[0]) == 0  # cleared bits observed
     assert backend.mirror_full_regathers == 1
 
 

@@ -400,11 +400,11 @@ def test_multi_member_kernel_words_match_state():
         keys, keys, {"k": keys.astype(float), "x": keys.astype(float)},
         vis, np.zeros(n, np.uint64),
     )
-    backend = PallasBackend(interpret=True)
+    backend = PallasBackend()
     probes = np.concatenate([keys[::3], rng.integers(0, 20_000, 200)]).astype(np.int64)
-    trip = backend.probe_visible_multi(s, probes)
-    assert trip is not None
-    p_idx, e_idx, words = trip
+    launch = backend.probe_visible_multi_launch(s, probes)
+    assert launch is not None
+    p_idx, e_idx, words = launch.collect()
     rp, re = s.probe(probes)
     np.testing.assert_array_equal(np.sort(p_idx), np.sort(rp))
     # pair streams agree as sets of (probe, entry) pairs

@@ -222,24 +222,49 @@ def test_pallas_batch_insert_detects_in_batch_duplicates(db):
     )
     pal, ref = PallasBackend(), ReferenceBackend()
     probe = np.array([7, 9], dtype=np.int64)
-    p_pairs = pal.probe(state, probe)
-    r_pairs = ref.probe(state, probe)
+    p_pairs = pal.probe_launch(state, probe).collect()
+    r_pairs = ref.probe_launch(state, probe).collect()
     np.testing.assert_array_equal(p_pairs[0], r_pairs[0])
     np.testing.assert_array_equal(p_pairs[1], r_pairs[1])
     assert pal.fallback_probes == 1  # multi-match state: reference path
 
 
-def test_seg_aggregate_kernel_matches_bincount():
-    pytest.importorskip("jax")
-    b = PallasBackend(use_agg_kernel=True)
-    r = ReferenceBackend()
-    rng = np.random.default_rng(0)
-    gids = rng.integers(0, 37, 500).astype(np.int64)
-    vals = rng.normal(size=500)
-    np.testing.assert_allclose(
-        b.segment_sum(gids, vals, 37), r.segment_sum(gids, vals, 37), rtol=1e-5
-    )
-    np.testing.assert_allclose(b.segment_sum(gids, None, 37), r.segment_sum(gids, None, 37))
+@pytest.mark.parametrize("kind", ["reference", "pallas"])
+def test_backend_implements_interface(db, kind):
+    """Each backend has every ``ExecutionBackend`` member, with the
+    protocol's parameters, and runs a query through a bare ``GraftEngine``
+    — which, given no backend, runs on a ``ReferenceBackend``."""
+    import inspect
+
+    from repro.api.backends import ExecutionBackend
+    from repro.core.engine import GraftEngine
+    from repro.core.scheduler import Runner
+
+    cls = {"reference": ReferenceBackend, "pallas": PallasBackend}[kind]
+    backend = cls()
+    assert isinstance(backend, ExecutionBackend)
+    methods = [n for n, v in vars(ExecutionBackend).items()
+               if callable(v) and not n.startswith("_")]
+    assert len(methods) == 7
+    for name in methods:
+        want = list(inspect.signature(getattr(ExecutionBackend, name)).parameters)
+        assert list(inspect.signature(getattr(cls, name)).parameters) == want, name
+    assert cls.launches_on_device is (kind == "pallas")
+    assert cls.name == kind
+
+    engine = GraftEngine(db) if kind == "reference" else GraftEngine(db, backend=backend)
+    assert isinstance(engine.backend, cls)
+    q = _q3(db, "1995-03-15")
+    (handle,) = Runner(engine).run([q])
+    want = refexec.execute(db, q.plan)
+    assert set(handle.result) == set(want)
+    for k in want:
+        np.testing.assert_allclose(
+            np.sort(np.asarray(handle.result[k], float)),
+            np.sort(np.asarray(want[k], float)),
+            rtol=1e-9,
+        )
+    assert (engine.backend.stats().get("kernel_probes", 0) > 0) is (kind == "pallas")
 
 
 def test_backend_instance_passthrough(db):

@@ -78,9 +78,9 @@ def traced(db, tmp_path_factory):
         futs = session.submit_all(qs)
         session.run()
         s, keys = _mini_state()
-        session.backend.probe(s, keys)
-        session.backend.probe_visible(s, keys, 7)
-        session.backend.probe_visible_multi(s, keys)
+        session.backend.probe_launch(s, keys).collect()
+        session.backend.probe_visible_launch(s, keys, 7).collect()
+        session.backend.probe_visible_multi_launch(s, keys).collect()
     assert all(f.status == "done" for f in futs)
     return program_spans.load(trace.latest_xplane(str(out))), qs
 
@@ -171,17 +171,17 @@ def test_admit_spans_carry_their_query_ids(traced):
 
 
 def test_transfer_counters_count_one_probe_by_hand():
-    """A second ``probe_visible`` of 100 keys against an unchanged state
+    """A second ``probe_visible_launch`` of 100 keys against an unchanged state
     ships 128 padded uint32 keys (the all-ones ownership words of 128 rows
     stay on the device from the first) and reads back 128 int32 entries;
     nothing of the state moves again."""
     from repro.api.backends import PallasBackend
 
     s, keys = _mini_state()
-    backend = PallasBackend(interpret=True)
-    assert backend.probe_visible(s, keys[:100], 7) is not None  # mirrors upload
+    backend = PallasBackend()
+    backend.probe_visible_launch(s, keys[:100], 7).collect()  # mirrors upload
     before = backend.stats()
-    pair = backend.probe_visible(s, keys[:100], 7)
+    pair = backend.probe_visible_launch(s, keys[:100], 7).collect()
     after = backend.stats()
     assert len(pair[0]) == 100
     delta = {k: after[k] - before[k] for k in after}
