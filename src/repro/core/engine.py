@@ -203,6 +203,10 @@ class GraftEngine:
         # (zero) from the start so stats dicts are shape-stable
         for k in (
             "index_rebuilds",
+            # rows insert_or_mark resolved through a direct-addressed and
+            # through a hashed did index (§8)
+            "dense_did_rows",
+            "hashed_did_rows",
             "kernel_lens_probes",
             "fused_filter_rows",
             # member-major fused data plane (§11) — present (zero) from the
@@ -326,7 +330,7 @@ class GraftEngine:
         # residual producers. The greedy path never sets it.
         self.cohort_ctx: Optional[Dict[int, List]] = None
         self._domains: Dict[str, int] = {}
-        self._distinct_keys: Dict[tuple, int] = {}
+        self._semi_bounds: Dict[tuple, Tuple[int, Optional[int]]] = {}
         self._next_state_id = 0
         self._agg_producers: Dict[int, SharedAggregateState] = {}  # member.mid -> agg
         # engine-scoped runtime-object ids (no class-counter leaks across
@@ -379,6 +383,7 @@ class GraftEngine:
 
     def new_hash_state(self, sig, join, did_domain: int) -> SharedHashBuildState:
         self._next_state_id += 1
+        max_entries, key_domain = self._build_bounds(join)
         return SharedHashBuildState(
             self._next_state_id,
             sig,
@@ -388,23 +393,27 @@ class GraftEngine:
             counters=self.counters,
             n_partitions=self.n_partitions,
             semi=join.kind == "semi",
-            max_entries=self._max_entries(join),
+            max_entries=max_entries,
+            key_domain=key_domain,
         )
 
-    def _max_entries(self, join) -> int:
+    def _build_bounds(self, join) -> Tuple[int, Optional[int]]:
         """The most entries the boundary's state can hold, from the
         database: its build source's rows, or for a semi-join over a plain
-        scan the source's distinct keys (cached per table and key)."""
+        scan the source's distinct keys. Beside it, for that semi-join, the
+        exclusive bound of the source's key codes, None where a code is
+        negative or the build is no plain scan (cached per table and key)."""
         scan, _ = build_spine(join.build)
         table = self.db[scan.table]
         if join.kind != "semi" or not isinstance(join.build, Scan):
-            return table.nrows
+            return table.nrows, None
         key = (scan.table, tuple(join.build_keys))
-        n = self._distinct_keys.get(key)
-        if n is None:
+        bounds = self._semi_bounds.get(key)
+        if bounds is None:
             codes = encode_keys(table.columns, tuple(join.build_keys))
-            n = self._distinct_keys[key] = len(np.unique(codes))
-        return n
+            domain = int(codes.max()) + 1 if len(codes) and codes.min() >= 0 else None
+            bounds = self._semi_bounds[key] = (len(np.unique(codes)), domain)
+        return bounds
 
     # -- submission (query grafting, §5.2) ------------------------------------
     def submit(self, query: Query) -> QueryHandle:

@@ -4,6 +4,9 @@ Every shared-state hot path that used to walk a Python ``dict`` per row —
 derivation-id dedup in ``SharedHashBuildState.insert_or_mark``, group-id
 assignment in ``SharedAggregateState``, count(distinct) seen-sets, and the
 probe-side key index — runs on this primitive instead (DESIGN.md §8).
+A hash-build state whose derivation ids lie in a small dense domain
+resolves them through ``DenseIndex`` instead: the same contract by direct
+address, with no hash, sort or rehash.
 
 ``HashIndex`` maps int64 keys to dense ids (0, 1, 2, ... in first-insertion
 order) with batched, fully vectorized ``lookup`` / ``lookup_or_insert``:
@@ -209,6 +212,61 @@ class HashIndex:
 
     def __contains__(self, key: int) -> bool:
         return int(self.lookup(np.asarray([key], dtype=np.int64))[0]) >= 0
+
+
+class DenseIndex:
+    """Keys in ``[0, domain)`` -> dense ids in first-insertion order, by
+    direct address.
+
+    ``HashIndex.lookup_or_insert``'s contract and ``n`` over an int32
+    id-per-key array (-1 = absent): a lookup is one gather. A batch's
+    absent keys take new ids in first-occurrence order, exactly as
+    ``dict.setdefault`` over the same stream. The array costs 4 bytes per
+    key of the domain, so ``SharedHashBuildState`` picks this index only
+    for a domain within a small multiple of its entry bound (DESIGN.md §8).
+    A key outside the domain raises."""
+
+    __slots__ = ("_ids", "n")
+
+    def __init__(self, domain: int):
+        self._ids = np.full(int(domain), -1, dtype=np.int32)
+        self.n = 0  # dense ids handed out
+
+    def lookup_or_insert(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        keys = np.asarray(keys, dtype=np.int64)
+        n_in = len(keys)
+        is_new = np.zeros(n_in, dtype=bool)
+        if n_in == 0:
+            return np.empty(0, dtype=np.int64), is_new
+        tab = self._ids
+        if keys.min() < 0 or keys.max() >= len(tab):
+            raise ValueError(f"key outside the index's domain [0, {len(tab)})")
+        found = tab[keys].astype(np.int64)
+        absent = np.flatnonzero(found < 0)
+        if len(absent):
+            ak = keys[absent]
+            if (ak[1:] >= ak[:-1]).all():
+                # non-decreasing (scan row ids, sorted key runs): a key
+                # occurs first where it differs from its predecessor
+                first = np.ones(len(ak), dtype=bool)
+                np.not_equal(ak[1:], ak[:-1], out=first[1:])
+            else:
+                # each absent key's lowest batch position by an exact
+                # minimum, never numpy's order of duplicate writes; shifted
+                # below -1 so it reads as neither an id nor absent
+                shifted = (absent - (n_in + 1)).astype(np.int32)
+                np.minimum.at(tab, ak, shifted)
+                first = tab[ak] == shifted
+            src = absent[first]  # ascending batch positions
+            n_new = len(src)
+            tab[keys[src]] = np.arange(self.n, self.n + n_new, dtype=np.int32)
+            self.n += n_new
+            found[absent] = tab[ak]
+            is_new[src] = True
+        return found, is_new
+
+    def __len__(self) -> int:
+        return self.n
 
 
 class MultiKeyIndex:

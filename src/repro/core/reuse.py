@@ -509,6 +509,7 @@ class ReusePlane:
             "semi": st.semi,
             "max_entries": st.max_entries,
             "did_domain": st.did_domain,
+            "key_domain": st.key_domain,
             "extents": extents,  # (conj | None, complete) in eid order
             "extent_parts": {
                 eid: (total, tuple(sorted(done)))
@@ -667,6 +668,7 @@ class ReusePlane:
             n_partitions=n_partitions,
             semi=meta["semi"],
             max_entries=meta["max_entries"],
+            key_domain=meta["key_domain"],
         )
         arrays = art.arrays
         n = meta["n_entries"]
@@ -682,10 +684,7 @@ class ReusePlane:
             if index:
                 # derived structure: ids assign 0..n-1 in array order (dids
                 # are unique per entry), matching the original exactly
-                if st.n_partitions == 1:
-                    st._did_index.lookup_or_insert(dids)
-                else:
-                    st._sharded_did_resolve(dids, kcs, 0)
+                st.resolve_dids(dids, kcs, 0)
             st.rows_inserted = n
         for conj, done in meta["extents"]:
             eid = st.register_extent(conj)

@@ -29,8 +29,10 @@ B_q on the entry (allowed = ALL).
 Layout is columnar SoA (TPU adaptation — DESIGN.md §2): dense append-only
 arrays indexed by two batched hash structures (DESIGN.md §8):
 
-* derivation ids dedup through a vectorized ``HashIndex`` (insert-or-mark
-  is one batched lookup/insert plus one ``bitwise_or.at`` pass),
+* derivation ids dedup through a batched did index (insert-or-mark is one
+  lookup/insert plus one ``bitwise_or.at`` pass): a direct-addressed
+  ``DenseIndex`` when the state's did domain is small beside its entry
+  bound (``DENSE_DID_FACTOR``, ``DENSE_DID_MAX``), else a ``HashIndex``,
 * probes resolve through an *incremental multi-match index*: a ``HashIndex``
   over keycodes routes unique keys in O(batch), while keys with multiple
   entries fall to a sorted duplicate run maintained by delta merge — no
@@ -38,7 +40,8 @@ arrays indexed by two batched hash structures (DESIGN.md §8):
 
 Partition-parallel sharding (DESIGN.md §9): under ``n_partitions > 1`` both
 index structures split into P shards routed by ``key_partition`` (splitmix64
-of the entry keycode). Entry *storage* stays one global SoA with ids
+of the entry keycode); a dense did index stays one array over the domain.
+Entry *storage* stays one global SoA with ids
 assigned in batch-stream first-occurrence order, which makes the resident
 arrays (and therefore per-partition visibility words: each entry's packed
 word belongs to exactly one key shard) bit-identical for every P — only the
@@ -70,7 +73,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .descriptors import StateSignature
-from .hashindex import HashIndex, MultiKeyIndex, key_partition
+from .hashindex import DenseIndex, HashIndex, MultiKeyIndex, key_partition
 from .predicates import Conjunction, Coverage, evaluate_conj
 from .visibility import SlotAllocator, bit_of
 
@@ -88,6 +91,13 @@ DIRECT_PROBE_MAX = 32768
 #: ids the log compacts away and bumps its epoch — one mirror regather then
 #: beats replaying an unbounded patch list.
 MARK_LOG_LIMIT = 1 << 16
+
+#: Did index rule (DESIGN.md §8): a state whose dids lie in ``[0, domain)``
+#: with ``domain`` at most ``DENSE_DID_FACTOR`` times its entry bound and at
+#: most ``DENSE_DID_MAX`` resolves them by direct address (``DenseIndex``,
+#: 4 bytes per key of the domain); any other state hashes them.
+DENSE_DID_FACTOR = 8
+DENSE_DID_MAX = 1 << 26
 
 
 def _bincount_segment_sum(gids, values, n_groups):
@@ -258,7 +268,13 @@ class SharedHashBuildState:
 
     ``max_entries`` bounds the entries the state can reach, from the
     database (the rows of its build source, or the source's distinct keys
-    for a semi state); the device backend sizes its mirrors from it."""
+    for a semi state); the device backend sizes its mirrors from it.
+
+    Dids lie in ``[0, did_domain)``; a semi state's dids are its key codes,
+    which lie in ``[0, key_domain)`` where the engine knows that bound (a
+    semi-join over a plain scan). The state resolves dids by direct address
+    when that domain is small beside ``max_entries`` (``dense_dids``), and
+    through a ``HashIndex`` otherwise; both give the same entry ids."""
 
     def __init__(
         self,
@@ -271,6 +287,7 @@ class SharedHashBuildState:
         n_partitions: int = 1,
         semi: bool = False,
         max_entries: Optional[int] = None,
+        key_domain: Optional[int] = None,
     ):
         self.state_id = state_id
         self.sig = sig
@@ -280,6 +297,7 @@ class SharedHashBuildState:
         self.payload = tuple(payload)
         self.retained_attrs = frozenset(self.payload) | frozenset(self.key_attrs)
         self.did_domain = did_domain
+        self.key_domain = key_domain
         self.n_partitions = max(1, int(n_partitions))
         self._counters = counters
 
@@ -289,7 +307,18 @@ class SharedHashBuildState:
         self.emask = GrowArray(np.uint64)
         self.cols: Dict[str, GrowArray] = {a: GrowArray(np.float64) for a in self.retained_attrs}
 
-        if self.n_partitions == 1:
+        domain = key_domain if self.semi else did_domain
+        self.dense_dids = (
+            max_entries is not None
+            and domain is not None
+            and domain <= min(DENSE_DID_FACTOR * max_entries, DENSE_DID_MAX)
+        )
+        self._did_index = None
+        if self.dense_dids:
+            # one array over the whole domain: its ids come in batch-stream
+            # first-occurrence order, which the shards reproduce for P > 1
+            self._did_index = DenseIndex(domain)
+        elif self.n_partitions == 1:
             self._did_index = HashIndex(counters=counters)
         else:
             # key-hash shards: a derivation's keycode determines its shard
@@ -517,10 +546,11 @@ class SharedHashBuildState:
         """Insert rows absent by derivation id; OR visibility/provenance on
         present ones. Returns (inserted, marked).
 
-        One batched ``HashIndex.lookup_or_insert`` per shard resolves every
-        row's entry position (deduping within the batch in first-occurrence
-        order); a single ``bitwise_or.at`` pass then merges visibility and
-        provenance for marks, fresh inserts, and in-batch duplicates alike.
+        One batched ``lookup_or_insert`` of the did index (per shard for a
+        sharded ``HashIndex``) resolves every row's entry position
+        (deduping within the batch in first-occurrence order); a single
+        ``bitwise_or.at`` pass then merges visibility and provenance for
+        marks, fresh inserts, and in-batch duplicates alike.
         Global entry ids are assigned in batch-stream first-occurrence
         order for every P, so the resident SoA is partition-independent.
 
@@ -534,11 +564,9 @@ class SharedHashBuildState:
         keycodes = np.asarray(keycodes, dtype=np.int64)
         dids = keycodes if self.semi else np.asarray(dids, dtype=np.int64)
         n0 = self.did.n
-        if self.n_partitions == 1:
-            ids, is_new = self._did_index.lookup_or_insert(dids)
-            sel = np.flatnonzero(is_new)  # ids[sel] == n0 + arange(n_inserted)
-        else:
-            ids, sel = self._sharded_did_resolve(dids, keycodes, n0)
+        ids, sel = self.resolve_dids(dids, keycodes, n0)
+        if self._counters is not None:
+            self._counters["dense_did_rows" if self.dense_dids else "hashed_did_rows"] += len(dids)
         n_inserted = len(sel)
         marked = ids < n0
         n_marked = int(marked.sum())
@@ -565,6 +593,18 @@ class SharedHashBuildState:
         np.bitwise_or.at(self.emask.data, ids, emask)
         self.rows_marked += n_marked
         return n_inserted, n_marked
+
+    def resolve_dids(
+        self, dids: np.ndarray, keycodes: np.ndarray, n0: int
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Register a batch's dids with the did index: global entry id per
+        row, and the ascending batch positions of new first occurrences
+        (``ids[sel] == n0 + arange(len(sel))``), whichever index the state
+        holds. Appends no entries."""
+        if self._did_index is not None:
+            ids, is_new = self._did_index.lookup_or_insert(dids)
+            return ids, np.flatnonzero(is_new)
+        return self._sharded_did_resolve(dids, keycodes, n0)
 
     def _sharded_did_resolve(
         self, dids: np.ndarray, keycodes: np.ndarray, n0: int

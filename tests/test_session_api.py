@@ -325,7 +325,12 @@ def test_stats_expose_data_plane_counters(db):
     assert counters["evictions"] == 0 and counters["queued_admissions"] == 0
     assert fut.stats()["admission"] is None  # no controller on this session
     assert fut.stats()["queue_delay_s"] == 0.0
-    assert counters["index_rebuilds"] > 0  # did/key indexes doubled under growth
+    # hash indexes doubled under growth: the did index of the orders ⋈
+    # customer build (composed dids, too wide to address directly) and the
+    # aggregate's group index
+    assert counters["index_rebuilds"] > 0
+    # the customer scan build resolved its row ids by direct address (§8)
+    assert session.counters["dense_did_rows"] > 0 and session.counters["hashed_did_rows"] > 0
     assert counters["kernel_lens_probes"] == 0  # reference backend: no kernel lens
     # the worker-pool utilization block rides along on every stats dict
     wstats = fut.stats()["workers"]
